@@ -408,6 +408,8 @@ def main() -> None:
                     help="save the profile the --online-tune stream left "
                          "active (the CI artifact)")
     args = ap.parse_args()
+    from repro import runtime
+    runtime.enable_compile_cache()
 
     # snapshot the checked-in baseline BEFORE the export overwrites it
     bench_path = obs.bench_root() / "BENCH_serve.json"

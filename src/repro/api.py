@@ -75,10 +75,12 @@ class Policy:
     multi-pod dry-run mode that must stay XLA-compilable end to end).
     """
     backend: str = "auto"          # pallas | xla | auto | tuned
-    interpret: bool = True         # pallas interpret mode (CPU container)
+    # pallas interpret mode; None = decided by the platform (compiled on
+    # a TPU backend, interpreted elsewhere — repro.runtime)
+    interpret: Optional[bool] = None
     method: str = "dp"             # tiler: dp (ours) | greedy (paper)
     paper_thresholds: bool = False  # use the ARMv8 80/32 bounds verbatim
-    max_plan_regions: int = 64     # sanity valve
+    max_plan_regions: int = 64     # plans with more regions route to XLA
     iaat: bool = True              # False: model matmuls bypass the router
     kernels: str = ""              # "pallas"|"xla"; "" = derive from backend
 
@@ -159,7 +161,7 @@ def _resolve(policy: Optional[Policy]) -> Policy:
 POLICY_NAMES = ("xla", "pallas", "auto", "tuned")
 
 
-def named_policy(name: str, *, interpret: bool = True) -> Policy:
+def named_policy(name: str, *, interpret: Optional[bool] = None) -> Policy:
     """Build the Policy a launcher flag means.
 
     ``xla``    — forced XLA everywhere (the multi-pod dry-run mode).
@@ -167,6 +169,9 @@ def named_policy(name: str, *, interpret: bool = True) -> Policy:
                  ``Backend("pallas", iaat=True)``).
     ``auto``   — same routing, kernel family derived.
     ``tuned``  — route by the measured DeviceProfile (repro.tune).
+
+    ``interpret=None`` leaves interpret mode to the platform
+    (:func:`repro.runtime.pallas_interpret`).
     """
     if name == "xla":
         return Policy(backend="xla", kernels="xla", iaat=False,
@@ -275,17 +280,35 @@ class Router:
                 M *= int(d)
             dims = (M, N, K)
         M, N, K = (int(d) for d in dims)
-        if pol.backend == "pallas":
-            return Decision(True, "forced", op)
         if pol.backend == "xla":
             return Decision(False, "forced", op)
-        if pol.backend == "tuned":
-            entry = self._profile_entry(M, N, K, letter, trans)
-            if entry is not None:
-                if entry.prefer_pallas:
-                    return Decision(True, "profile", op, sig=entry.sig)
-                return Decision(False, "profile", op)
-        return Decision(small_enough(M, N, K, trans, pol), "analytical", op)
+        if pol.backend == "pallas":
+            d = Decision(True, "forced", op)
+        else:
+            d = Decision(small_enough(M, N, K, trans, pol), "analytical", op)
+            if pol.backend == "tuned":
+                entry = self._profile_entry(M, N, K, letter, trans)
+                if entry is not None:
+                    d = Decision(entry.prefer_pallas, "profile", op,
+                                 sig=entry.sig if entry.prefer_pallas
+                                 else None)
+        return self._plan_valve(d, M, N, K, letter, trans, pol)
+
+    @staticmethod
+    def _plan_valve(d: Decision, M, N, K, letter, trans,
+                    pol: Policy) -> Decision:
+        """A Pallas decision whose kernel plan needs more than
+        ``max_plan_regions`` launches goes to XLA — as a decision of its
+        own (``source="plan_overflow"``, counted under
+        ``route.plan_overflow``), so the shape log shows it."""
+        if not d.use_pallas:
+            return d
+        p = plan_mod.build_plan(M, N, K, letter, trans, pol.method,
+                                override=d.sig)
+        if p.num_kernel_calls <= pol.max_plan_regions:
+            return d
+        obs.counter("route.plan_overflow").inc()
+        return Decision(False, "plan_overflow", d.op)
 
     def _route_grouped(self, op: str, dims, letter: str,
                        pol: Policy) -> Decision:
@@ -399,8 +422,6 @@ def _plan_gemm(pol: Policy, d: Decision, a, b, c, alpha, beta, trans: str):
     letter = kernelgen.blas_letter(jnp.result_type(a.dtype, b.dtype))
     p = plan_mod.build_plan(M, N, K, letter, trans, pol.method,
                             override=d.sig)
-    if p.num_kernel_calls > pol.max_plan_regions:
-        return _xla_gemm(a, b, c, alpha, beta, trans)
     return plan_mod.execute(p, a, b, c, alpha, beta,
                             interpret=pol.interpret)
 

@@ -12,6 +12,8 @@ it measures what IAAT removes.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from repro import api
@@ -24,7 +26,7 @@ _PACK_SIG = {"S": (128, 256, 256), "D": (64, 128, 128),
 
 def traditional_gemm(a, b, c=None, alpha=1.0, beta=0.0,
                      trans_a: bool = False, trans_b: bool = False,
-                     *, interpret: bool = True):
+                     *, interpret: Optional[bool] = None):
     """Classic block+pack+compute GEMM (paper §I): normalise both operands
     into padded NN layout (the pack step — real extra HBM traffic), then
     run ONE fixed kernel over the padded problem.  Exists to measure what

@@ -144,7 +144,7 @@ _BUILD_CACHE: Dict[Tuple, Callable] = {}
 
 
 def build_kernel(sig: KernelSig, *, has_c_in: bool = False,
-                 interpret: bool = False) -> Callable:
+                 interpret: Optional[bool] = None) -> Callable:
     """Lower one signature to a callable pallas kernel.
 
     Returned callable computes ``alpha * op(A) @ op(B) + beta * C`` for
@@ -164,7 +164,7 @@ def build_kernel(sig: KernelSig, *, has_c_in: bool = False,
 
 def install(letters: Sequence[str] = ("S", "D", "C", "Z"),
             trans: Sequence[str] = TRANSPOSITIONS,
-            *, interpret: bool = False,
+            *, interpret: Optional[bool] = None,
             max_per_family: Optional[int] = None,
             tune: bool = False,
             tune_kwargs: Optional[dict] = None) -> int:

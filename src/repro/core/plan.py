@@ -154,7 +154,7 @@ def _slice_operand(x, lo: int, hi: int, axis: int):
 
 
 def execute(plan: Plan, a, b, c=None, alpha=1.0, beta=0.0, *,
-            interpret: bool = False):
+            interpret: Optional[bool] = None):
     """Run the kernel executing plan; returns C (M x N)."""
     from repro.kernels import iaat_gemm
     M, N, K, trans = plan.M, plan.N, plan.K, plan.trans

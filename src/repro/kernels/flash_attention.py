@@ -20,6 +20,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 NEG_INF = -1e30
 
 
@@ -87,7 +89,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     q_offset: int = 0, scale: Optional[float] = None,
                     bq: int = 128, bkv: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D); returns (B, Hq, Sq, D).
 
     GQA via the kv BlockSpec index map (no repeat-materialisation of kv)."""
@@ -117,5 +119,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=runtime.pallas_interpret(interpret),
     )(q, k, v)

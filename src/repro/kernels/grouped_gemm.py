@@ -25,11 +25,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
 from repro.core import kernelgen, vmem
+from repro.kernels.iaat_gemm import mask_k
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -72,10 +73,8 @@ def _batched_body(nk: int, K: int, bk: int, *refs):
     x = x_ref[0]
     w = w_ref[0]
     if K % bk:
-        kid = lax.broadcasted_iota(jnp.int32, x.shape, 1)
-        x = jnp.where(kid + k * bk < K, x, 0)
-        kid = lax.broadcasted_iota(jnp.int32, w.shape, 0)
-        w = jnp.where(kid + k * bk < K, w, 0)
+        x = mask_k(x, k, bk, K, 1)
+        w = mask_k(w, k, bk, K, 0)
     acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -83,7 +82,8 @@ def _batched_body(nk: int, K: int, bk: int, *refs):
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def batched_gemm(x: jax.Array, w: jax.Array, *, interpret: bool = True,
+def batched_gemm(x: jax.Array, w: jax.Array, *,
+                 interpret: Optional[bool] = None,
                  blocks: Optional[tuple] = None) -> jax.Array:
     """x: (G, C, K), w: (G, K, N) -> (G, C, N)."""
     G, C, K = x.shape
@@ -101,7 +101,7 @@ def batched_gemm(x: jax.Array, w: jax.Array, *, interpret: bool = True,
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, C, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=runtime.pallas_interpret(interpret),
     )(x, w)
 
 
@@ -120,10 +120,8 @@ def _ragged_body(nk: int, K: int, bk: int, gid_ref, *refs):
     x = x_ref[...]
     w = w_ref[0]
     if K % bk:
-        kid = lax.broadcasted_iota(jnp.int32, x.shape, 1)
-        x = jnp.where(kid + k * bk < K, x, 0)
-        kid = lax.broadcasted_iota(jnp.int32, w.shape, 0)
-        w = jnp.where(kid + k * bk < K, w, 0)
+        x = mask_k(x, k, bk, K, 1)
+        w = mask_k(w, k, bk, K, 0)
     acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -132,7 +130,7 @@ def _ragged_body(nk: int, K: int, bk: int, gid_ref, *refs):
 
 
 def ragged_gemm(x: jax.Array, w: jax.Array, tile_group_ids: jax.Array,
-                *, bm: int = 128, interpret: bool = True,
+                *, bm: int = 128, interpret: Optional[bool] = None,
                 blocks: Optional[tuple] = None) -> jax.Array:
     """x: (T, K) group-contiguous (each group padded to bm rows, padding
     zeroed); w: (G, K, N); tile_group_ids: (T//bm,) int32 mapping each row
@@ -158,5 +156,5 @@ def ragged_gemm(x: jax.Array, w: jax.Array, tile_group_ids: jax.Array,
         functools.partial(_ragged_body, nk, K, bk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), out_dtype),
-        interpret=interpret,
+        interpret=runtime.pallas_interpret(interpret),
     )(tile_group_ids.astype(jnp.int32), x, w)

@@ -32,6 +32,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
 from repro.core import templates
 from repro.core.kernelgen import KernelSig
 
@@ -40,16 +41,9 @@ def _cdiv(a: int, b: int) -> int:
     return -(a // -b)
 
 
-def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (AttributeError, TypeError):
-        try:
-            return pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:
-            return None
+# (M, N) blocks are independent; K carries the accumulator.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _a_spec(sig: KernelSig):
@@ -75,10 +69,17 @@ def _k_axis(trans_char: str, operand: str) -> int:
     return 0 if trans_char == "N" else 1
 
 
-def _mask_k(x, k_id, bk: int, K: int, axis: int):
-    """Zero the K-overhang of a block (guards OOB garbage, incl. NaN/inf)."""
+def mask_k(x, k_id, bk: int, K: int, axis: int):
+    """Zero the K-overhang of a block (guards OOB garbage, incl. NaN/inf).
+
+    Packed dtypes (bf16) select in f32: Mosaic cannot lower a packed
+    select when the block is taller than the array (M < bm, the decode
+    regime).  The round trip is exact, so the numerics are unchanged."""
     idx = lax.broadcasted_iota(jnp.int32, x.shape, axis)
-    return jnp.where(idx + k_id * bk < K, x, jnp.zeros_like(x))
+    keep = idx + k_id * bk < K
+    if x.dtype.itemsize >= 4:
+        return jnp.where(keep, x, jnp.zeros_like(x))
+    return jnp.where(keep, x.astype(jnp.float32), 0.0).astype(x.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -101,8 +102,8 @@ def _real_body(sig: KernelSig, nk: int, K: int, alpha, beta, has_c: bool,
     a = a_ref[...]
     b = b_ref[...]
     if K % sig.bk:
-        a = _mask_k(a, k, sig.bk, K, _k_axis(sig.trans[0], "a"))
-        b = _mask_k(b, k, sig.bk, K, _k_axis(sig.trans[1], "b"))
+        a = mask_k(a, k, sig.bk, K, _k_axis(sig.trans[0], "a"))
+        b = mask_k(b, k, sig.bk, K, _k_axis(sig.trans[1], "b"))
     acc_ref[...] += templates.contract(a, b, sig.trans, sig.acc_dtype)
 
     @pl.when(k == nk - 1)
@@ -128,11 +129,6 @@ def _real_call(sig: KernelSig, a, b, c, alpha, beta, interpret: bool):
         args.append(c)
     kernel = functools.partial(_real_body, sig, nk, K, alpha, beta, has_c,
                                out_dtype)
-    kw = {}
-    if not interpret:
-        cp = _compiler_params()
-        if cp is not None:
-            kw["compiler_params"] = cp
     return pl.pallas_call(
         kernel,
         grid=(gm, gn, nk),
@@ -141,7 +137,7 @@ def _real_call(sig: KernelSig, a, b, c, alpha, beta, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((sig.bm, sig.bn), sig.acc_dtype)],
         interpret=interpret,
-        **kw,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
 
 
@@ -171,10 +167,10 @@ def _cx_body(sig: KernelSig, nk: int, K: int, alpha, beta, has_c: bool,
     if K % sig.bk:
         ka = _k_axis(sig.trans[0], "a")
         kb = _k_axis(sig.trans[1], "b")
-        ar = _mask_k(ar, k, sig.bk, K, ka)
-        ai = _mask_k(ai, k, sig.bk, K, ka)
-        br = _mask_k(br, k, sig.bk, K, kb)
-        bi = _mask_k(bi, k, sig.bk, K, kb)
+        ar = mask_k(ar, k, sig.bk, K, ka)
+        ai = mask_k(ai, k, sig.bk, K, ka)
+        br = mask_k(br, k, sig.bk, K, kb)
+        bi = mask_k(bi, k, sig.bk, K, kb)
     p1, p2, p3 = templates.cmul_karatsuba(ar, ai, br, bi, sig.trans,
                                           sig.acc_dtype)
     p1_ref[...] += p1
@@ -217,11 +213,6 @@ def _cx_call(sig: KernelSig, a, b, c, alpha, beta, interpret: bool):
     beta = complex(beta)
     kernel = functools.partial(_cx_body, sig, nk, K, alpha, beta, has_c,
                                real_dtype)
-    kw = {}
-    if not interpret:
-        cp = _compiler_params()
-        if cp is not None:
-            kw["compiler_params"] = cp
     outr, outi = pl.pallas_call(
         kernel,
         grid=(gm, gn, nk),
@@ -231,7 +222,7 @@ def _cx_call(sig: KernelSig, a, b, c, alpha, beta, interpret: bool):
                    jax.ShapeDtypeStruct((M, N), real_dtype)],
         scratch_shapes=[pltpu.VMEM((sig.bm, sig.bn), sig.acc_dtype)] * 3,
         interpret=interpret,
-        **kw,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     return lax.complex(outr, outi).astype(sig.dtype)
 
@@ -296,13 +287,16 @@ _real_region_c.defvjp(_real_region_c_fwd, _real_region_c_bwd)
 # --------------------------------------------------------------------------
 
 def gemm_region(sig: KernelSig, a, b, c=None, *, alpha=1.0, beta=0.0,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """Run one plan region: op(a) @ op(b) (+ beta*c) with kernel ``sig``.
 
     Operand shapes may be any size; the grid is derived with ceil-div and
     edges are masked as described in the module docstring.  Real dtypes
     are differentiable (custom VJP); complex kernels are forward-only
-    (the paper's C/Z BLAS entries are not training paths)."""
+    (the paper's C/Z BLAS entries are not training paths).
+    ``interpret=None`` decides from the platform
+    (:func:`repro.runtime.pallas_interpret`)."""
+    interpret = runtime.pallas_interpret(interpret)
     if sig.complex_:
         return _cx_call(sig, a, b, c, alpha, beta, interpret)
     if c is None:
@@ -313,7 +307,7 @@ def gemm_region(sig: KernelSig, a, b, c=None, *, alpha=1.0, beta=0.0,
 
 
 def make_gemm_kernel(sig: KernelSig, *, has_c_in: bool = False,
-                     interpret: bool = False):
+                     interpret: Optional[bool] = None):
     """Install-time build: returns the specialised kernel callable."""
     def call(a, b, c=None, alpha=1.0, beta=0.0):
         if has_c_in and c is None:

@@ -36,11 +36,11 @@ def _grouped_blocks(op, G, C, K, N, dtype, bm=None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "blocks"))
-def _batched_gemm_jit(x, w, *, interpret=True, blocks=None):
+def _batched_gemm_jit(x, w, *, interpret=None, blocks=None):
     return _gg.batched_gemm(x, w, interpret=interpret, blocks=blocks)
 
 
-def batched_gemm(x, w, *, interpret=True, blocks=None):
+def batched_gemm(x, w, *, interpret=None, blocks=None):
     """Always-Pallas grouped kernel entry; ``blocks=None`` resolves the
     block sizes through the router (profile-refined under
     ``backend="tuned"``, the analytical table otherwise)."""
@@ -52,13 +52,13 @@ def batched_gemm(x, w, *, interpret=True, blocks=None):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret", "blocks"))
-def _ragged_gemm_jit(x, w, tile_group_ids, *, bm=128, interpret=True,
+def _ragged_gemm_jit(x, w, tile_group_ids, *, bm=128, interpret=None,
                      blocks=None):
     return _gg.ragged_gemm(x, w, tile_group_ids, bm=bm,
                            interpret=interpret, blocks=blocks)
 
 
-def ragged_gemm(x, w, tile_group_ids, *, bm=128, interpret=True,
+def ragged_gemm(x, w, tile_group_ids, *, bm=128, interpret=None,
                 blocks=None):
     """Always-Pallas ragged kernel entry; block resolution as above (the
     row block ``bm`` stays caller-pinned — group sizes are traced)."""
@@ -74,12 +74,12 @@ def ragged_gemm(x, w, tile_group_ids, *, bm=128, interpret=True,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "q_offset", "scale", "bq", "bkv", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                    scale=None, bq=128, bkv=128, interpret=True):
+                    scale=None, bq=128, bkv=128, interpret=None):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                q_offset=q_offset, scale=scale, bq=bq,
                                bkv=bkv, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk=128, interpret=True):
+def ssd_scan(x, dt, A, B, C, *, chunk=128, interpret=None):
     return _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=interpret)

@@ -14,12 +14,15 @@ Pallas guarantees scratch persistence along the trailing grid axis.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import runtime
 
 
 def _body(chunk: int, S: int, nc: int,
@@ -69,7 +72,7 @@ def _body(chunk: int, S: int, nc: int,
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, *, chunk: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: Optional[bool] = None) -> jax.Array:
     """x: (Bt, S, H, P); dt: (Bt, S, H); A: (H,); B, C: (Bt, S, 1, N).
 
     Returns y: (Bt, S, H, P).  D-skip is applied by the caller."""
@@ -98,6 +101,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((Bt, Sp, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=interpret,
+        interpret=runtime.pallas_interpret(interpret),
     )(x, dt, dA, B, C)
     return out[:, :S]

@@ -19,7 +19,7 @@ import time
 import jax
 import numpy as np
 
-from repro import api, configs, obs
+from repro import api, configs, obs, runtime
 from repro.models.registry import build as build_model
 from repro.serve import PagedEngine, Request
 
@@ -53,6 +53,7 @@ def main() -> None:
                          "--backend — forced xla never calls route(), "
                          "so the tuner sees no traffic and idles)")
     args = ap.parse_args()
+    runtime.enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get_config(args.arch)
@@ -67,7 +68,7 @@ def main() -> None:
         raise SystemExit(f"--engine paged: family {cfg.family!r} has no "
                          f"paged serving path")
     # model-entry policy install: the engine snapshots the ambient policy
-    be = api.install(api.named_policy(args.backend, interpret=True))
+    be = api.install(api.named_policy(args.backend))
     params = model.init(jax.random.PRNGKey(args.seed))
     rng = np.random.RandomState(args.seed)
     tuner = None

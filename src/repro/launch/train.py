@@ -20,7 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import api, configs, obs
+from repro import api, configs, obs, runtime
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import frontends
 from repro.models.registry import build as build_model
@@ -75,8 +75,7 @@ def run(args) -> dict:
     # family is pinned to the XLA/ref paths while GEMM routing stays
     # input-aware (auto) or profile-refined (tuned): the routed GEMM
     # plan path carries a custom VJP.
-    be = api.install(api.named_policy(args.backend,
-                                      interpret=True).replace(kernels="xla"))
+    be = api.install(api.named_policy(args.backend).replace(kernels="xla"))
     tc = train_loop.TrainConfig(
         opt=opt.OptConfig(peak_lr=args.lr, warmup_steps=args.warmup,
                           decay_steps=max(args.steps, 10)),
@@ -152,6 +151,7 @@ def run(args) -> dict:
 def main() -> None:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    runtime.enable_compile_cache()
     out = run(build_args())
     print({k: v for k, v in out.items()})
 

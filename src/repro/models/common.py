@@ -23,9 +23,9 @@ Specs = Dict[str, Any]
 
 #: Canonical policies for the two reference operating points: the
 #: XLA-compilable dry-run stack, and pallas kernels with input-aware
-#: GEMM routing under interpret mode (the CI container).
+#: GEMM routing (interpreted on CPU, compiled on a TPU).
 XLA = api.named_policy("xla")
-PALLAS_INTERPRET = api.named_policy("pallas")
+PALLAS = api.named_policy("pallas")
 
 
 def mm(x: jax.Array, w: jax.Array,

@@ -478,6 +478,14 @@ class RouteLog:
             out[ak] = out.get(ak, 0) + h[0]
         return out
 
+    def decisions(self) -> Dict[tuple, Any]:
+        """Live memoized decisions per exact shape:
+        ``(op, dtype, trans, dims) -> Decision`` (one per policy; entries
+        folded into the aggregate by compaction are not listed)."""
+        with self._lock:
+            live = list(self.hits.items())
+        return {key[:4]: h[3] for key, h in live}
+
     def shape_counts(self) -> Dict[Tuple[str, str, str], int]:
         """The ROADMAP query: counts per (op, dtype, size-class)."""
         out: Dict[Tuple[str, str, str], int] = {}

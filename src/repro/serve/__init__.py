@@ -8,13 +8,13 @@ preempt-on-exhaustion) for every decoder-only family.
 tests/benchmarks as the temperature-0 parity oracle.
 """
 from repro.serve.engine import (ContinuousBatcher, PagedEngine, Request,
-                                make_serve_fns, sample)
+                                make_serve_fns, paged_step_fns, sample)
 from repro.serve.paged import (BlockAllocator, BlockTable, CacheMap,
                                OutOfBlocks, SlotStateStore)
 from repro.serve.sched import Seq, SlotScheduler
 
 __all__ = [
     "ContinuousBatcher", "PagedEngine", "Request", "make_serve_fns",
-    "sample", "BlockAllocator", "BlockTable", "CacheMap", "OutOfBlocks",
+    "paged_step_fns", "sample", "BlockAllocator", "BlockTable", "CacheMap", "OutOfBlocks",
     "SlotStateStore", "Seq", "SlotScheduler",
 ]

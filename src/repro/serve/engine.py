@@ -63,10 +63,43 @@ def make_serve_fns(model: Model, be: Optional[Policy] = None):
             jax.jit(decode, donate_argnums=(2,)))
 
 
-def sample(logits, key, temperature: float = 0.0):
+def sample(logits, key, temperature: float = 0.0,
+           vocab: Optional[int] = None):
+    """Greedy (temperature 0) or categorical sampling over the last axis;
+    ``vocab`` drops the padded tail of a vocab-padded unembedding, so a
+    served token is always a real id."""
+    if vocab is not None:
+        logits = logits[..., :vocab]
     if temperature <= 0.0:
         return jnp.argmax(logits, axis=-1)
     return jax.random.categorical(key, logits / temperature, axis=-1)
+
+
+def paged_step_fns(model: Model, be: Policy, temperature: float = 0.0):
+    """The paged engine's two device steps, unjitted, so a compile check
+    can lower exactly what :class:`PagedEngine` runs:
+
+    ``decode(params, cur, ps, block_tables, pos, active, key)`` ->
+    ``(next_tokens (slots,), ps, key)`` — one token for every slot,
+    sampled on device;
+    ``prefill(params, toks, ps, block_tables, pos0, slot, seg_len,
+    n_prompt, last_idx)`` -> ``(logits row (Vp,), ps)`` — one chunk of
+    one request."""
+    def decode(p, cur, ps, bt, pos, active, k):
+        logits, ps = model.paged_decode(
+            p, {"tokens": cur[:, None]}, ps, bt, pos, active, be)
+        k, sub = jax.random.split(k)
+        nxt = sample(logits[:, -1], sub, temperature, model.cfg.vocab)
+        return nxt.astype(jnp.int32), ps, k
+
+    def prefill(p, toks, ps, bt, pos0, slot, seg_len, n_prompt, last_idx):
+        logits, ps = model.paged_prefill(
+            p, {"tokens": toks}, ps, bt, pos0, slot, seg_len, n_prompt, be)
+        row = jax.lax.dynamic_index_in_dim(logits[0], last_idx,
+                                           axis=0, keepdims=False)
+        return row, ps
+
+    return decode, prefill
 
 
 @dataclasses.dataclass
@@ -145,25 +178,9 @@ class PagedEngine:
         # in order; holding the arrays (instead of np.asarray per step)
         # is what lets device steps pipeline
         self._pending: List[tuple] = []
-
-        def _decode(p, cur, ps, bt, pos, active, k):
-            logits, ps = model.paged_decode(
-                p, {"tokens": cur[:, None]}, ps, bt, pos, active, be)
-            k, sub = jax.random.split(k)
-            nxt = sample(logits[:, -1], sub, temperature)
-            return nxt.astype(jnp.int32), ps, k
-
-        def _prefill(p, toks, ps, bt, pos0, slot, seg_len, n_prompt,
-                     last_idx):
-            logits, ps = model.paged_prefill(
-                p, {"tokens": toks}, ps, bt, pos0, slot, seg_len,
-                n_prompt, be)
-            row = jax.lax.dynamic_index_in_dim(logits[0], last_idx,
-                                               axis=0, keepdims=False)
-            return row, ps
-
-        self._decode_fn = jax.jit(_decode, donate_argnums=(2,))
-        self._prefill_fn = jax.jit(_prefill, donate_argnums=(2,))
+        decode, prefill = paged_step_fns(model, be, temperature)
+        self._decode_fn = jax.jit(decode, donate_argnums=(2,))
+        self._prefill_fn = jax.jit(prefill, donate_argnums=(2,))
 
     # -- API (mirrors ContinuousBatcher) -----------------------------------
 
@@ -325,7 +342,8 @@ class PagedEngine:
         # host-side sample for the prefill boundary token only — every
         # subsequent token is sampled inside the jit'd decode step
         self.key, k = jax.random.split(self.key)
-        tok = int(np.asarray(sample(row, k, self.temperature)))
+        tok = int(np.asarray(sample(row, k, self.temperature,
+                                    self.model.cfg.vocab)))
         seq.out.append(tok)
         obs.counter("serve.tokens").inc()
         if len(seq.out) == 1:
@@ -402,7 +420,8 @@ class ContinuousBatcher:
             logits, c = model.decode(p, {"tokens": t}, c, be)
             # sampling fused into the step: only (B,) token ids cross
             # to the host, never the (B, V) logits
-            return sample(logits, k, temperature).astype(jnp.int32), c
+            return sample(logits, k, temperature,
+                          model.cfg.vocab).astype(jnp.int32), c
 
         self._decode = jax.jit(_decode)
 
@@ -447,7 +466,8 @@ class ContinuousBatcher:
             logits = jax.block_until_ready(logits)
         outs = [[] for _ in wave]
         alive = np.ones(B, bool)
-        cur = np.asarray(sample(logits, self.key, self.temperature))
+        cur = np.asarray(sample(logits, self.key, self.temperature,
+                                self.model.cfg.vocab))
         t_first = time.perf_counter()
         ttft = obs.histogram("serve.ttft_us")
         for i in range(B):

@@ -16,6 +16,7 @@ import argparse
 import json
 import sys
 
+from repro import runtime
 from repro.tune import classes as classes_mod
 from repro.tune import profile as profile_mod
 from repro.tune import search
@@ -55,8 +56,9 @@ def main(argv=None) -> int:
                     help="cube classes only, max-dim 128, reps 3, top 2 "
                          "(CI / interpret-mode smoke)")
     ap.add_argument("--compiled", action="store_true",
-                    help="time compiled kernels (real TPU) instead of "
-                         "interpret mode")
+                    help="time compiled kernels even where the platform "
+                         "would interpret them (default: compiled on a "
+                         "TPU, interpret mode elsewhere)")
     ap.add_argument("--out", default=None,
                     help="profile path (default: per-device cache path)")
     ap.add_argument("--no-merge", action="store_true",
@@ -65,7 +67,8 @@ def main(argv=None) -> int:
                     help="print the profile at the target path and exit")
     args = ap.parse_args(argv)
 
-    mode = "compiled" if args.compiled else "interpret"
+    interpret = runtime.pallas_interpret(False if args.compiled else None)
+    mode = "interpret" if interpret else "compiled"
     path = args.out or profile_mod.default_profile_path(mode=mode)
     if args.show:
         # without --out, show what tuned dispatch would actually load
@@ -95,7 +98,6 @@ def main(argv=None) -> int:
     n_classes = len(classes_mod.classes_up_to(
         args.letters, args.trans, args.max_dim, min_dim=args.min_dim,
         cube_only=args.quick))
-    mode = "interpret" if not args.compiled else "compiled"
     print(f"tuning {n_classes} size classes "
           f"({''.join(args.letters)} x {','.join(args.trans)}, "
           f"dims {args.min_dim}..{args.max_dim}, {mode} mode)")
@@ -103,7 +105,7 @@ def main(argv=None) -> int:
                         min_dim=args.min_dim, max_dim=args.max_dim,
                         cube_only=args.quick, top=args.top,
                         warmup=args.warmup, reps=args.reps,
-                        interpret=not args.compiled, progress=progress)
+                        interpret=interpret, progress=progress)
     if not args.no_merge:
         try:
             prof = profile_mod.DeviceProfile.load(path).merge(prof)

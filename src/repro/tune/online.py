@@ -40,7 +40,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro import obs
+from repro import obs, runtime
 from repro.tune import classes as classes_mod
 from repro.tune.classes import SizeClass
 from repro.tune.profile import DeviceProfile, active_profile, \
@@ -147,7 +147,7 @@ class OnlineTuner:
                  budget: int = 8, decay: float = 0.5, n_buckets: int = 8,
                  min_weight: float = 1.0, retune_ratio: float = 1.5,
                  top: int = 1, warmup: int = 0, reps: int = 1,
-                 interpret: bool = True, grouped_G: int = 4,
+                 interpret: Optional[bool] = None, grouped_G: int = 4,
                  max_dim: Optional[int] = 1024,
                  device_kind: Optional[str] = None,
                  sweeper: Optional[Callable[..., tuple]] = None,
@@ -157,9 +157,10 @@ class OnlineTuner:
         self.decay, self.n_buckets = decay, n_buckets
         self.min_weight, self.retune_ratio = min_weight, retune_ratio
         self.top, self.warmup, self.reps = top, warmup, reps
-        self.interpret, self.grouped_G = interpret, grouped_G
+        self.interpret = runtime.pallas_interpret(interpret)
+        self.grouped_G = grouped_G
         self.max_dim = max_dim
-        self.mode = "interpret" if interpret else "compiled"
+        self.mode = "interpret" if self.interpret else "compiled"
         self._device_kind = device_kind
         self._sweeper = sweeper
         self.persist = persist

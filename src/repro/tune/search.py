@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import obs, runtime
 from repro.core import cost, kernelgen
 from repro.core.kernelgen import KernelSig
 from repro.tune import classes as classes_mod
@@ -86,7 +86,8 @@ def _xla_fn(trans: str, a, b) -> Callable[[], jax.Array]:
     return lambda: f(a, b)
 
 
-def _pallas_fn(sig: KernelSig, a, b, interpret: bool) -> Callable[[], jax.Array]:
+def _pallas_fn(sig: KernelSig, a, b,
+               interpret: Optional[bool]) -> Callable[[], jax.Array]:
     from repro.kernels import iaat_gemm
 
     @jax.jit
@@ -97,7 +98,8 @@ def _pallas_fn(sig: KernelSig, a, b, interpret: bool) -> Callable[[], jax.Array]
 
 
 def tune_class(sc: SizeClass, *, top: int = 4, warmup: int = 1,
-               reps: int = 5, interpret: bool = True) -> ProfileEntry:
+               reps: int = 5, interpret: Optional[bool] = None
+               ) -> ProfileEntry:
     """Measure one size class at its representative shape; returns the
     entry (best pallas sig + both timings) to record in the profile."""
     M, N, K = classes_mod.representative(sc)
@@ -115,7 +117,7 @@ def tune_class(sc: SizeClass, *, top: int = 4, warmup: int = 1,
 
 def tune_grouped_class(sc: SizeClass, *, G: int = 4, top: int = 4,
                        warmup: int = 1, reps: int = 5,
-                       interpret: bool = True) -> ProfileEntry:
+                       interpret: Optional[bool] = None) -> ProfileEntry:
     """Measure one grouped size class ON the grouped kernel.
 
     The per-group problem (C, K, N) keys the same class table as 2-D
@@ -177,7 +179,7 @@ class TuneTarget:
 
 def budgeted_sweep(targets: Sequence[TuneTarget], *, budget: int = 8,
                    top: int = 1, warmup: int = 0, reps: int = 1,
-                   interpret: bool = True, grouped_G: int = 4,
+                   interpret: Optional[bool] = None, grouped_G: int = 4,
                    device_kind: Optional[str] = None,
                    ) -> Tuple[DeviceProfile, List[TuneTarget], int]:
     """Re-tune ``targets`` in order until the timing budget runs out.
@@ -190,6 +192,7 @@ def budgeted_sweep(targets: Sequence[TuneTarget], *, budget: int = 8,
     touched.  Returns ``(delta_profile, tuned_targets, timings_spent)``;
     the delta holds only the classes actually tuned, ready to merge.
     """
+    interpret = runtime.pallas_interpret(interpret)
     prof = DeviceProfile(device_kind or current_device_kind(),
                          mode="interpret" if interpret else "compiled")
     per_class = 1 + max(1, top)
@@ -221,10 +224,12 @@ def sweep(letters: Sequence[str] = ("S",),
           trans: Sequence[str] = ("NN",), *,
           min_dim: int = 8, max_dim: int = 512, cube_only: bool = False,
           top: int = 4, warmup: int = 1, reps: int = 5,
-          interpret: bool = True, device_kind: Optional[str] = None,
+          interpret: Optional[bool] = None,
+          device_kind: Optional[str] = None,
           progress: Optional[Callable[[SizeClass, ProfileEntry], None]] = None,
           ) -> DeviceProfile:
     """Run the tuning sweep and return the (unsaved) DeviceProfile."""
+    interpret = runtime.pallas_interpret(interpret)
     prof = DeviceProfile(device_kind or current_device_kind(),
                          mode="interpret" if interpret else "compiled")
     with obs.span("tune.sweep"):

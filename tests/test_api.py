@@ -81,7 +81,7 @@ def test_ambient_policy_install_and_using():
 
 def test_named_policy_covers_cli_surface():
     assert api.named_policy("xla") == common.XLA
-    assert api.named_policy("pallas") == common.PALLAS_INTERPRET
+    assert api.named_policy("pallas") == common.PALLAS
     assert api.named_policy("tuned").backend == "tuned"
     with pytest.raises(ValueError):
         api.named_policy("cuda")
@@ -125,6 +125,51 @@ def test_route_profile_says_xla_wins():
               sig=KernelSig("S", "NN", 32, 128, 256))
     d = api.route("gemm", (45, 45, 45), "S", policy=Policy(backend="tuned"))
     assert d.source == "profile" and not d.use_pallas
+
+
+@pytest.mark.parametrize("backend", ["pallas", "auto"])
+def test_plan_overflow_is_a_visible_decision(backend):
+    """A Pallas decision whose plan exceeds ``max_plan_regions`` becomes
+    its own XLA decision: counted, logged in ROUTES, numerically XLA."""
+    from repro import obs
+    dims = (1, 6448, 1536)                      # a 3-region bf16 plan
+    pol = Policy(backend=backend, interpret=True)
+    assert api.route("gemm", dims, "H", policy=pol).use_pallas
+    obs.ROUTES.reset()
+    before = obs.counter("route.plan_overflow").value
+    tight = pol.replace(max_plan_regions=2)
+    d = api.route("gemm", dims, "H", policy=tight)
+    assert (d.use_pallas, d.source) == (False, "plan_overflow")
+    assert obs.counter("route.plan_overflow").value == before + 1
+    rows = obs.ROUTES.snapshot()
+    assert [(r["use_pallas"], r["source"]) for r in rows] == \
+        [(False, "plan_overflow")]
+    rng = np.random.RandomState(7)
+    a = jnp.asarray(rng.randn(2, 300), jnp.float32)
+    b = jnp.asarray(rng.randn(300, 70), jnp.float32)
+    out = api.gemm(a, b, policy=pol.replace(max_plan_regions=0))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(a) @ np.asarray(b),
+                               rtol=2e-5, atol=1e-4)
+
+
+def test_policy_interpret_follows_platform():
+    """Unset, interpret mode is the platform's call: on in this CPU
+    process, and an explicit flag still wins."""
+    from repro import runtime
+    assert Policy().interpret is None
+    assert api.named_policy("auto").interpret is None
+    assert runtime.pallas_interpret(None) is (jax.default_backend() != "tpu")
+    assert runtime.pallas_interpret(False) is False
+    assert runtime.pallas_interpret(True) is True
+
+
+def test_compile_cache_dir_prefers_environment(monkeypatch, tmp_path):
+    from repro import runtime
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert runtime.compile_cache_dir() == str(runtime.CACHE_DIR)
+    assert runtime.CACHE_DIR.name == ".jax_cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert runtime.compile_cache_dir() == str(tmp_path)
 
 
 def test_route_rejects_unknown_op():

@@ -111,3 +111,27 @@ def test_plan_region_count_small_problem():
     assert p.num_kernel_calls == 1
     p2 = plan_mod.build_plan(80, 80, 80, "S", "NN")
     assert p2.num_kernel_calls <= 2
+
+
+@pytest.mark.parametrize("letter,M,N,K", [
+    ("H", 4, 200, 300),     # bf16 decode regime: M < bm, K % bk != 0
+    ("H", 1, 130, 129),
+    ("S", 4, 200, 300),
+    ("S", 3, 140, 257),
+])
+def test_short_m_ragged_k_region(letter, M, N, K):
+    """The repaired K-tail mask: a block taller than the operand (M < bm)
+    with a ragged K agrees with numpy in bf16 and f32."""
+    rng = np.random.RandomState(M * 1000 + K)
+    dt = jnp.bfloat16 if letter == "H" else jnp.float32
+    sig = kernelgen.KernelSig(letter, "NN", 16 if letter == "H" else 8,
+                              128, 128)
+    assert M < sig.bm and K % sig.bk
+    a = jnp.asarray(rng.randn(M, K), dt)
+    b = jnp.asarray(rng.randn(K, N), dt)
+    out = iaat_gemm.gemm_region(sig, a, b, interpret=True)
+    assert out.dtype == dt
+    want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    tol = _RTOL[letter]
+    np.testing.assert_allclose(np.asarray(out, np.float64), want,
+                               rtol=tol, atol=tol * np.abs(want).max())

@@ -14,7 +14,7 @@ from repro import api
 from repro.core import kernelgen, plan as plan_mod
 from repro.kernels import ref
 from repro.models import registry
-from repro.models.common import PALLAS_INTERPRET, XLA
+from repro.models.common import PALLAS, XLA
 
 KEY = jax.random.PRNGKey(0)
 
@@ -88,7 +88,7 @@ def test_model_forward_through_iaat_backend():
     tok = jax.random.randint(KEY, (1, 16), 0, cfg.vocab)
     l_xla, _ = model.forward_train(params, {"tokens": tok}, XLA)
     l_iaat, _ = model.forward_train(params, {"tokens": tok},
-                                    PALLAS_INTERPRET)
+                                    PALLAS)
     scale = float(jnp.abs(l_xla).max())
     assert float(jnp.abs(l_xla - l_iaat).max()) / scale < 5e-3
 
@@ -102,7 +102,7 @@ def test_moe_through_pallas_batched_gemm():
     params = model.init(KEY)
     tok = jax.random.randint(KEY, (1, 16), 0, cfg.vocab)
     l_xla, _ = model.forward_train(params, {"tokens": tok}, XLA)
-    be = PALLAS_INTERPRET.replace(backend="pallas", iaat=False)
+    be = PALLAS.replace(backend="pallas", iaat=False)
     l_pl, _ = model.forward_train(params, {"tokens": tok}, be)
     scale = float(jnp.abs(l_xla).max())
     assert float(jnp.abs(l_xla - l_pl).max()) / scale < 5e-3
