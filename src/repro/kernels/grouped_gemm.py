@@ -102,6 +102,7 @@ def batched_gemm(x: jax.Array, w: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((G, C, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=runtime.pallas_interpret(interpret),
+        name=f"iaat_batched_gemm_{bm}x{bn}x{bk}",
     )(x, w)
 
 
@@ -157,4 +158,5 @@ def ragged_gemm(x: jax.Array, w: jax.Array, tile_group_ids: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), out_dtype),
         interpret=runtime.pallas_interpret(interpret),
+        name=f"iaat_ragged_gemm_{bm}x{bn}x{bk}",
     )(tile_group_ids.astype(jnp.int32), x, w)

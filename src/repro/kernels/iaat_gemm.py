@@ -1,7 +1,9 @@
 """The generated IAAT GEMM microkernel family (Pallas TPU).
 
 One ``pl.pallas_call`` instance per :class:`KernelSig` — the TPU analogue
-of the paper's auto-generated assembly kernels:
+of the paper's auto-generated assembly kernels — named
+:func:`call_name` (``iaat_<sig.name>``), so the compiled program and
+the device trace name each plan region's kernel:
 
 * operands are consumed in their native (possibly transposed) layout via
   per-transposition BlockSpec index maps + dot_general dimension numbers
@@ -39,6 +41,12 @@ from repro.core.kernelgen import KernelSig
 
 def _cdiv(a: int, b: int) -> int:
     return -(a // -b)
+
+
+def call_name(sig: KernelSig) -> str:
+    """The ``pallas_call``'s name: the HLO instruction of the kernel's
+    custom call, and the device trace's operation, carry it."""
+    return f"iaat_{sig.name}"
 
 
 # (M, N) blocks are independent; K carries the accumulator.
@@ -138,6 +146,7 @@ def _real_call(sig: KernelSig, a, b, c, alpha, beta, interpret: bool):
         scratch_shapes=[pltpu.VMEM((sig.bm, sig.bn), sig.acc_dtype)],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=call_name(sig),
     )(*args)
 
 
@@ -223,6 +232,7 @@ def _cx_call(sig: KernelSig, a, b, c, alpha, beta, interpret: bool):
         scratch_shapes=[pltpu.VMEM((sig.bm, sig.bn), sig.acc_dtype)] * 3,
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=call_name(sig),
     )(*args)
     return lax.complex(outr, outi).astype(sig.dtype)
 

@@ -3,7 +3,8 @@
 Every later perf PR reports through this layer, so it is deliberately
 small and stdlib-only: a metric registry (:class:`Counter`,
 :class:`Gauge`, log-bucket :class:`Histogram` with p50/p95/p99), a
-:func:`span` context manager for wall-clock sections (which also emits a
+:class:`span` context manager for host sections (written to the flight
+recorder :data:`TRACE` as ``SPAN`` events, and opened as a
 ``jax.profiler.TraceAnnotation`` so spans line up with device traces
 when a profiler is active), and :func:`export_bench`, which writes a
 schema'd ``BENCH_<name>.json`` at the repo root — the per-PR perf
@@ -22,8 +23,8 @@ traffic-aware tuning stage needs (Tillet's input-aware predictor trains
 on it; see ROADMAP).
 
 ``REPRO_OBS=0`` in the environment disables everything: metric helpers
-hand out shared null objects, :func:`span` skips the clock, and the
-route log is bypassed with a single attribute check.
+hand out shared null objects, :class:`span` and the flight recorder do
+nothing, and the route log is bypassed with a single attribute check.
 """
 from __future__ import annotations
 
@@ -333,49 +334,54 @@ def _get_trace_annotation():
 
 
 class span:
-    """Wall-clock section: ``with span("serve.prefill"): ...``
+    """Host section of the program: ``with span("serve.drain"): ...``
 
-    Nested spans record under their dotted path ("a" inside "b" becomes
-    ``span.b.a_us``), so a report shows where time went hierarchically.
-    Each span also opens a ``jax.profiler.TraceAnnotation`` — free when
-    no profiler is active, and the host-side section shows up alongside
-    device events when one is.
+    On exit it records one ``SPAN`` event in the flight recorder
+    (:data:`TRACE`): ``t`` is its end on ``perf_counter``, like every
+    other event's, ``dur_us`` its length, and ``arg`` is ``(name,
+    parent)``, ``parent`` being the name of the span it ran inside on
+    this thread (None at the top), so nesting survives into the ring;
+    ``info``, where set before the span closes, is appended as a third
+    element.  It also opens a ``jax.profiler.TraceAnnotation`` of the
+    same name, so a profiler run shows the section beside the device
+    planes.  With the recorder off (``REPRO_OBS=0`` or ``REPRO_TRACE=0``)
+    it does nothing beyond one flag check.
     """
-    __slots__ = ("name", "_t0", "_path", "_ann")
+    __slots__ = ("name", "info", "_t0", "_parent", "_ann", "_open")
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, info: Any = None) -> None:
         self.name = name
-        self._t0 = 0.0
-        self._path = ""
-        self._ann = None
+        self.info = info
+        self._open = False
 
     def __enter__(self) -> "span":
-        if not _ENABLED:
+        if not TRACE.on:
             return self
         stack = getattr(_span_stack, "names", None)
         if stack is None:
             stack = _span_stack.names = []
+        self._parent = stack[-1] if stack else None
         stack.append(self.name)
-        self._path = ".".join(stack)
         ta = _get_trace_annotation()
-        if ta:
-            self._ann = ta(self._path)
+        self._ann = ta(self.name) if ta else None
+        if self._ann is not None:
             self._ann.__enter__()
+        self._open = True
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        if not self._path:
+        if not self._open:
             return
-        dt_us = (time.perf_counter() - self._t0) * 1e6
+        t1 = time.perf_counter()
+        self._open = False
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None
-        stack = _span_stack.names
-        if stack and stack[-1] == self.name:
-            stack.pop()
-        REGISTRY.histogram(f"span.{self._path}_us").record(dt_us)
-        self._path = ""
+        _span_stack.names.pop()
+        arg = (self.name, self._parent) if self.info is None \
+            else (self.name, self._parent, self.info)
+        TRACE.emit("SPAN", arg=arg, dur_us=(t1 - self._t0) * 1e6, t=t1)
 
 
 # --------------------------------------------------------------------------

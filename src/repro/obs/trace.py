@@ -3,8 +3,9 @@
 The aggregate histograms in :mod:`repro.obs` answer *how slow* — this
 module answers *why*: a bounded ring-buffer :class:`EventLog` records
 typed, timestamped events from the paged serving engine (admission,
-prefill chunks, first token, sampled decode ticks, preemption/resume,
-finish, block eviction), the Router's memo-miss path and the tuner's
+prefill chunks, first token, preemption/resume, finish, block
+eviction), the host spans of the engine loop (``SPAN``, written by
+:class:`repro.obs.span`), the Router's memo-miss path and the tuner's
 profile swaps.  Three consumers sit on top:
 
 * :func:`per_request` — a reducer deriving per-request queue-wait, the
@@ -16,7 +17,8 @@ profile swaps.  Three consumers sit on top:
   export (loadable in Perfetto / ``chrome://tracing``): slots render as
   tracks, each request as flow-connected queued→prefill→decode slices,
   so a scheduling pathology (a request parked in the queue, a preempt
-  ping-pong) is *visible* instead of inferred from a p99.
+  ping-pong) is *visible* instead of inferred from a p99; the spans
+  nest on one "engine" track beside them.
 * the raw event list itself, embedded in the export under
   ``reproTrace`` so ``python -m repro.obs trace IN OUT`` can re-derive
   both views offline.
@@ -26,9 +28,8 @@ code (never inside jit), appends are single ``deque.append`` calls
 (GIL-atomic; drop-oldest is the deque's ``maxlen``), timestamps are
 ``time.perf_counter()`` (monotonic), and the whole layer obeys the
 ``REPRO_OBS`` kill switch plus its own ``REPRO_TRACE=0`` override.
-High-frequency decode steps are sampled (``PagedEngine.TICK_SAMPLE``)
-so a long decode cannot wash the interesting transitions out of the
-ring.
+The default capacity holds a long benchmark window of the engine's
+spans several times over, so the request events never drop out.
 """
 from __future__ import annotations
 
@@ -56,7 +57,6 @@ EVENT_TYPES = frozenset((
     "RESUME",         # sched.admit, re-admission after preempt: rid, slot
     "PREFILL_CHUNK",  # engine: rid, slot, (pos0, n_tokens), dur_us
     "FIRST_TOKEN",    # engine: rid, slot
-    "DECODE_TICK",    # engine, sampled: (step_idx, n_decoding)
     "PREEMPT",        # sched.preempt: rid, slot
     "FINISH",         # engine._finish: rid, slot, n_out
     "EVICT",          # paged.CacheMap.release: rid, blocks freed
@@ -64,16 +64,21 @@ EVENT_TYPES = frozenset((
     "PROFILE_SWAP",   # tune.profile active-profile transition: tag
     "TUNE_CYCLE",     # tune.online cycle end: (cycle, retuned, timings,
                       #   swapped), dur_us = cycle wall time
+    "SPAN",           # obs.span end: (name, parent[, info]), dur_us
 ))
 
 #: One record: (t, type, rid, slot, arg, dur_us).  ``t`` is a
 #: ``perf_counter`` second; ``rid``/``slot`` are -1 when not applicable;
 #: ``arg`` is a small JSON-serializable payload; ``dur_us`` is set for
-#: events that timed a section (prefill chunks).
+#: events that timed a section (prefill chunks, spans), which then end
+#: at ``t``.
 Event = Tuple[float, str, int, int, Any, Optional[float]]
 
 _CAP_ENV = "REPRO_TRACE_CAP"
-_DEFAULT_CAP = 65536
+#: Holds a 51 s benchmark window and its drain (up to 60 s more), the
+#: engine's spans included, at least four times over (PERF.md gives the
+#: count on the chip), so a window's request events never drop out.
+_DEFAULT_CAP = 1 << 18
 
 
 class EventLog:
@@ -105,15 +110,18 @@ class EventLog:
     # -- emit path (hot-ish; host scheduling code only) --------------------
 
     def emit(self, etype: str, rid: int = -1, slot: int = -1,
-             arg: Any = None, dur_us: Optional[float] = None) -> None:
+             arg: Any = None, dur_us: Optional[float] = None,
+             t: Optional[float] = None) -> None:
+        """Record one event at ``t`` (``perf_counter`` seconds; now when
+        None)."""
         if not self.on:
             return
         if etype not in EVENT_TYPES:
             raise ValueError(f"unknown trace event {etype!r}; "
                              f"expected one of {sorted(EVENT_TYPES)}")
         self.n_total += 1
-        self._ring.append((time.perf_counter(), etype, rid, slot, arg,
-                           dur_us))
+        self._ring.append((time.perf_counter() if t is None else t, etype,
+                           rid, slot, arg, dur_us))
 
     # -- views -------------------------------------------------------------
 
@@ -289,6 +297,7 @@ def summary(per_req: Dict[int, dict]) -> dict:
 _PID_SERVE = 1
 _PID_ROUTER = 2
 _TID_QUEUE = 0                      # request queue track; slots are 1 + slot
+_TID_ENGINE = 1 << 16               # the engine loop's spans (sorted first)
 
 
 def _meta(pid: int, tid: Optional[int], name: str, value: str,
@@ -307,8 +316,11 @@ def perfetto(events: Iterable[Event], *,
              slots: Optional[int] = None) -> dict:
     """Render the event stream as a Chrome-trace-event document.
 
-    Track layout: pid 1 ("repro.serve") has tid 0 = the admission queue
-    and tid ``1+s`` = slot ``s``; pid 2 ("repro.router") carries
+    Track layout: pid 1 ("repro.serve") has tid 0 = the admission queue,
+    tid ``1+s`` = slot ``s``, and an "engine" track, drawn above them,
+    that holds every ``SPAN`` as a complete slice ending at its ``t``
+    (spans nest by time, each inside its parent, and carry ``parent``
+    and ``info`` in ``args``); pid 2 ("repro.router") carries
     ROUTE_MISS / PROFILE_SWAP instants on tid 0 and the online tuner's
     TUNE_CYCLE slices on its own tid 1 track (each cycle renders as a
     complete slice spanning its measured duration, so a miss burst on
@@ -325,7 +337,8 @@ def perfetto(events: Iterable[Event], *,
     te = doc["traceEvents"]
     if not evs:
         return doc
-    t0 = evs[0][0]
+    # a timed section starts dur_us before its event
+    t0 = min(e[0] - (e[5] or 0.0) * 1e-6 for e in evs)
 
     def us(t: float) -> float:
         return round((t - t0) * 1e6, 3)
@@ -334,6 +347,8 @@ def perfetto(events: Iterable[Event], *,
     if slots is not None:
         max_slot = max(max_slot, slots - 1)
     te.extend(_meta(_PID_SERVE, None, "process_name", "repro.serve"))
+    te.extend(_meta(_PID_SERVE, _TID_ENGINE, "thread_name", "engine",
+                    sort=-1))
     te.extend(_meta(_PID_SERVE, _TID_QUEUE, "thread_name", "queue", sort=0))
     for s in range(max_slot + 1):
         te.extend(_meta(_PID_SERVE, 1 + s, "thread_name", f"slot {s}",
@@ -394,11 +409,13 @@ def perfetto(events: Iterable[Event], *,
                        "ts": max(us(t) - round(dur, 3), 0.0),
                        "dur": round(dur, 3),
                        "args": {"rid": rid, "span": arg}})
-        elif etype == "DECODE_TICK":
-            te.append({"ph": "i", "pid": _PID_SERVE, "tid": _TID_QUEUE,
-                       "name": "decode_tick", "cat": "sched",
-                       "ts": us(t), "s": "p",
-                       "args": {"tick": arg}})
+        elif etype == "SPAN":
+            te.append({"ph": "X", "pid": _PID_SERVE, "tid": _TID_ENGINE,
+                       "name": arg[0], "cat": "engine",
+                       "ts": max(us(t) - round(dur, 3), 0.0),
+                       "dur": round(dur, 3),
+                       "args": {"parent": arg[1],
+                                "info": arg[2] if len(arg) > 2 else None}})
         elif etype == "EVICT":
             te.append({"ph": "i", "pid": _PID_SERVE, "tid": _TID_QUEUE,
                        "name": f"evict req {rid}", "cat": "sched",

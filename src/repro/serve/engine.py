@@ -25,9 +25,11 @@ benchmarking in ``benchmarks/serve_stream.py``.
 
 Every request is traced through :mod:`repro.obs`: admission wait, time
 to first token, end-to-end latency (all measured from ``submit``),
-slot occupancy, queue depth, preemptions and block-pool usage — the
-numbers the serving-scale ROADMAP items are judged by
-(``BENCH_serve.json`` via ``benchmarks/serve_stream.py``).
+slot occupancy and preemptions — the numbers the serving-scale ROADMAP
+items are judged by (``BENCH_serve.json`` via
+``benchmarks/serve_stream.py``).  The paged engine's host loop writes
+its own spans (``serve.*``, :class:`repro.obs.span`) into the flight
+recorder, on the clock the profiler's device trace uses.
 """
 from __future__ import annotations
 
@@ -135,11 +137,14 @@ class PagedEngine:
     Every lifecycle transition is recorded in the :data:`repro.obs.TRACE`
     flight recorder (REQ_ARRIVE here, ADMIT/RESUME/PREEMPT in the
     scheduler, EVICT in the cache map) so a single request's path
-    through the queue/slots is reconstructible after the fact; decode
-    steps are sampled 1-in-``TICK_SAMPLE`` to keep a long decode from
-    flushing the ring."""
-
-    TICK_SAMPLE = 8
+    through the queue/slots is reconstructible after the fact.  The
+    loop's host work is recorded there too, as spans whose names
+    readers rely on: ``serve.step`` (one iteration that did work) holds
+    ``serve.admit``, ``serve.decode_issue`` (info: live slots and their
+    context sum), ``serve.prefill_chunk`` (holding ``serve.prefill_sample``
+    on a final chunk, info: microseconds of its host sync) and
+    ``serve.drain`` (holding one ``serve.drain_wait`` per token array
+    pulled to the host); ``serve.preempt`` marks a preemption."""
 
     def __init__(self, model: Model, params, be: Optional[Policy] = None,
                  *, slots: int = 4, max_len: int = 256, eos: int = 2,
@@ -169,7 +174,6 @@ class PagedEngine:
         self.state = SlotStateStore(slots)
         self.scheduler = sched.SlotScheduler(self.cache, slots, self.state)
         self.done: Dict[int, List[int]] = {}
-        self._decode_steps = 0
         dtype = model.cfg.compute_dtype
         self._ps = model.init_paged_state(num_blocks, block_size, slots,
                                           dtype)
@@ -201,38 +205,43 @@ class PagedEngine:
         self.scheduler.submit(seq, fit_tokens=worst)
 
     def step(self) -> bool:
-        """One scheduler iteration; False when fully idle."""
-        worked = False
-        now = time.perf_counter()
-        for seq in self.scheduler.admit():
-            worked = True
-            if not seq.admitted_once:
-                seq.admitted_once = True
-                obs.histogram("serve.admission_wait_us").record(
-                    (now - seq.req.t_submit) * 1e6)
-        dec = [q for q in self.scheduler.decoding() if q.budget_left > 0]
-        for q in list(dec):
-            if q.state == sched.DECODE:
-                self._ensure(q, q.pos + 1)
-        dec = [q for q in self.scheduler.decoding() if q.budget_left > 0]
-        if dec:
-            self._issue_decode(dec)
-            worked = True
-        pre = self.scheduler.next_prefill()
-        if pre is not None:
-            self._prefill_chunk(pre)
-            worked = True
-        if self._pending and (
-                len(self._pending) >= self.drain_every
-                or not any(q.budget_left > 0
-                           for q in self.scheduler.decoding())):
-            self._drain()
-        if worked:
-            obs.histogram("serve.slot_occupancy").record(
-                self.scheduler.active() / self.slots)
-            obs.histogram("serve.queue_depth").record(
-                len(self.scheduler.queue))
-            obs.gauge("serve.blocks_in_use").set(self.cache.blocks_in_use)
+        """One scheduler iteration; False when fully idle.  An idle call
+        returns before it opens a span, so an idle loop records nothing:
+        with a request queued, in a slot or a token array pending, the
+        iteration admits, decodes, prefills or drains."""
+        if not (self.scheduler.has_work() or self._pending):
+            return False
+        with obs.span("serve.step"):
+            worked = False
+            if self.scheduler.queue and self.scheduler.active() < self.slots:
+                with obs.span("serve.admit"):
+                    now = time.perf_counter()
+                    for seq in self.scheduler.admit():
+                        worked = True
+                        if not seq.admitted_once:
+                            seq.admitted_once = True
+                            obs.histogram("serve.admission_wait_us").record(
+                                (now - seq.req.t_submit) * 1e6)
+            dec = [q for q in self.scheduler.decoding() if q.budget_left > 0]
+            for q in list(dec):
+                if q.state == sched.DECODE:
+                    self._ensure(q, q.pos + 1)
+            dec = [q for q in self.scheduler.decoding() if q.budget_left > 0]
+            if dec:
+                self._issue_decode(dec)
+                worked = True
+            pre = self.scheduler.next_prefill()
+            if pre is not None:
+                self._prefill_chunk(pre)
+                worked = True
+            if self._pending and (
+                    len(self._pending) >= self.drain_every
+                    or not any(q.budget_left > 0
+                               for q in self.scheduler.decoding())):
+                self._drain()
+            if worked:
+                obs.histogram("serve.slot_occupancy").record(
+                    self.scheduler.active() / self.slots)
         return worked
 
     def run(self) -> Dict[int, List[int]]:
@@ -290,93 +299,109 @@ class PagedEngine:
                     raise RuntimeError(
                         "block pool exhausted by a single sequence that "
                         "passed the admission fit check — pool leak?")
-                self.scheduler.preempt(victim)
+                with obs.span("serve.preempt", info=victim.rid):
+                    self.scheduler.preempt(victim)
                 if victim is seq:
                     return False
 
     def _issue_decode(self, dec: List[sched.Seq]) -> None:
-        bt = np.zeros((self.slots, self.cache.nmax), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        act = np.zeros((self.slots,), bool)
-        for q in dec:
-            bt[q.slot] = self.cache.row(q.rid)
-            pos[q.slot] = q.pos
-            act[q.slot] = True
-        self._cur, self._ps, self.key = self._decode_fn(
-            self.params, self._cur, self._ps,
-            jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(act), self.key)
-        self._pending.append((self._cur, [(q, q.slot) for q in dec]))
-        self._decode_steps += 1
-        if obs.TRACE.on and self._decode_steps % self.TICK_SAMPLE == 0:
-            obs.TRACE.emit("DECODE_TICK",
-                           arg=(self._decode_steps, len(dec)))
-        for q in dec:
-            q.pos += 1
-            q.inflight += 1
+        with obs.span("serve.decode_issue") as sp:
+            bt = np.zeros((self.slots, self.cache.nmax), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            act = np.zeros((self.slots,), bool)
+            ctx = 0
+            for q in dec:
+                bt[q.slot] = self.cache.row(q.rid)
+                pos[q.slot] = q.pos
+                act[q.slot] = True
+                ctx += q.pos + 1
+            sp.info = (len(dec), ctx)
+            self._cur, self._ps, self.key = self._decode_fn(
+                self.params, self._cur, self._ps,
+                jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(act),
+                self.key)
+            self._pending.append((self._cur, [(q, q.slot) for q in dec]))
+            for q in dec:
+                q.pos += 1
+                q.inflight += 1
 
     def _prefill_chunk(self, seq: sched.Seq) -> None:
-        p0, C = seq.pos, self.chunk
-        if not self._ensure(seq, p0 + C):
-            return                      # preempted itself; re-queued
-        target = seq.target
-        segment = target[p0:p0 + C]
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :len(segment)] = segment
-        final = (p0 + len(segment)) == len(target)
-        last_idx = np.int32(len(segment) - 1)
-        t_chunk = time.perf_counter()
-        row, self._ps = self._prefill_fn(
-            self.params, jnp.asarray(toks), self._ps,
-            jnp.asarray(self.cache.row(seq.rid)[None]),
-            jnp.asarray([p0], dtype=jnp.int32), np.int32(seq.slot),
-            np.int32(len(segment)), np.int32(len(seq.req.prompt)),
-            last_idx)
-        seq.pos = p0 + len(segment)
-        obs.counter("serve.prefill_chunks").inc()
-        obs.TRACE.emit(
-            "PREFILL_CHUNK", rid=seq.rid, slot=seq.slot,
-            arg=(p0, len(segment)),
-            dur_us=(time.perf_counter() - t_chunk) * 1e6)
-        if not final:
-            return
-        # host-side sample for the prefill boundary token only — every
-        # subsequent token is sampled inside the jit'd decode step
-        self.key, k = jax.random.split(self.key)
-        tok = int(np.asarray(sample(row, k, self.temperature,
-                                    self.model.cfg.vocab)))
-        seq.out.append(tok)
+        with obs.span("serve.prefill_chunk"):
+            p0, C = seq.pos, self.chunk
+            if not self._ensure(seq, p0 + C):
+                return                  # preempted itself; re-queued
+            target = seq.target
+            segment = target[p0:p0 + C]
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :len(segment)] = segment
+            final = (p0 + len(segment)) == len(target)
+            last_idx = np.int32(len(segment) - 1)
+            t_chunk = time.perf_counter()
+            row, self._ps = self._prefill_fn(
+                self.params, jnp.asarray(toks), self._ps,
+                jnp.asarray(self.cache.row(seq.rid)[None]),
+                jnp.asarray([p0], dtype=jnp.int32), np.int32(seq.slot),
+                np.int32(len(segment)), np.int32(len(seq.req.prompt)),
+                last_idx)
+            seq.pos = p0 + len(segment)
+            obs.counter("serve.prefill_chunks").inc()
+            obs.TRACE.emit(
+                "PREFILL_CHUNK", rid=seq.rid, slot=seq.slot,
+                arg=(p0, len(segment)),
+                dur_us=(time.perf_counter() - t_chunk) * 1e6)
+            if final:
+                self._sample_boundary(seq, row)
+
+    def _sample_boundary(self, seq: sched.Seq, row) -> None:
+        """Host-side sample of the prefill boundary token only — every
+        later token is sampled inside the jit'd decode step.  The
+        ``serve.prefill_sample`` span covers the sample up to its host
+        sync and the ``_cur`` update; its info is the sync's
+        microseconds."""
+        with obs.span("serve.prefill_sample") as sp:
+            self.key, k = jax.random.split(self.key)
+            tok = sample(row, k, self.temperature, self.model.cfg.vocab)
+            t_sync = time.perf_counter()
+            tok = int(np.asarray(tok))
+            sp.info = (time.perf_counter() - t_sync) * 1e6
+            seq.out.append(tok)
+            # like the wave reference, the request's FIRST token is
+            # exempt from EOS (a request always yields at least one
+            # token); a post-preemption boundary token is an ordinary
+            # decode token and does get the EOS check
+            done = (tok == self.eos and len(seq.out) > 1) \
+                or len(seq.out) >= seq.req.max_new
+            if not done:
+                self._cur = self._cur.at[seq.slot].set(tok)
         obs.counter("serve.tokens").inc()
         if len(seq.out) == 1:
             obs.histogram("serve.ttft_us").record(
                 (time.perf_counter() - seq.req.t_submit) * 1e6)
             obs.TRACE.emit("FIRST_TOKEN", rid=seq.rid, slot=seq.slot)
-        # like the wave reference, the request's FIRST token is exempt
-        # from EOS (a request always yields at least one token); a
-        # post-preemption boundary token is an ordinary decode token
-        # and does get the EOS check
-        if (tok == self.eos and len(seq.out) > 1) \
-                or len(seq.out) >= seq.req.max_new:
+        if done:
             self._finish(seq)
         else:
             seq.state = sched.DECODE
-            self._cur = self._cur.at[seq.slot].set(tok)
 
     def _drain(self) -> None:
         """Pull every pending decode token to the host in one pass and
         apply EOS / token-budget eviction with the (bounded) lag the
-        async pipeline allows."""
-        pend, self._pending = self._pending, []
-        for arr, entries in pend:
-            host = np.asarray(arr)
-            for q, slot in entries:
-                q.inflight -= 1
-                if q.state != sched.DECODE:
-                    continue            # evicted earlier in this drain
-                tok = int(host[slot])
-                q.out.append(tok)
-                obs.counter("serve.tokens").inc()
-                if tok == self.eos or len(q.out) >= q.req.max_new:
-                    self._finish(q)
+        async pipeline allows.  ``serve.drain`` holds one
+        ``serve.drain_wait`` per token array: the host sync alone."""
+        with obs.span("serve.drain"):
+            pend, self._pending = self._pending, []
+            for arr, entries in pend:
+                with obs.span("serve.drain_wait"):
+                    host = np.asarray(arr)
+                for q, slot in entries:
+                    q.inflight -= 1
+                    if q.state != sched.DECODE:
+                        continue        # evicted earlier in this drain
+                    tok = int(host[slot])
+                    q.out.append(tok)
+                    obs.counter("serve.tokens").inc()
+                    if tok == self.eos or len(q.out) >= q.req.max_new:
+                        self._finish(q)
 
     def _finish(self, seq: sched.Seq) -> None:
         self.done[seq.rid] = seq.out

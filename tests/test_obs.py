@@ -117,27 +117,55 @@ def test_histogram_percentile_all_zeros():
 
 # -- spans ------------------------------------------------------------------
 
+def _spans():
+    return [e for e in obs.TRACE.snapshot() if e[1] == "SPAN"]
+
+
 def test_span_nesting_builds_dotted_paths():
+    """Each span's ring record names its parent, children close first,
+    and each child lies inside its parent on the recorder's clock."""
     with obs.span("outer"):
         with obs.span("inner"):
             pass
-        with obs.span("inner"):
+        with obs.span("inner", info=(4, 17)):
             pass
     with obs.span("outer"):
         pass
-    outer = obs.REGISTRY.get("span.outer_us")
-    inner = obs.REGISTRY.get("span.outer.inner_us")
-    assert outer.count == 2
-    assert inner.count == 2
-    assert obs.REGISTRY.get("span.inner_us") is None
+    ev = _spans()
+    assert [e[4] for e in ev] == [("inner", "outer"),
+                                  ("inner", "outer", (4, 17)),
+                                  ("outer", None), ("outer", None)]
+    outer = ev[2]
+    for child in ev[:2]:
+        assert outer[0] - outer[5] * 1e-6 <= child[0] - child[5] * 1e-6
+        assert child[0] <= outer[0]
+    assert obs.REGISTRY.collect("span.") == {}   # no histogram any more
 
 
 def test_span_records_elapsed_time():
     import time
-    with obs.span("t.sleep"):
+    t_before = time.perf_counter()
+    with obs.span("t.sleep") as sp:
         time.sleep(0.01)
-    h = obs.REGISTRY.get("span.t.sleep_us")
-    assert h.count == 1 and h.vmin >= 9e3
+        sp.info = "slept"
+    (t, etype, rid, slot, arg, dur_us), = _spans()
+    assert dur_us >= 9e3 and arg == ("t.sleep", None, "slept")
+    assert t_before + dur_us * 1e-6 <= t <= time.perf_counter()
+    assert rid == slot == -1
+
+
+def test_span_recorder_off():
+    obs.TRACE.set_enabled(False)         # REPRO_TRACE=0 with metrics on
+    try:
+        with obs.span("t.off"):
+            with obs.span("t.off.inner"):
+                pass
+    finally:
+        obs.TRACE.set_enabled(True)
+    assert len(obs.TRACE) == 0
+    with obs.span("after"):              # the nesting stack stayed clean
+        pass
+    assert [e[4] for e in _spans()] == [("after", None)]
 
 
 # -- the Router shape log / decision memo -----------------------------------
@@ -347,9 +375,9 @@ def test_disabled_is_noop_everywhere():
         pass
     _route_batch(Policy(backend="auto"))
     assert obs.ROUTES.total == 0
+    assert len(obs.TRACE) == 0           # no SPAN event either
     obs.set_enabled(True)
     assert obs.REGISTRY.snapshot() == {} # nothing leaked into the registry
-    assert obs.REGISTRY.get("span.t.dead_span_us") is None
 
 
 def test_disabled_routing_still_correct():

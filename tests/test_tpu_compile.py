@@ -9,6 +9,7 @@ at import: only one process may load the TPU library, and the test
 workers all import this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import api, configs
-from repro.kernels import grouped_gemm
+from repro.core import kernelgen, plan as plan_mod
+from repro.kernels import grouped_gemm, iaat_gemm
 from repro.models.registry import build
 from repro.serve import paged_step_fns
 
@@ -61,6 +63,20 @@ def _check(compiled, n_kernels=1):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def _named_kernel(compiled, name):
+    """The kernel's custom call is the HLO instruction named after its
+    ``pallas_call``: the device trace shows that text."""
+    pat = re.compile(rf"%{re.escape(name)}(\.\d+)? = [^\n]*custom-call\("
+                     rf"[^\n]*{re.escape(KERNEL)}")
+    assert pat.search(compiled.as_text()), name
+
+
+def _kernels_named(compiled):
+    """Every Pallas kernel of a serving step is a named IAAT GEMM."""
+    calls = [ln for ln in compiled.as_text().splitlines() if KERNEL in ln]
+    assert calls and all(re.search(r"%iaat_[\w.]+ = ", ln) for ln in calls)
+
+
 # M < bm (16 for bf16) with a ragged K tail: Mosaic refused the packed
 # K-mask select for all three before the mask selected in f32.
 @pytest.mark.parametrize("M,K,N,dtype", [
@@ -77,6 +93,10 @@ def test_routed_gemm_compiles(one_chip, M, K, N, dtype):
     compiled = jax.jit(lambda x, w: api.matmul(x, w, policy=pol)) \
         .lower(x, w).compile()
     _check(compiled)
+    plan = plan_mod.build_plan(M, N, K, kernelgen.blas_letter(dtype), "NN",
+                               pol.method)
+    for region in plan.regions:
+        _named_kernel(compiled, iaat_gemm.call_name(region.sig))
 
 
 def test_grouped_gemm_short_rows_ragged_k_compiles(one_chip):
@@ -85,6 +105,8 @@ def test_grouped_gemm_short_rows_ragged_k_compiles(one_chip):
     compiled = jax.jit(lambda x, w: grouped_gemm.batched_gemm(
         x, w, interpret=False)).lower(x, w).compile()
     _check(compiled)
+    bm, bn, bk = grouped_gemm.pick_blocks(4, 3000, 256, jnp.bfloat16)
+    _named_kernel(compiled, f"iaat_batched_gemm_{bm}x{bn}x{bk}")
 
 
 def _compile_paged_decode(cfg, one_chip, slots):
@@ -110,11 +132,15 @@ def test_mamba2_780m_paged_decode_step_compiles(one_chip):
     """Full width, all 48 layers, 4 slots: out_proj (4,3072)@(3072,1536)
     routes to Pallas under ``auto`` and must lower to a TPU kernel."""
     cfg = configs.get_config("mamba2-780m")
-    _check(_compile_paged_decode(cfg, one_chip, slots=4))
+    compiled = _compile_paged_decode(cfg, one_chip, slots=4)
+    _check(compiled)
+    _kernels_named(compiled)
 
 
 def test_glm4_9b_cut_paged_decode_step_compiles(one_chip):
     """Published widths, depth cut to 4 of 40 layers: the k/v
     projections (4,4096)@(4096,256) route to Pallas under ``auto``."""
     cfg = dataclasses.replace(configs.get_config("glm4-9b"), n_layers=4)
-    _check(_compile_paged_decode(cfg, one_chip, slots=4), n_kernels=2)
+    compiled = _compile_paged_decode(cfg, one_chip, slots=4)
+    _check(compiled, n_kernels=2)
+    _kernels_named(compiled)

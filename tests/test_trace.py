@@ -33,7 +33,7 @@ def _clean_obs():
 def test_ring_drops_oldest_and_counts_drops():
     log = trace.EventLog(capacity=4)
     for i in range(10):
-        log.emit("DECODE_TICK", arg=i)
+        log.emit("SPAN", arg=i)
     assert len(log) == 4
     assert log.n_total == 10 and log.dropped == 6
     # the ring keeps the most recent window, oldest-first
@@ -77,7 +77,7 @@ def test_ring_threaded_emits_never_corrupt():
     def work():
         try:
             for _ in range(n):
-                log.emit("DECODE_TICK")
+                log.emit("SPAN")
         except Exception as e:           # pragma: no cover
             errors.append(e)
 
@@ -88,7 +88,7 @@ def test_ring_threaded_emits_never_corrupt():
     for t in threads:
         t.join()
     assert not errors
-    assert all(e[1] == "DECODE_TICK" for s in snaps for e in s)
+    assert all(e[1] == "SPAN" for s in snaps for e in s)
     assert len(log) == 256
     assert log.dropped == log.n_total - 256
     assert log.n_total <= n * nthreads   # += under the GIL never overcounts
@@ -166,7 +166,8 @@ def test_reducer_tolerates_partial_trace():
 
 
 def test_reducer_skips_batch_and_router_events():
-    evs = [(0.0, "DECODE_TICK", -1, -1, (8, 2), None),
+    evs = [(0.0, "SPAN", -1, -1, ("serve.decode_issue", "serve.step",
+                                   (2, 40)), 12.5),
            (0.1, "ROUTE_MISS", -1, -1, ("gemm", "S", "NN", [4, 8, 8],
                                         "analytical"), None)]
     assert trace.per_request(evs) == {}
@@ -339,6 +340,133 @@ def test_paged_engine_emits_full_lifecycle():
     for rid in preempted:
         assert any(x["name"] == f"req {rid} queued (preempted)"
                    for x in slices)
+
+
+def _span_tree(evs):
+    """SPAN records as dicts with their start and end (seconds)."""
+    return [{"name": a[0], "parent": a[1], "info": a[2] if len(a) > 2
+             else None, "t0": t - d * 1e-6, "t1": t}
+            for t, k, _r, _s, a, d in evs if k == "SPAN"]
+
+
+def _inside(child, spans, name):
+    return any(p["name"] == name and p["t0"] <= child["t0"]
+               and child["t1"] <= p["t1"] for p in spans)
+
+
+def test_paged_engine_span_tree():
+    """Every working iteration records its span tree, each child inside
+    its parent; an idle step() records nothing, and nothing at all is
+    recorded with obs off."""
+    cfg = configs.get_smoke("olmo-1b")
+    model = registry.build(cfg)
+    params = model.init(KEY)
+    rng = np.random.RandomState(3)
+
+    def engine():
+        e = PagedEngine(model, params, XLA, slots=2, max_len=40, eos=-1,
+                        block_size=8, chunk=8, drain_every=2)
+        for rid, n in enumerate((5, 19, 11)):
+            e.submit(Request(rid, rng.randint(0, cfg.vocab, n)
+                             .astype(np.int32), max_new=6))
+        return e
+
+    e = engine()
+    working = idle = 0
+    while e.scheduler.has_work() or e._pending:
+        n0 = len(obs.TRACE)
+        worked = e.step()
+        names = [x["name"] for x in _span_tree(obs.TRACE.snapshot()[n0:])]
+        if names:
+            working += 1
+            assert names[-1] == "serve.step"     # the root closes last
+        else:
+            idle += 1
+            assert not worked and len(obs.TRACE) == n0
+    assert len(e.done) == 3
+
+    spans = _span_tree(obs.TRACE.snapshot())
+    steps = [x for x in spans if x["name"] == "serve.step"]
+    assert len(steps) == working
+    by = lambda n: [x for x in spans if x["name"] == n]  # noqa: E731
+    for name, parent in (("serve.admit", "serve.step"),
+                         ("serve.decode_issue", "serve.step"),
+                         ("serve.prefill_chunk", "serve.step"),
+                         ("serve.drain", "serve.step"),
+                         ("serve.drain_wait", "serve.drain"),
+                         ("serve.prefill_sample", "serve.prefill_chunk")):
+        assert by(name), name
+        for x in by(name):
+            assert x["parent"] == parent and _inside(x, spans, parent)
+    # one boundary sample per final chunk, i.e. per first token here
+    firsts = [ev for ev in obs.TRACE.snapshot() if ev[1] == "FIRST_TOKEN"]
+    assert len(by("serve.prefill_sample")) == len(firsts) == 3
+    assert all(0 <= x["info"] <= (x["t1"] - x["t0"]) * 1e6
+               for x in by("serve.prefill_sample"))
+    # decode issues carry (live slots, context sum)
+    assert all(1 <= x["info"][0] <= 2 and x["info"][1] > x["info"][0]
+               for x in by("serve.decode_issue"))
+    n = len(obs.TRACE)
+    assert e.step() is False and len(obs.TRACE) == n
+
+    obs.set_enabled(False)
+    off = engine()
+    assert len(off.run()) == 3
+    assert len(obs.TRACE) == n                   # nothing while off
+
+
+def test_paged_engine_preempt_span():
+    cfg = configs.get_smoke("olmo-1b")
+    model = registry.build(cfg)
+    params = model.init(KEY)
+    rng = np.random.RandomState(2)
+    e = PagedEngine(model, params, XLA, slots=2, max_len=24, eos=-1,
+                    block_size=8, chunk=8, num_blocks=4)
+    for rid in range(2):
+        e.submit(Request(rid, rng.randint(0, cfg.vocab, 7).astype(np.int32),
+                         max_new=10))
+    e.run()
+    evs = obs.TRACE.snapshot()
+    spans = _span_tree(evs)
+    pre = [x for x in spans if x["name"] == "serve.preempt"]
+    victims = [ev[2] for ev in evs if ev[1] == "PREEMPT"]
+    assert victims and [x["info"] for x in pre] == victims
+    assert all(x["parent"] is not None for x in pre)
+
+
+def test_perfetto_engine_track_roundtrip(tmp_path):
+    """SPAN events render on one "engine" track of the serve process,
+    nested by time, and survive the write_trace/load_events round trip."""
+    evs = _two_request_stream() + [
+        (4.0 * _T, "SPAN", -1, -1, ("serve.decode_issue", "serve.step",
+                                    (2, 31)), 300.0),
+        (5.0 * _T, "SPAN", -1, -1, ("serve.drain_wait", "serve.drain"),
+                                    100.0),
+        (5.2 * _T, "SPAN", -1, -1, ("serve.drain", "serve.step"), 400.0),
+        (5.5 * _T, "SPAN", -1, -1, ("serve.step", None), 2000.0),
+    ]
+    back = trace.load_events(trace.write_trace(tmp_path / "t.json", evs,
+                                               slots=3))
+    assert trace.per_request(back) == trace.per_request(evs)
+    te = trace.perfetto(back, slots=3)["traceEvents"]
+    tracks = {(e["pid"], e["tid"]): e["args"]["name"] for e in te
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    engine = [k for k, v in tracks.items() if v == "engine"]
+    assert len(engine) == 1 and engine[0][0] == 1    # the repro.serve pid
+    sl = {e["name"]: e for e in te if e["ph"] == "X"
+          and (e["pid"], e["tid"]) == engine[0]}
+    assert set(sl) == {"serve.step", "serve.decode_issue", "serve.drain",
+                       "serve.drain_wait"}
+    assert all(e["ts"] >= 0 for e in sl.values())
+    for child, parent in (("serve.decode_issue", "serve.step"),
+                          ("serve.drain", "serve.step"),
+                          ("serve.drain_wait", "serve.drain")):
+        c, p = sl[child], sl[parent]
+        assert c["args"]["parent"] == parent
+        assert p["ts"] <= c["ts"] + 1e-3
+        assert c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-3
+    assert sl["serve.step"]["dur"] == pytest.approx(2000.0)
+    assert sl["serve.decode_issue"]["args"]["info"] == [2, 31]
 
 
 # --------------------------------------------------------------------------
