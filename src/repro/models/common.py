@@ -86,11 +86,5 @@ def assert_same_structure(params: Params, specs: Specs) -> None:
         raise ValueError(f"param/spec structure drift:\n{pt}\nvs\n{st}")
 
 
-def cast_tree(params: Params, dtype) -> Params:
-    return jax.tree.map(
-        lambda p: p.astype(dtype)
-        if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
-
-
 def count_params(params: Params) -> int:
     return sum(int(p.size) for p in jax.tree.leaves(params))

@@ -318,6 +318,12 @@ def moe_specs(cfg: ModelConfig) -> Dict:
             "w_down": ("experts", "expert_mlp", "embed")}
 
 
+#: MoE leaves read in float32: the router's logits are computed in
+#: float32 (:func:`_moe_dispatch`).  Attention and MLP weights, like the
+#: experts, are consumed in the compute dtype only.
+MOE_F32_LEAVES = frozenset({"router"})
+
+
 def _capacity(T: int, m) -> int:
     c = int(math.ceil(T * m.top_k / m.num_experts * m.capacity_factor))
     # 128-multiples: MXU-aligned AND divisible by the data axis so the

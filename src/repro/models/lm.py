@@ -176,6 +176,37 @@ def lm_specs(cfg: ModelConfig) -> Dict:
     return specs
 
 
+#: Leaves read in float32 whatever the compute dtype, by their key: the
+#: norm scales (``rmsnorm`` multiplies in float32) and each family's own.
+#: Every other floating leaf is consumed only after a cast to
+#: ``compute_dtype``: ``common.mm``, the embedding gather, the experts.
+F32_LEAVES = (frozenset({"ln1", "ln2", "final_norm"})
+              | S.MAMBA_F32_LEAVES | L.MOE_F32_LEAVES)
+
+
+def serving_params(params: Dict, cfg: ModelConfig) -> Dict:
+    """The param tree in the dtypes the serving steps consume it in:
+    every floating leaf outside :data:`F32_LEAVES` cast to
+    ``compute_dtype`` in one jitted pass, the rest the same arrays.  A
+    step's own ``w.astype(x.dtype)`` is then a no-op, so no step converts
+    a weight stack again, and the logits are bit-for-bit those of the
+    uncast tree.  The identity when ``param_dtype`` is the compute
+    dtype."""
+    dt = cfg.compute_dtype
+    if jnp.dtype(cfg.param_dtype) == dt:
+        return params
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [p for _, p in flat]
+    idx = [i for i, (path, p) in enumerate(flat)
+           if path[-1].key not in F32_LEAVES
+           and jnp.issubdtype(p.dtype, jnp.floating) and p.dtype != dt]
+    cast = jax.jit(lambda ws: [w.astype(dt) for w in ws])(
+        [leaves[i] for i in idx])
+    for i, w in zip(idx, cast):
+        leaves[i] = w
+    return jax.tree_util.tree_unflatten(tree, leaves)
+
+
 # --------------------------------------------------------------------------
 # Block application (shared by all modes).
 # --------------------------------------------------------------------------

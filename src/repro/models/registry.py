@@ -33,6 +33,9 @@ class Model:
     # ^ (params, batch, ps, tables, pos, active, be) -> (logits, ps)
     init_paged_state: Optional[Callable] = None
     # ^ (num_blocks, block_size, slots, dtype) -> lm.PagedState
+    serving_params: Optional[Callable] = None
+    # ^ (params) -> params with the weights in compute_dtype
+    #   (lm.serving_params); PagedEngine casts once at build
 
 
 def build(cfg: ModelConfig) -> Model:
@@ -88,4 +91,5 @@ def build(cfg: ModelConfig) -> Model:
     return Model(cfg, lambda key: lm.init_lm(key, cfg),
                  lambda: lm.lm_specs(cfg), fwd, pf, dec, mk_cache,
                  paged_prefill=ppf, paged_decode=pdec,
-                 init_paged_state=mk_ps)
+                 init_paged_state=mk_ps,
+                 serving_params=lambda p: lm.serving_params(p, cfg))

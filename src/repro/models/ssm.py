@@ -64,6 +64,13 @@ def mamba_specs(cfg: ModelConfig) -> Dict:
     }
 
 
+#: Mamba leaves the serving path reads in float32 (the conv window and
+#: the SSM scalars, the gated norm's scale); ``in_proj`` and ``out_proj``
+#: are consumed in the compute dtype (see ``lm.serving_params``).
+MAMBA_F32_LEAVES = frozenset({"conv_w", "conv_b", "A_log", "D", "dt_bias",
+                              "norm_w"})
+
+
 def _causal_conv(x, w, b):
     """Depthwise causal conv via shifted adds. x: (B,S,ch); w: (K,ch)."""
     K = w.shape[0]

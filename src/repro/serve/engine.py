@@ -156,7 +156,21 @@ class PagedEngine:
                 f"{model.cfg.name}: family {model.cfg.family!r} has no "
                 "paged serving path")
         be = be if be is not None else api.current_policy()
-        self.model, self.params, self.be = model, params, be
+        self.model, self.be = model, be
+        # the weights in the dtype the steps consume them in, cast once
+        # here instead of in every decode step and prefill chunk
+        with obs.span("serve.cast_params"):
+            self.params = jax.block_until_ready(
+                model.serving_params(params))
+        cast = kept = 0
+        for was, now in zip(jax.tree.leaves(params),
+                            jax.tree.leaves(self.params)):
+            if now.dtype != was.dtype:
+                cast += now.nbytes
+            elif jnp.issubdtype(was.dtype, jnp.floating):
+                kept += was.nbytes
+        obs.counter("serve.params_cast_bytes").inc(cast)
+        obs.counter("serve.params_kept_bytes").inc(kept)
         # optional repro.tune.online.OnlineTuner: run() starts it and
         # stops it on drain, so `--online-tune` serving re-tunes hot
         # classes in the background for exactly the engine's lifetime

@@ -109,30 +109,61 @@ def test_grouped_gemm_short_rows_ragged_k_compiles(one_chip):
     _named_kernel(compiled, f"iaat_batched_gemm_{bm}x{bn}x{bk}")
 
 
-def _compile_paged_decode(cfg, one_chip, slots):
-    """Lower the engine's decode step exactly as PagedEngine jits it."""
+CHUNK = 32                  # PagedEngine's default prefill chunk
+
+
+def _weight_shapes(template, served):
+    """What a per-step cast of a weight would convert: each leaf that
+    ``serving_params`` casts, whole and as the layer scan's slice."""
+    shapes = set()
+    for (path, p), s in zip(jax.tree_util.tree_flatten_with_path(template)[0],
+                            jax.tree.leaves(served)):
+        if s.dtype != p.dtype:
+            shapes.add(p.shape)
+            if path[0].key == "blocks":
+                shapes.add(p.shape[1:])
+    return {"x".join(map(str, sh)) for sh in shapes}
+
+
+def _compile_paged_step(cfg, one_chip, slots, step="decode"):
+    """Lower the engine's decode step or prefill chunk exactly as
+    PagedEngine jits it, over the params it holds
+    (``model.serving_params``); the lowered step converts no float32
+    weight."""
     model = build(cfg)
-    decode, _ = paged_step_fns(model, api.named_policy("auto",
-                                                       interpret=False))
+    decode, prefill = paged_step_fns(model, api.named_policy(
+        "auto", interpret=False))
     block_size, nmax = 16, 256 // 16              # max_len 256
-    params = _sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                  one_chip)
+    template = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    served = jax.eval_shape(model.serving_params, template)
+    params = _sds(served, one_chip)
     ps = _sds(jax.eval_shape(lambda: model.init_paged_state(
         1 + slots * nmax, block_size, slots, cfg.compute_dtype)), one_chip)
 
     def arr(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    return jax.jit(decode, donate_argnums=(2,)).lower(
-        params, arr((slots,)), ps, arr((slots, nmax)), arr((slots,)),
-        arr((slots,), bool), arr((2,), jnp.uint32)).compile()
+    if step == "decode":
+        lowered = jax.jit(decode, donate_argnums=(2,)).lower(
+            params, arr((slots,)), ps, arr((slots, nmax)), arr((slots,)),
+            arr((slots,), bool), arr((2,), jnp.uint32))
+    else:
+        lowered = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, arr((1, CHUNK)), ps, arr((1, nmax)), arr((1,)), arr(()),
+            arr(()), arr(()), arr(()))
+    converted = set(re.findall(
+        r"stablehlo\.convert %\S+ : \(tensor<([0-9x]*)xf32>\)",
+        lowered.as_text()))
+    weights = _weight_shapes(template, served)
+    assert weights and not converted & weights
+    return lowered.compile()
 
 
 def test_mamba2_780m_paged_decode_step_compiles(one_chip):
     """Full width, all 48 layers, 4 slots: out_proj (4,3072)@(3072,1536)
     routes to Pallas under ``auto`` and must lower to a TPU kernel."""
     cfg = configs.get_config("mamba2-780m")
-    compiled = _compile_paged_decode(cfg, one_chip, slots=4)
+    compiled = _compile_paged_step(cfg, one_chip, slots=4)
     _check(compiled)
     _kernels_named(compiled)
 
@@ -141,6 +172,22 @@ def test_glm4_9b_cut_paged_decode_step_compiles(one_chip):
     """Published widths, depth cut to 4 of 40 layers: the k/v
     projections (4,4096)@(4096,256) route to Pallas under ``auto``."""
     cfg = dataclasses.replace(configs.get_config("glm4-9b"), n_layers=4)
-    compiled = _compile_paged_decode(cfg, one_chip, slots=4)
+    compiled = _compile_paged_step(cfg, one_chip, slots=4)
     _check(compiled, n_kernels=2)
     _kernels_named(compiled)
+
+
+def test_mamba2_780m_paged_prefill_step_compiles(one_chip):
+    """Full width, all 48 layers: one 32-token prefill chunk over the
+    bfloat16 serving weights."""
+    cfg = configs.get_config("mamba2-780m")
+    compiled = _compile_paged_step(cfg, one_chip, slots=4, step="prefill")
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_glm4_9b_cut_paged_prefill_step_compiles(one_chip):
+    """Published widths, depth cut to 4 of 40 layers: one 32-token
+    prefill chunk over the bfloat16 serving weights."""
+    cfg = dataclasses.replace(configs.get_config("glm4-9b"), n_layers=4)
+    compiled = _compile_paged_step(cfg, one_chip, slots=4, step="prefill")
+    assert _device_bytes(compiled) < HBM_BYTES
