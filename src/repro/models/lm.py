@@ -544,7 +544,10 @@ def _paged_core(params, cfg: ModelConfig, be: Policy, x, ps: PagedState,
                 decode_from=None):
     """Layer stack shared by paged prefill chunks and slot decode.
     ``conv``/``ssm_h`` are (L, B, ...) rows aligned with x's batch dim
-    (callers slice/scatter the slot rows); K/V route through
+    (callers slice/scatter the slot rows); they ride in the layer scan's
+    carry and each layer reads its row and writes it back in place, so a
+    donated state stack is updated where it lies rather than rebuilt as
+    a stacked scan output and copied over.  K/V route through
     ``block_tables`` into the pools; ``decode_from`` (B,) marks the
     original decode boundary so recompute-resume chunks replay those
     rows with decode numerics (see layers.paged_attend).  Returns
@@ -552,8 +555,8 @@ def _paged_core(params, cfg: ModelConfig, be: Policy, x, ps: PagedState,
     idxs = jnp.arange(cfg.n_layers)
     if cfg.family in ("ssm", "hybrid"):
         def body(carry, xs):
-            x, sk, sv = carry
-            blk, i, cv, hh = xs
+            x, conv, ssm_h, sk, sv = carry
+            blk, i = xs
             if cfg.shared_attn_every:
                 app = i // cfg.shared_attn_every
 
@@ -568,12 +571,16 @@ def _paged_core(params, cfg: ModelConfig, be: Policy, x, ps: PagedState,
                                      apply, lambda x, sk, sv: (x, sk, sv),
                                      x, sk, sv)
             h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
-            y, (cv, hh) = S.paged_step(blk["mixer"], h, be, cfg, (cv, hh),
+            row = (lax.dynamic_index_in_dim(conv, i, 0, keepdims=False),
+                   lax.dynamic_index_in_dim(ssm_h, i, 0, keepdims=False))
+            y, (cv, hh) = S.paged_step(blk["mixer"], h, be, cfg, row,
                                        seg_len=seg_len, active=active)
-            return (x + y, sk, sv), (cv, hh)
-        (x, sk, sv), (conv_new, ssm_new) = lax.scan(
-            body, (x, ps.shared_k, ps.shared_v),
-            (params["blocks"], idxs, conv, ssm_h))
+            conv = lax.dynamic_update_index_in_dim(conv, cv, i, 0)
+            ssm_h = lax.dynamic_update_index_in_dim(ssm_h, hh, i, 0)
+            return (x + y, conv, ssm_h, sk, sv), None
+        (x, conv_new, ssm_new, sk, sv), _ = lax.scan(
+            body, (x, conv, ssm_h, ps.shared_k, ps.shared_v),
+            (params["blocks"], idxs))
         ps = dataclasses.replace(ps, shared_k=sk, shared_v=sv)
     else:
         def body(carry, xs):

@@ -99,40 +99,53 @@ def test_scheduler_keeps_store_in_lockstep():
 # Masked decode: inactive slot rows are bitwise frozen (device).
 # --------------------------------------------------------------------------
 
-def test_masked_decode_freezes_inactive_carries(get_model):
+@pytest.mark.parametrize("arch", ["mamba2-780m", "zamba2-7b"])
+def test_masked_decode_freezes_inactive_carries(get_model, arch):
     """A decode step with a slot masked inactive must leave that slot's
     conv/ssm rows bitwise unchanged and touch no pool block but the
-    null sink (block 0) — zamba2 exercises both the recurrent rows and
-    the shared-attention pool in one model."""
-    cfg, model, params = get_model("zamba2-7b")
+    null sink (block 0), while each active slot's rows equal the wave
+    oracle's carry after the same tokens — mamba2 exercises the
+    recurrent rows alone, zamba2 the rows and the shared-attention pool
+    in one model."""
+    cfg, model, params = get_model(arch)
     slots = 3
-    ps = model.init_paged_state(4, 8, slots)
-    bt = jnp.zeros((slots, 4), jnp.int32)           # all-null tables
+    ps = model.init_paged_state(1 + slots, 8, slots)
+    null = jnp.zeros((slots, 4), jnp.int32)         # all-null tables
+    own = null.at[:, 0].set(jnp.arange(1, slots + 1, dtype=jnp.int32))
     pos = jnp.zeros((slots,), jnp.int32)
     toks = {"tokens": jnp.arange(1, slots + 1, dtype=jnp.int32)[:, None]}
+    nxt = {"tokens": toks["tokens"] + slots}
+
+    def same(a, b):
+        return bool(jnp.all(a == b))
+
     # one all-active step so the carries are non-zero (a frozen zero
-    # row proves nothing)
-    _, ps1 = model.paged_decode(params, toks, ps, bt, pos,
+    # row proves nothing); the oracle primes its cache on the same token
+    _, ps1 = model.paged_decode(params, toks, ps, own, pos,
                                 jnp.ones((slots,), bool), XLA)
     assert bool(jnp.any(ps1.conv != 0)) and bool(jnp.any(ps1.ssm != 0))
+    _, wave = model.prefill(params, toks, XLA, cache_len=2)
+    assert same(ps1.conv, wave.conv) and same(ps1.ssm, wave.ssm)
 
     # all-inactive step: different tokens, nothing may move
-    _, ps2 = model.paged_decode(params, toks, ps1, bt, pos + 1,
+    _, ps2 = model.paged_decode(params, nxt, ps1, null, pos + 1,
                                 jnp.zeros((slots,), bool), XLA)
-    assert bool(jnp.all(ps2.conv == ps1.conv))
-    assert bool(jnp.all(ps2.ssm == ps1.ssm))
-    # writes landed in the null sink only
-    assert bool(jnp.all(ps2.shared_k[:, 1:] == ps1.shared_k[:, 1:]))
-    assert bool(jnp.all(ps2.shared_v[:, 1:] == ps1.shared_v[:, 1:]))
+    assert same(ps2.conv, ps1.conv) and same(ps2.ssm, ps1.ssm)
+    if ps.shared_k is not None:         # writes landed in the null sink only
+        assert same(ps2.shared_k[:, 1:], ps1.shared_k[:, 1:])
+        assert same(ps2.shared_v[:, 1:], ps1.shared_v[:, 1:])
 
-    # mixed step: only the active slot's rows advance
+    # mixed step: only the active slot's rows advance, as the oracle's do
     act = jnp.array([False, True, False])
-    _, ps3 = model.paged_decode(params, toks, ps2, bt, pos + 1, act, XLA)
-    assert bool(jnp.all(ps3.conv[:, 0] == ps2.conv[:, 0]))
-    assert bool(jnp.all(ps3.conv[:, 2] == ps2.conv[:, 2]))
-    assert bool(jnp.all(ps3.ssm[:, 0] == ps2.ssm[:, 0]))
-    assert bool(jnp.all(ps3.ssm[:, 2] == ps2.ssm[:, 2]))
+    _, ps3 = model.paged_decode(params, nxt, ps2, null.at[1].set(own[1]),
+                                pos + 1, act, XLA)
+    for s in (0, 2):
+        assert same(ps3.conv[:, s], ps2.conv[:, s])
+        assert same(ps3.ssm[:, s], ps2.ssm[:, s])
     assert bool(jnp.any(ps3.ssm[:, 1] != ps2.ssm[:, 1]))
+    _, wave = model.decode(params, nxt, wave, XLA)
+    assert same(ps3.conv[:, 1], wave.conv[:, 1])
+    assert same(ps3.ssm[:, 1], wave.ssm[:, 1])
 
 
 # --------------------------------------------------------------------------

@@ -159,13 +159,74 @@ def _compile_paged_step(cfg, one_chip, slots, step="decode"):
     return lowered.compile()
 
 
-def test_mamba2_780m_paged_decode_step_compiles(one_chip):
+MAMBA2_SLOTS = 4
+
+
+@pytest.fixture(scope="module")
+def mamba2_decode(one_chip):
+    """mamba2-780m's decode step at full width, all 48 layers, compiled
+    once for the tests that read it."""
+    cfg = configs.get_config("mamba2-780m")
+    return cfg, _compile_paged_step(cfg, one_chip, slots=MAMBA2_SLOTS)
+
+
+def test_mamba2_780m_paged_decode_step_compiles(mamba2_decode):
     """Full width, all 48 layers, 4 slots: out_proj (4,3072)@(3072,1536)
     routes to Pallas under ``auto`` and must lower to a TPU kernel."""
-    cfg = configs.get_config("mamba2-780m")
-    compiled = _compile_paged_step(cfg, one_chip, slots=4)
+    _, compiled = mamba2_decode
     _check(compiled)
     _kernels_named(compiled)
+
+
+def _hlo_type(x) -> str:
+    """``f32[48,4,48,64,128]``: an array's type as the HLO text prints it."""
+    short = {"float32": "f32", "bfloat16": "bf16"}
+    return f"{short[jnp.dtype(x.dtype).name]}[{','.join(map(str, x.shape))}]"
+
+
+def _tuple_types(s: str, i: int):
+    """The element types of the parenthesised list that opens at
+    ``s[i]``, each without its layout."""
+    parts, depth, start = [], 0, i + 1
+    for j in range(i, len(s)):
+        depth += s[j] in "([{"
+        depth -= s[j] in ")]}"
+        if depth == 1 and s[j] == ",":
+            parts.append(s[start:j])
+            start = j + 1
+        elif depth == 0:
+            parts.append(s[start:j])
+            break
+    return [re.match(r"\s*(\w+\[[\d,]*\])", t).group(1) for t in parts]
+
+
+def _state_aliases(text: str):
+    """The (output, parameter) pairs of the entry computation's
+    ``input_output_alias``, each given by its type."""
+    head = re.sub(r"/\*[^*]*\*/", "", text.split("\n", 1)[0])
+    key = "entry_computation_layout={"
+    i = head.index(key) + len(key)
+    ins = _tuple_types(head, i)
+    outs = _tuple_types(head, head.index(")->(", i) + 3)
+    return {(outs[int(o)], ins[int(p)]) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
+def test_mamba2_780m_paged_decode_updates_state_in_place(mamba2_decode):
+    """The decode step writes each layer's row of the donated slot state
+    where it lies: no copy of the whole ``ssm`` or ``conv`` stack, and
+    both stacks still come back in the donated buffers."""
+    cfg, compiled = mamba2_decode
+    text = compiled.as_text()
+    ps = jax.eval_shape(lambda: build(cfg).init_paged_state(
+        1, 16, MAMBA2_SLOTS, cfg.compute_dtype))
+    aliases = _state_aliases(text)
+    for stack in (ps.ssm, ps.conv):
+        typ = _hlo_type(stack)
+        assert stack.shape[:2] == (cfg.n_layers, MAMBA2_SLOTS)
+        assert not re.search(rf"= {re.escape(typ)}(\{{[^}}]*\}})? copy\(",
+                             text), typ
+        assert (typ, typ) in aliases, typ
 
 
 def test_glm4_9b_cut_paged_decode_step_compiles(one_chip):
