@@ -1,7 +1,6 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
 # Exits nonzero when any suite reports an ERROR row (CI regression gate).
-# ``--record`` additionally appends the serving headline numbers to the
-# BENCH_serve.json trajectory (the per-PR perf history).
+# Serving is measured on the chip by ``bench/run.py``, not here.
 from __future__ import annotations
 
 import os
@@ -18,26 +17,17 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 
 
 def main() -> None:
-    from benchmarks import (gemm_sweep, kernel_table, pack_cost, roofline,
-                            route_overhead, serve_stream, tiling_memops,
-                            tune_report)
-    record = "--record" in sys.argv[1:]
+    from benchmarks import (gemm_sweep, kernel_table, pack_cost,
+                            tiling_memops, tune_report)
     suites = [
         ("tiling_memops", tiling_memops.run),   # paper Fig. 2
         ("pack_cost", pack_cost.run),           # paper Fig. 3
         ("kernel_table", kernel_table.run),     # paper TABLE I
         ("gemm_sweep", gemm_sweep.run),         # paper Figs. 4-7
-        ("roofline", roofline.run),             # framework deliverable (g)
         ("tune_report", tune_report.run),       # empirical vs analytical
-        ("route_overhead", route_overhead.run),  # obs <5% gate
-        # Poisson serving stream, both engines; --record appends the
-        # per-PR trajectory row
-        ("serve_stream",
-         lambda rows: serve_stream.run(rows, record=record)),
     ]
     if "--quick" in sys.argv[1:]:
-        quick = {"tiling_memops", "kernel_table", "roofline", "tune_report",
-                 "route_overhead"}
+        quick = {"tiling_memops", "kernel_table", "tune_report"}
         suites = [s for s in suites if s[0] in quick]
     rows = []
     for name, fn in suites:

@@ -71,8 +71,8 @@ class Policy:
     ``backend`` picks the routing mode (how use-pallas is decided);
     ``kernels`` picks the non-GEMM kernel family (flash attention, SSD
     scan) — empty string derives it from ``backend``; ``iaat=False``
-    short-circuits framework matmuls straight to ``jnp.matmul`` (the
-    multi-pod dry-run mode that must stay XLA-compilable end to end).
+    short-circuits framework matmuls straight to ``jnp.matmul`` (a mode
+    that stays XLA-compilable end to end).
     """
     backend: str = "auto"          # pallas | xla | auto | tuned
     # pallas interpret mode; None = decided by the platform (compiled on
@@ -164,7 +164,7 @@ POLICY_NAMES = ("xla", "pallas", "auto", "tuned")
 def named_policy(name: str, *, interpret: Optional[bool] = None) -> Policy:
     """Build the Policy a launcher flag means.
 
-    ``xla``    — forced XLA everywhere (the multi-pod dry-run mode).
+    ``xla``    — forced XLA everywhere.
     ``pallas`` — pallas kernels with input-aware GEMM routing (the old
                  ``Backend("pallas", iaat=True)``).
     ``auto``   — same routing, kernel family derived.

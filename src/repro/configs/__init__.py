@@ -5,10 +5,9 @@ Arch ids use the assignment's dashed names; module files use underscores.
 from __future__ import annotations
 
 import importlib
-from typing import Dict, List
+from typing import List
 
-from repro.configs.base import (SHAPES, ModelConfig, ShapeConfig,
-                                shape_applicable)
+from repro.configs.base import ModelConfig
 
 ARCH_IDS: List[str] = [
     "mixtral-8x22b",
@@ -35,14 +34,3 @@ def get_config(arch_id: str) -> ModelConfig:
 
 def get_smoke(arch_id: str) -> ModelConfig:
     return _module(arch_id).smoke()
-
-
-def all_cells():
-    """Every assigned (arch, shape) cell with its applicability verdict."""
-    out = []
-    for a in ARCH_IDS:
-        cfg = get_config(a)
-        for s in SHAPES.values():
-            ok, why = shape_applicable(cfg, s)
-            out.append((a, s.name, ok, why))
-    return out

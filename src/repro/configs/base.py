@@ -3,14 +3,14 @@
 One ``ModelConfig`` per assigned architecture lives in
 ``src/repro/configs/<arch>.py`` (exact numbers from the assignment) plus a
 ``smoke()`` reduction of the same family for CPU tests.  ``ShapeConfig``
-encodes the assigned input-shape set (train_4k / prefill_32k / decode_32k /
-long_500k).
+is a (sequence length, global batch) pair that training's input shardings
+are laid out for.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax.numpy as jnp
 
@@ -198,24 +198,3 @@ class ShapeConfig:
     @property
     def tokens(self) -> int:
         return self.seq_len * self.global_batch
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Assignment rules: long_500k only for sub-quadratic archs."""
-    if shape.name == "long_500k":
-        sub = cfg.attn.is_subquadratic() or cfg.family in ("ssm", "hybrid") \
-            or cfg.attn.kind == "local_global"
-        if not sub:
-            return False, "pure full-attention arch: long_500k skipped per assignment"
-    if shape.kind == "decode" and cfg.family == "encdec" \
-            and shape.name == "long_500k":
-        return False, "enc-dec 500k decode not meaningful"
-    return True, ""

@@ -1,9 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contract).
 
 Each kernel in this package has a reference here, used by the per-kernel
-allclose tests and — for attention/SSD — by the XLA model path that the
-multi-pod dry-run compiles (chunked formulations keep 32k+ sequences
-compilable without materialising S x S score matrices).
+allclose tests and — for attention/SSD — by the model's XLA path
+(chunked formulations keep 32k+ sequences compilable without
+materialising S x S score matrices).
 """
 from __future__ import annotations
 
@@ -92,7 +92,7 @@ def chunked_mha(q, k, v, *, causal: bool = True,
                 scale: Optional[float] = None, kv_chunk: int = 1024):
     """Online-softmax attention scanning KV in chunks (flash-style, pure
     jnp + lax.scan).  This is both the oracle for the Pallas flash kernel
-    at scale and the XLA model path used by the dry-run (memory O(S·c))."""
+    at scale and the model's XLA attention path (memory O(S·c))."""
     B, Hq, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     rep = Hq // Hkv

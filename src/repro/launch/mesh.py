@@ -1,8 +1,8 @@
 """Production mesh builders.
 
 ``make_production_mesh`` is a FUNCTION (never a module-level constant) so
-importing this module never touches jax device state — required because
-the dry-run must set XLA_FLAGS before any jax initialisation.
+importing this module never touches jax device state — a caller may set
+XLA_FLAGS (a described device count) before any jax initialisation.
 
 Every builder makes ``Auto`` axes: the model and training code shard
 through ``with_sharding_constraint`` and leave propagation to the

@@ -21,8 +21,8 @@ from repro.api import Policy
 Params = Dict[str, Any]
 Specs = Dict[str, Any]
 
-#: Canonical policies for the two reference operating points: the
-#: XLA-compilable dry-run stack, and pallas kernels with input-aware
+#: Canonical policies for the two reference operating points: plain
+#: XLA everywhere, and pallas kernels with input-aware
 #: GEMM routing (interpreted on CPU, compiled on a TPU).
 XLA = api.named_policy("xla")
 PALLAS = api.named_policy("pallas")

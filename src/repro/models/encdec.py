@@ -2,14 +2,14 @@
 
 The audio frontend is a STUB per the assignment: the encoder consumes
 precomputed frame embeddings (B, S_src, d) from ``input_specs``.  The
-decoder is a causal LM stack with cross-attention into the encoder states;
-serving caches both the self-attention KV ring and the projected cross KV.
+decoder is a causal LM stack with cross-attention into the encoder states.
+Only the teacher-forced forward exists: the family has no serving path
+(``Model.paged_decode`` is None and ``PagedEngine`` refuses it).
 """
 from __future__ import annotations
 
-import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +20,7 @@ from repro.models import layers as L
 from repro.api import Policy
 from repro.models.common import (mm, ninit, rmsnorm, stack_init,
                                  stack_specs)
-from repro.models.lm import LMCache, _remat
+from repro.models.lm import _remat
 
 
 def _norm(cfg, dtype):
@@ -106,21 +106,15 @@ def _cross_kv(blk, enc, cfg, be):
     return k, v
 
 
-def _dec_block(blk, x, enc_or_kv, cfg, be, *, positions=None, kv=None,
-               pos=None, precomputed_cross: bool = False):
+def _dec_block(blk, x, enc, cfg, be, *, positions=None):
     h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
-    out = L.attention(blk["self_attn"], h, be, cfg, causal=True,
-                      positions=positions, kv_cache=kv, pos=pos)
-    if kv is not None:
-        sa, kv_new = out
-    else:
-        sa, kv_new = out, None
-    x = x + sa
+    x = x + L.attention(blk["self_attn"], h, be, cfg, causal=True,
+                        positions=positions)
     h = rmsnorm(x, blk["ln_x"], cfg.norm_eps)
-    ckv = enc_or_kv if precomputed_cross else _cross_kv(blk, enc_or_kv, cfg, be)
-    x = x + L.attention(blk["cross_attn"], h, be, cfg, cross_kv=ckv)
+    x = x + L.attention(blk["cross_attn"], h, be, cfg,
+                        cross_kv=_cross_kv(blk, enc, cfg, be))
     h = rmsnorm(x, blk["ln2"], cfg.norm_eps)
-    return x + L.mlp(blk["mlp"], h, be), kv_new
+    return x + L.mlp(blk["mlp"], h, be)
 
 
 def forward_train(params, cfg: ModelConfig, be: Policy, tokens,
@@ -131,92 +125,8 @@ def forward_train(params, cfg: ModelConfig, be: Policy, tokens,
     positions = jnp.arange(x.shape[1])
 
     def body(x, blk):
-        x, _ = _dec_block(blk, x, enc, cfg, be, positions=positions)
-        return x, None
+        return _dec_block(blk, x, enc, cfg, be, positions=positions), None
 
     x, _ = lax.scan(_remat(body, cfg), x, params["dec_blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return mm(x, params["unembed"], be), jnp.zeros((), jnp.float32)
-
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class EncDecCache:
-    pos: jax.Array
-    self_k: jax.Array            # (L, B, Hkv, W, hd)
-    self_v: jax.Array
-    cross_k: jax.Array           # (L, B, Hkv, S_src, hd)
-    cross_v: jax.Array
-
-    def tree_flatten(self):
-        return ((self.pos, self.self_k, self.self_v, self.cross_k,
-                 self.cross_v), None)
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children)
-
-
-def init_cache(cfg: ModelConfig, batch: int, seq_len: int, src_len: int,
-               dtype=jnp.bfloat16, prefill_len: int = 0) -> EncDecCache:
-    Hkv, hd, Ld = cfg.n_kv_heads_padded, cfg.head_dim_, cfg.n_layers
-    return EncDecCache(
-        pos=jnp.asarray(prefill_len, jnp.int32),
-        self_k=jnp.zeros((Ld, batch, Hkv, seq_len, hd), dtype),
-        self_v=jnp.zeros((Ld, batch, Hkv, seq_len, hd), dtype),
-        cross_k=jnp.zeros((Ld, batch, Hkv, src_len, hd), dtype),
-        cross_v=jnp.zeros((Ld, batch, Hkv, src_len, hd), dtype),
-    )
-
-
-def prefill(params, cfg: ModelConfig, be: Policy, tokens, src_embeds,
-            cache_len: Optional[int] = None
-            ) -> Tuple[jax.Array, EncDecCache]:
-    enc = encode(params, cfg, be, src_embeds)
-    B, Stgt = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
-    positions = jnp.arange(Stgt)
-
-    def body(x, blk):
-        h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
-        sa, (k, v) = L.attention(blk["self_attn"], h, be, cfg, causal=True,
-                                 positions=positions, return_kv=True)
-        x = x + sa
-        h = rmsnorm(x, blk["ln_x"], cfg.norm_eps)
-        ck, cv = _cross_kv(blk, enc, cfg, be)
-        x = x + L.attention(blk["cross_attn"], h, be, cfg, cross_kv=(ck, cv))
-        h = rmsnorm(x, blk["ln2"], cfg.norm_eps)
-        return x + L.mlp(blk["mlp"], h, be), (k, v, ck, cv)
-
-    x, (ks, vs, cks, cvs) = lax.scan(body, x, params["dec_blocks"])
-    W = cache_len or Stgt
-    if W > Stgt:
-        pad = ((0, 0),) * 3 + ((0, W - Stgt), (0, 0))
-        ks, vs = jnp.pad(ks, pad), jnp.pad(vs, pad)
-    cache = EncDecCache(pos=jnp.asarray(Stgt, jnp.int32),
-                        self_k=ks.astype(cfg.compute_dtype),
-                        self_v=vs.astype(cfg.compute_dtype),
-                        cross_k=cks.astype(cfg.compute_dtype),
-                        cross_v=cvs.astype(cfg.compute_dtype))
-    x = rmsnorm(x[:, -1:], params["final_norm"], cfg.norm_eps)
-    return mm(x, params["unembed"], be)[:, 0], cache
-
-
-def decode(params, cfg: ModelConfig, be: Policy, tokens,
-           cache: EncDecCache) -> Tuple[jax.Array, EncDecCache]:
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
-    pos = cache.pos
-
-    def body(x, xs):
-        blk, kb, vb, ck, cv = xs
-        x, (kn, vn) = _dec_block(blk, x, (ck, cv), cfg, be, kv=(kb, vb),
-                                 pos=pos, precomputed_cross=True)
-        return x, (kn, vn)
-
-    x, (kn, vn) = lax.scan(body, x, (params["dec_blocks"], cache.self_k,
-                                     cache.self_v, cache.cross_k,
-                                     cache.cross_v))
-    cache = EncDecCache(pos=pos + 1, self_k=kn, self_v=vn,
-                        cross_k=cache.cross_k, cross_v=cache.cross_v)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return mm(x, params["unembed"], be)[:, 0], cache

@@ -2,10 +2,11 @@
 gated MLP, and capacity-routed MoE with sort-based dispatch.
 
 Every projection goes through ``common.mm`` (the IAAT dispatch hook); the
-attention inner loop switches between the Pallas flash kernel and the
-chunked-XLA oracle by the ``Policy``; MoE expert compute switches between
-``ops.batched_gemm`` (Pallas, the paper's batched-small-GEMM habitat) and
-a batched einsum (XLA path for the multi-pod dry-run).
+full-sequence attention inner loop switches between the Pallas flash
+kernel and the chunked-XLA oracle by the ``Policy``; serving attends over
+a paged KV pool (:func:`paged_attend`); MoE expert compute switches
+between ``ops.batched_gemm`` (Pallas, the paper's batched-small-GEMM
+habitat) and a batched einsum (the XLA path).
 """
 from __future__ import annotations
 
@@ -79,35 +80,6 @@ def _full_attn(q, k, v, be: Policy, *, causal, window, q_offset, scale):
                            kv_chunk=min(1024, k.shape[2]))
 
 
-def decode_attend(q, k_buf, v_buf, pos, *, window: Optional[int],
-                  scale: float):
-    """One-token attention over a (ring) KV buffer.
-
-    q: (B, H, 1, hd); k_buf/v_buf: (B, Hkv, W, hd); ``pos`` is the position
-    of the query token (the buffer already contains it at slot pos % W).
-    Slot s holds position  p_s = pos - ((pos - s) mod W)  — for a
-    full-length buffer this degenerates to p_s = s, so one formula covers
-    both the ring (sliding-window) and the linear (full) cache."""
-    B, H, _, hd = q.shape
-    Hkv, W = k_buf.shape[1], k_buf.shape[2]
-    rep = H // Hkv
-    s_idx = jnp.arange(W)
-    p_s = pos - jnp.mod(pos - s_idx, W)
-    ok = p_s >= 0
-    if window is not None:
-        ok &= p_s > pos - window
-    qf = q.reshape(B, Hkv, rep, hd)
-    # preferred_element_type keeps the accumulation in f32 WITHOUT
-    # materialising an f32 copy of the (huge) KV buffers
-    logits = jnp.einsum("bkrd,bksd->bkrs", qf, k_buf,
-                        preferred_element_type=jnp.float32) * scale
-    logits = jnp.where(ok[None, None, None, :], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkrs,bksd->bkrd", p.astype(v_buf.dtype), v_buf,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, H, 1, hd).astype(q.dtype)
-
-
 def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
                  scale: float, window: Optional[int] = None,
                  decode_from=None):
@@ -122,19 +94,17 @@ def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
     request's own history; stale/pad slots beyond ``q_pos`` and other
     requests' blocks are unreachable by construction).
 
-    The branches mirror the wave engine's reference numerics
-    operation-for-operation — normalised-probs rounding for decode
-    tokens (:func:`decode_attend`) and flash-style unnormalised
-    accumulation for prefill rows (``ref.chunked_mha``) — so that at
-    temperature 0 the paged engine is token-identical to the wave
-    reference, not merely close (masked lanes contribute exact zeros
-    either way).  ``decode_from`` (B,) marks where the ORIGINAL decode
-    boundary sits: a recompute-resume chunk replays positions that the
-    reference timeline processed one token at a time, so rows at
+    Two numerics recipes: normalised-probs rounding for decode tokens
+    (C == 1) and flash-style unnormalised accumulation for prefill rows
+    (``ref.chunked_mha``'s order); masked lanes contribute exact zeros
+    either way.  ``decode_from`` (B,) marks where the request's ORIGINAL
+    decode boundary sits: a recompute-resume chunk replays positions
+    that the unpreempted run processed one token at a time, so rows at
     ``q_pos >= decode_from`` select the decode numerics even inside a
-    C > 1 chunk — without this the replayed rows pick up flash-vs-
+    C > 1 chunk.  That is what makes preempted requests replay
+    bit-identically: without it the replayed rows pick up flash-vs-
     softmax rounding, the recurrent carries inherit it, and the
-    continuation after preemption drifts off the oracle."""
+    continuation after preemption drifts from the unpreempted one."""
     B, H, C, hd = q.shape
     Hkv, BS = k_pool.shape[1], k_pool.shape[2]
     nmax = block_table.shape[1]
@@ -149,7 +119,7 @@ def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
     if window is not None:
         ok &= key_pos[None, None, :] > q_pos[:, :, None] - window
     if C == 1:
-        # decode: decode_attend's grouped-GQA, normalised-softmax order
+        # decode: grouped-GQA, normalised-softmax order
         qf = q.reshape(B, Hkv, rep, hd)
         logits = jnp.einsum("bkrd,bksd->bkrs", qf, kg,
                             preferred_element_type=jnp.float32) * scale
@@ -173,8 +143,9 @@ def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
     flash = (acc / jnp.maximum(l, 1e-37)[..., None]).astype(q.dtype)
     if decode_from is None:
         return flash
-    # recompute-resume: replayed decode rows take decode_attend's
-    # op-for-op numerics (same grouped-GQA shapes, batched over C)
+    # recompute-resume: replayed decode rows take the C == 1 branch's
+    # op-for-op numerics (same grouped-GQA shapes, batched over C), so
+    # preempted requests replay bit-identically
     qf = q.reshape(B, Hkv, rep, C, hd)
     logits = jnp.einsum("bkrqd,bksd->bkrqs", qf, kg,
                         preferred_element_type=jnp.float32) * scale
@@ -189,21 +160,19 @@ def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
 
 def attention(p: Dict, x, be: Policy, cfg: ModelConfig, *,
               causal: bool = True, window: Optional[int] = None,
-              positions=None, kv_cache: Optional[Tuple] = None,
-              pos=None, cross_kv: Optional[Tuple] = None,
-              paged_kv: Optional[Tuple] = None,
-              return_kv: bool = False):
+              positions=None, cross_kv: Optional[Tuple] = None,
+              paged_kv: Optional[Tuple] = None):
     """Unified attention layer.
 
     Modes:
-      train/prefill: kv_cache None; positions (S,) or (B,S).
-      decode:        kv_cache (k_buf, v_buf); pos scalar; x is (B,1,d).
-      paged:         paged_kv (k_pool, v_pool, block_table, pos (B,C));
-                     writes the chunk through the table, attends via
-                     the gather path; one code path serves chunked
-                     prefill (C>1) and slot decode (C=1).
-      cross:         cross_kv (k, v) precomputed from encoder states.
-    Returns y [, new_kv or (k,v) when return_kv]."""
+      sequence: positions (S,) or (B,S); the whole sequence at once
+                (training, the encoder).
+      paged:    paged_kv (k_pool, v_pool, block_table, pos (B,C));
+                writes the chunk through the table, attends via the
+                gather path; one code path serves chunked prefill (C>1)
+                and slot decode (C=1).
+      cross:    cross_kv (k, v) precomputed from encoder states.
+    Returns y, and in paged mode (y, (k_pool, v_pool))."""
     H, Hkv, hd = cfg.n_heads_padded, cfg.n_kv_heads_padded, cfg.head_dim_
     scale = hd ** -0.5
     B, S, _ = x.shape
@@ -240,30 +209,13 @@ def attention(p: Dict, x, be: Policy, cfg: ModelConfig, *,
         y = paged_attend(q, k_pool, v_pool, bt, qpos, window=window,
                          scale=scale, decode_from=decode_from)
         return mm(_merge_heads(y), p["wo"], be), (k_pool, v_pool)
-    if kv_cache is not None:
-        # decode: rope at absolute position, ring-write, attend buffer
-        k_buf, v_buf = kv_cache
-        W = k_buf.shape[2]
-        pos_arr = jnp.full((B, 1), pos, jnp.int32)
-        q = rope(q, pos_arr, cfg.rope_theta)
-        k = rope(k, pos_arr, cfg.rope_theta)
-        slot = jnp.mod(pos, W).astype(jnp.int32)
-        zero = jnp.zeros((), jnp.int32)
-        idx = (zero, zero, slot, zero)
-        k_buf = lax.dynamic_update_slice(k_buf, k.astype(k_buf.dtype), idx)
-        v_buf = lax.dynamic_update_slice(v_buf, v.astype(v_buf.dtype), idx)
-        y = decode_attend(q, k_buf, v_buf, pos, window=window, scale=scale)
-        return mm(_merge_heads(y), p["wo"], be), (k_buf, v_buf)
     if positions is None:
         positions = jnp.arange(S)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     y = _full_attn(q, k, v, be, causal=causal, window=window, q_offset=0,
                    scale=scale)
-    out = mm(_merge_heads(y), p["wo"], be)
-    if return_kv:
-        return out, (k, v)
-    return out
+    return mm(_merge_heads(y), p["wo"], be)
 
 
 # --------------------------------------------------------------------------

@@ -1,9 +1,10 @@
 """Decoder-only LM covering the dense / MoE / SSM / hybrid families.
 
-One parameter schema + three entry points (`forward_train`, `prefill`,
-`decode`), all built on a remat'd ``lax.scan`` over stacked layer params
-(compile time stays O(1) in depth — mandatory for the 81-layer zamba2 and
-56-layer mixtral dry-runs).
+One parameter schema + two entry points: ``forward_train`` over a whole
+sequence, and the paged serving steps (``paged_prefill`` for one chunk of
+one request, ``paged_decode`` for one token of every slot).  Both are
+built on a ``lax.scan`` over stacked layer params (compile time stays
+O(1) in depth — the 81-layer zamba2 and 56-layer mixtral depend on it).
 
 Family wiring:
   dense / vlm   uniform [attn + mlp] blocks; attention pattern full /
@@ -13,10 +14,10 @@ Family wiring:
   ssm           [mamba] blocks (attention-free).
   hybrid        [mamba] blocks + ONE shared [attn + mlp] block (zamba2
                 style) applied every ``shared_attn_every`` layers; its
-                params are closed over (true weight sharing), its KV cache
+                params are closed over (true weight sharing), its KV pool
                 is indexed per application.
-VLM (internvl2) enters through ``prefix_embeds`` (the stubbed ViT
-frontend); audio enc-dec lives in encdec.py.
+VLM (internvl2) enters ``forward_train`` through ``prefix_embeds`` (the
+stubbed ViT frontend); audio enc-dec lives in encdec.py.
 """
 from __future__ import annotations
 
@@ -32,70 +33,7 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.api import Policy
-from repro.models.common import (assert_same_structure, mm, ninit,
-                                 rmsnorm, stack_init, stack_specs)
-
-
-# --------------------------------------------------------------------------
-# Cache pytree.
-# --------------------------------------------------------------------------
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class LMCache:
-    pos: jax.Array                              # scalar int32: next position
-    attn_k: Optional[jax.Array] = None          # (L, B, Hkv, W, hd)
-    attn_v: Optional[jax.Array] = None
-    conv: Optional[jax.Array] = None            # (L, B, K-1, ch)
-    ssm: Optional[jax.Array] = None             # (L, B, nh, P, N)
-    shared_k: Optional[jax.Array] = None        # (napps, B, Hkv, W, hd)
-    shared_v: Optional[jax.Array] = None
-
-    def tree_flatten(self):
-        return ((self.pos, self.attn_k, self.attn_v, self.conv, self.ssm,
-                 self.shared_k, self.shared_v), None)
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children)
-
-
-def _n_shared_apps(cfg: ModelConfig) -> int:
-    return -(cfg.n_layers // -cfg.shared_attn_every) \
-        if cfg.shared_attn_every else 0
-
-
-def cache_buffer_len(cfg: ModelConfig, seq_len: int) -> int:
-    """Ring-buffer length: window-sized iff NO layer needs full context."""
-    a = cfg.attn
-    if cfg.family in ("ssm",):
-        return 0
-    if a.kind == "swa" and not cfg.shared_attn_every:
-        return min(a.window, seq_len)
-    return seq_len
-
-
-def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
-               dtype=jnp.bfloat16, prefill_len: int = 0) -> LMCache:
-    W = cache_buffer_len(cfg, seq_len)
-    Hkv = cfg.n_kv_heads_padded
-    hd = cfg.head_dim_ if cfg.n_heads else 0
-    kw: Dict[str, Any] = {"pos": jnp.asarray(prefill_len, jnp.int32)}
-    Ld = cfg.n_layers
-    if cfg.family in ("dense", "moe", "vlm"):
-        kw["attn_k"] = jnp.zeros((Ld, batch, Hkv, W, hd), dtype)
-        kw["attn_v"] = jnp.zeros((Ld, batch, Hkv, W, hd), dtype)
-    if cfg.family in ("ssm", "hybrid"):
-        s = cfg.ssm
-        ch = cfg.d_inner + 2 * s.d_state
-        kw["conv"] = jnp.zeros((Ld, batch, s.d_conv - 1, ch), dtype)
-        kw["ssm"] = jnp.zeros((Ld, batch, cfg.ssm_heads, s.head_dim,
-                               s.d_state), jnp.float32)
-    if cfg.shared_attn_every:
-        na = _n_shared_apps(cfg)
-        kw["shared_k"] = jnp.zeros((na, batch, Hkv, W, hd), dtype)
-        kw["shared_v"] = jnp.zeros((na, batch, Hkv, W, hd), dtype)
-    return LMCache(**kw)
+from repro.models.common import mm, ninit, rmsnorm, stack_init, stack_specs
 
 
 # --------------------------------------------------------------------------
@@ -221,24 +159,22 @@ def _window_for_layer(cfg: ModelConfig, i):
     return False, None
 
 
-def _apply_attn_block(p, x, be, cfg, i, *, kv=None, pos=None,
-                      positions=None, paged_kv=None, return_kv=False):
+def _apply_attn_block(p, x, be, cfg, i, *, positions=None, paged_kv=None):
     """attention (+cond on local/global) + mlp/moe. Returns
-    (y, aux, new_kv_or_kv_pair)."""
+    (y, aux, new (k_pool, v_pool) or None)."""
     needs_cond, win = _window_for_layer(cfg, i)
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
 
     def run(window):
         return L.attention(p["attn"], h, be, cfg, causal=True, window=window,
-                           positions=positions, kv_cache=kv, pos=pos,
-                           paged_kv=paged_kv, return_kv=return_kv)
+                           positions=positions, paged_kv=paged_kv)
 
     if needs_cond:
         is_global = (i % (cfg.attn.local_ratio + 1)) == cfg.attn.local_ratio
         out = lax.cond(is_global, lambda: run(None), lambda: run(win))
     else:
         out = run(win)
-    if kv is not None or paged_kv is not None or return_kv:
+    if paged_kv is not None:
         attn_out, kv_out = out
     else:
         attn_out, kv_out = out, None
@@ -252,41 +188,22 @@ def _apply_attn_block(p, x, be, cfg, i, *, kv=None, pos=None,
     return x + y, aux, kv_out
 
 
-def _apply_mamba_block(p, x, be, cfg, *, state=None):
+def _apply_mamba_block(p, x, be, cfg):
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if state is not None:
-        y, new_state = S.mamba(p["mixer"], h, be, cfg, state=state)
-        return x + y, new_state
-    return x + S.mamba(p["mixer"], h, be, cfg), None
+    return x + S.mamba(p["mixer"], h, be, cfg)
 
 
-def _maybe_shared(params, x, be, cfg, i, *, shared_kv=None, pos=None,
-                  positions=None, return_kv=False):
+def _maybe_shared(params, x, be, cfg, i, *, positions=None):
     """Hybrid: apply the shared attn block when i % every == 0."""
     if not cfg.shared_attn_every:
-        return x, shared_kv
-    sp = params["shared"]
+        return x
 
     def apply(x):
-        y, _, kv_out = _apply_attn_block(sp, x, be, cfg, i, kv=shared_kv,
-                                         pos=pos, positions=positions,
-                                         return_kv=return_kv)
-        return y, kv_out
-
-    def skip(x):
-        if shared_kv is not None or return_kv:
-            dummy = shared_kv
-            if dummy is None:
-                # return_kv path needs consistent shapes; build zeros
-                B, Ssz, _ = x.shape
-                hd, Hkv = cfg.head_dim_, cfg.n_kv_heads_padded
-                z = jnp.zeros((B, Hkv, Ssz, hd), x.dtype)
-                dummy = (z, z)
-            return x, dummy
-        return x, None
+        return _apply_attn_block(params["shared"], x, be, cfg, i,
+                                 positions=positions)[0]
 
     return lax.cond(i % cfg.shared_attn_every == 0,
-                    apply, skip, x)
+                    apply, lambda x: x, x)
 
 
 # --------------------------------------------------------------------------
@@ -330,8 +247,8 @@ def forward_train(params: Dict, cfg: ModelConfig, be: Policy,
         def body(carry, xs):
             x = carry
             blk, i = xs
-            x, _ = _maybe_shared(params, x, be, cfg, i, positions=positions)
-            x, _ = _apply_mamba_block(blk, x, be, cfg)
+            x = _maybe_shared(params, x, be, cfg, i, positions=positions)
+            x = _apply_mamba_block(blk, x, be, cfg)
             return x, None
         x, _ = lax.scan(_remat(body, cfg), x, (params["blocks"], idxs))
         aux = jnp.zeros((), jnp.float32)
@@ -348,138 +265,6 @@ def forward_train(params: Dict, cfg: ModelConfig, be: Policy,
         aux = aux / cfg.n_layers
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return _unembed(params, cfg, x, be), aux
-
-
-# --------------------------------------------------------------------------
-# Prefill / decode (serving).
-# --------------------------------------------------------------------------
-
-def _ring_layout(k, W: int):
-    """Reorder the last W positions of k (B,H,S,hd) into ring-slot order."""
-    Ssz = k.shape[2]
-    if W >= Ssz:
-        return k, Ssz
-    slots = (Ssz - W) + jnp.mod(jnp.arange(W) - Ssz, W)
-    return jnp.take(k, slots, axis=2), W
-
-
-def _ring_pad(k, W: int, dtype):
-    """Ring-layout + pad to exactly W slots (applied INSIDE the prefill
-    layer scan so the stacked cache is (L,B,H,W,hd), never (L,B,H,S,hd) —
-    for sliding-window archs at 32k that is a ~8x cache-stack saving)."""
-    kr, have = _ring_layout(k, W)
-    if have < W:
-        kr = jnp.pad(kr, ((0, 0),) * 2 + ((0, W - have), (0, 0)))
-    return kr.astype(dtype)
-
-
-def prefill(params: Dict, cfg: ModelConfig, be: Policy, tokens: jax.Array,
-            prefix_embeds: Optional[jax.Array] = None,
-            cache_len: Optional[int] = None
-            ) -> Tuple[jax.Array, LMCache]:
-    """Run the prompt, return (last-token logits (B, Vp), primed cache)."""
-    x = _embed_tokens(params, cfg, tokens, be, prefix_embeds)
-    B, Stot, _ = x.shape
-    cache_len = cache_len or Stot
-    cache = init_cache(cfg, B, cache_len, cfg.compute_dtype,
-                       prefill_len=Stot)
-    positions = jnp.arange(Stot)
-    idxs = jnp.arange(cfg.n_layers)
-    W = cache_buffer_len(cfg, cache_len)
-
-    if cfg.family in ("ssm", "hybrid"):
-        zero = S.init_paged_state(cfg, B, cfg.compute_dtype)
-
-        def body(carry, xs):
-            x = carry
-            blk, i = xs
-            x, skv = _maybe_shared(params, x, be, cfg, i,
-                                   positions=positions, return_kv=True)
-            if cfg.shared_attn_every:
-                skv = (_ring_pad(skv[0], W, cfg.compute_dtype),
-                       _ring_pad(skv[1], W, cfg.compute_dtype))
-            # mamba over the whole prompt as ONE chunk of the serving
-            # recurrence (ssm.paged_step from a zero carry) — the carry
-            # left behind is bit-identical to any other chunking of the
-            # same tokens, which is what makes the paged engine's
-            # chunked prefill and recompute-resume exact against this
-            # wave path at temperature 0
-            h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
-            y, st = S.paged_step(blk["mixer"], h, be, cfg, zero)
-            return x + y, (st, skv)
-        x, (states, skvs) = lax.scan(body, x, (params["blocks"], idxs))
-        conv_states, ssm_states = states
-        cache.conv = conv_states
-        cache.ssm = ssm_states
-        if cfg.shared_attn_every:
-            ks_, vs_ = skvs
-            napps = _n_shared_apps(cfg)
-            app_layers = jnp.arange(napps) * cfg.shared_attn_every
-            cache.shared_k = ks_[app_layers]
-            cache.shared_v = vs_[app_layers]
-        aux = None
-    else:
-        def body(carry, xs):
-            x = carry
-            blk, i = xs
-            x, _, kv = _apply_attn_block(blk, x, be, cfg, i,
-                                         positions=positions, return_kv=True)
-            return x, (_ring_pad(kv[0], W, cfg.compute_dtype),
-                       _ring_pad(kv[1], W, cfg.compute_dtype))
-        x, (ks_, vs_) = lax.scan(body, x, (params["blocks"], idxs))
-        cache.attn_k = ks_
-        cache.attn_v = vs_
-    x = rmsnorm(x[:, -1:], params["final_norm"], cfg.norm_eps)
-    logits = _unembed(params, cfg, x, be)[:, 0]
-    return logits, cache
-
-
-def decode(params: Dict, cfg: ModelConfig, be: Policy, tokens: jax.Array,
-           cache: LMCache) -> Tuple[jax.Array, LMCache]:
-    """One-token step. tokens: (B, 1). Returns (logits (B, Vp), cache)."""
-    x = _embed_tokens(params, cfg, tokens, be)
-    pos = cache.pos
-    idxs = jnp.arange(cfg.n_layers)
-
-    if cfg.family in ("ssm", "hybrid"):
-        shared_kv_carry = (cache.shared_k, cache.shared_v)
-
-        def body(carry, xs):
-            x, sk, sv = carry
-            blk, i, conv, ssm_h = xs
-            if cfg.shared_attn_every:
-                app = i // cfg.shared_attn_every
-
-                def apply(x, sk, sv):
-                    kv = (sk[app], sv[app])
-                    y, _, kv_new = _apply_attn_block(
-                        params["shared"], x, be, cfg, i, kv=kv, pos=pos)
-                    sk = sk.at[app].set(kv_new[0])
-                    sv = sv.at[app].set(kv_new[1])
-                    return y, sk, sv
-
-                x, sk, sv = lax.cond(i % cfg.shared_attn_every == 0,
-                                     apply, lambda x, sk, sv: (x, sk, sv),
-                                     x, sk, sv)
-            x, st = _apply_mamba_block(blk, x, be, cfg, state=(conv, ssm_h))
-            return (x, sk, sv), st
-        (x, sk, sv), (conv_new, ssm_new) = lax.scan(
-            body, (x, cache.shared_k, cache.shared_v),
-            (params["blocks"], idxs, cache.conv, cache.ssm))
-        cache = LMCache(pos=pos + 1, conv=conv_new, ssm=ssm_new,
-                        shared_k=sk, shared_v=sv)
-    else:
-        def body(carry, xs):
-            x = carry
-            blk, i, kbuf, vbuf = xs
-            x, _, kv = _apply_attn_block(blk, x, be, cfg, i,
-                                         kv=(kbuf, vbuf), pos=pos)
-            return x, kv
-        x, (knew, vnew) = lax.scan(body, x, (params["blocks"], idxs,
-                                             cache.attn_k, cache.attn_v))
-        cache = LMCache(pos=pos + 1, attn_k=knew, attn_v=vnew)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return _unembed(params, cfg, x, be)[:, 0], cache
 
 
 # --------------------------------------------------------------------------
@@ -513,6 +298,11 @@ class PagedState:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
+
+
+def _n_shared_apps(cfg: ModelConfig) -> int:
+    return -(cfg.n_layers // -cfg.shared_attn_every) \
+        if cfg.shared_attn_every else 0
 
 
 def init_paged_state(cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -607,7 +397,7 @@ def paged_prefill(params: Dict, cfg: ModelConfig, be: Policy,
     past ``seg_len`` is padding and advances nothing), block_tables
     (1, nmax).  ``n_prompt`` is the request's prompt length: rows at
     positions >= n_prompt only exist on recompute-resume (they replay
-    tokens the reference timeline generated by decode) and take the
+    tokens that decode generated before the preemption) and take the
     decode-path attention numerics so the rebuilt K/V and recurrent
     carries are bitwise what an unpreempted run would hold.
 

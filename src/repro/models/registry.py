@@ -1,13 +1,12 @@
 """Model registry: one uniform interface over all backbone families.
 
-``Model`` bundles init/specs/apply closures so the launcher, dry-run,
-trainer and server never branch on family."""
+``Model`` bundles init/specs/apply closures so the launcher, trainer and
+server never branch on family."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional
+from typing import Callable, Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -20,9 +19,6 @@ class Model:
     init: Callable                    # (key) -> params
     specs: Callable                   # () -> logical-axis tree
     forward_train: Callable           # (params, batch, be) -> (logits, aux)
-    prefill: Callable                 # (params, batch, be) -> (logits, cache)
-    decode: Callable                  # (params, batch, cache, be) -> (logits, cache)
-    init_cache: Callable              # (batch, seq_len) -> cache
     # paged serving path (repro.serve.PagedEngine): block-pool KV plus
     # per-slot recurrent carries, so EVERY decoder-only family serves
     # paged; None only for encoder-decoder archs
@@ -44,37 +40,12 @@ def build(cfg: ModelConfig) -> Model:
             return encdec.forward_train(params, cfg, be, batch["tokens"],
                                         batch["src_embeds"])
 
-        def pf(params, batch, be, cache_len=None):
-            return encdec.prefill(params, cfg, be, batch["tokens"],
-                                  batch["src_embeds"], cache_len=cache_len)
-
-        def dec(params, batch, cache, be):
-            return encdec.decode(params, cfg, be, batch["tokens"], cache)
-
-        def mk_cache(batch, seq_len, dtype=jnp.bfloat16, src_len=None):
-            return encdec.init_cache(cfg, batch, seq_len,
-                                     src_len or seq_len, dtype,
-                                     prefill_len=seq_len)
-
         return Model(cfg, lambda key: encdec.init_encdec(key, cfg),
-                     lambda: encdec.encdec_specs(cfg), fwd, pf, dec,
-                     mk_cache)
+                     lambda: encdec.encdec_specs(cfg), fwd)
 
     def fwd(params, batch, be):
         return lm.forward_train(params, cfg, be, batch["tokens"],
                                 batch.get("prefix_embeds"))
-
-    def pf(params, batch, be, cache_len=None):
-        return lm.prefill(params, cfg, be, batch["tokens"],
-                          batch.get("prefix_embeds"), cache_len=cache_len)
-
-    def dec(params, batch, cache, be):
-        return lm.decode(params, cfg, be, batch["tokens"], cache)
-
-    def mk_cache(batch, seq_len, dtype=jnp.bfloat16, prefill_len=None):
-        return lm.init_cache(cfg, batch, seq_len, dtype,
-                             prefill_len=seq_len if prefill_len is None
-                             else prefill_len)
 
     def ppf(params, batch, ps, tables, pos0, slot, seg_len, n_prompt, be):
         return lm.paged_prefill(params, cfg, be, batch["tokens"], ps,
@@ -89,7 +60,7 @@ def build(cfg: ModelConfig) -> Model:
                                    dtype)
 
     return Model(cfg, lambda key: lm.init_lm(key, cfg),
-                 lambda: lm.lm_specs(cfg), fwd, pf, dec, mk_cache,
+                 lambda: lm.lm_specs(cfg), fwd,
                  paged_prefill=ppf, paged_decode=pdec,
                  init_paged_state=mk_ps,
                  serving_params=lambda p: lm.serving_params(p, cfg))

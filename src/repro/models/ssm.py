@@ -13,7 +13,7 @@ The short causal conv is implemented as ``d_conv`` shifted adds
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,17 +105,9 @@ def _project(p, x, cfg: ModelConfig, be: Policy):
     return z, xs, Bm, Cm, dt
 
 
-def mamba(p: Dict, x, be: Policy, cfg: ModelConfig,
-          state: Optional[Tuple] = None):
-    """Train/prefill path. x: (B, S, d) -> y (B, S, d).
-
-    When ``state`` is given (decode, S==1) returns (y, new_state) where
-    state = (conv_state, ssm_h)."""
-    if state is not None:
-        # decode (S == 1) is just a one-token chunk of the serving
-        # recurrence — same code path as prefill chunks, exact resume
-        return paged_step(p, x, be, cfg, state)
-
+def mamba(p: Dict, x, be: Policy, cfg: ModelConfig):
+    """Whole-sequence (training) path, chunked SSD. x: (B, S, d) ->
+    y (B, S, d).  Serving runs :func:`paged_step`."""
     s = cfg.ssm
     B, S, d = x.shape
     di, N, nh, P = cfg.d_inner, s.d_state, cfg.ssm_heads, s.head_dim
@@ -147,7 +139,7 @@ def mamba(p: Dict, x, be: Policy, cfg: ModelConfig,
 
 
 # --------------------------------------------------------------------------
-# Serving recurrence (paged engine + wave oracle share this path).
+# Serving recurrence (paged prefill chunks and slot decode share it).
 # --------------------------------------------------------------------------
 
 def init_paged_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):

@@ -6,9 +6,9 @@ small and stdlib-only: a metric registry (:class:`Counter`,
 :class:`span` context manager for host sections (written to the flight
 recorder :data:`TRACE` as ``SPAN`` events, and opened as a
 ``jax.profiler.TraceAnnotation`` so spans line up with device traces
-when a profiler is active), and :func:`export_bench`, which writes a
-schema'd ``BENCH_<name>.json`` at the repo root — the per-PR perf
-trajectory ROADMAP asks for.
+when a profiler is active), and :func:`report_str`, a readable dump of
+both.  The chip benchmark (``bench/``) reads spans, counters and routes
+from here.
 
 The hot-path consumer is ``api.Router.route``: every routing decision is
 recorded into :data:`ROUTES`, a shape log keyed by the full call
@@ -29,10 +29,8 @@ nothing, and the route log is bypassed with a single attribute check.
 from __future__ import annotations
 
 import collections as _collections
-import json
 import math
 import os
-import pathlib
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -40,12 +38,8 @@ from typing import Any, Dict, List, Optional, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY", "ROUTES",
     "TRACE", "counter", "gauge", "histogram", "span", "enabled",
-    "set_enabled", "export_bench", "load_bench", "diff_bench",
-    "report_str", "reset", "bench_root", "record_trajectory",
-    "BENCH_SCHEMA_VERSION",
+    "set_enabled", "report_str", "reset",
 ]
-
-BENCH_SCHEMA_VERSION = 1
 
 # Histogram bucket growth: bucket i covers [BASE**i, BASE**(i+1)) and
 # reports its geometric midpoint, so the worst-case relative error of any
@@ -599,144 +593,6 @@ from repro.obs import trace  # noqa: E402  (needs nothing above at import)
 #: additionally disables just the recorder.
 TRACE = trace.TRACE
 TRACE.on = TRACE.on and _ENABLED
-
-
-# --------------------------------------------------------------------------
-# BENCH_<name>.json export.
-# --------------------------------------------------------------------------
-
-def bench_root() -> pathlib.Path:
-    """Where BENCH files land: ``REPRO_BENCH_DIR`` or the repo root
-    (three levels above this file — src/repro/obs)."""
-    env = os.environ.get("REPRO_BENCH_DIR")
-    if env:
-        return pathlib.Path(env)
-    return pathlib.Path(__file__).resolve().parents[3]
-
-
-def export_bench(name: str, meta: Optional[dict] = None, *,
-                 root: Optional[os.PathLike] = None) -> pathlib.Path:
-    """Write the current registry + route log as ``BENCH_<name>.json``.
-
-    The file is the repo's perf-trajectory record: schema-versioned,
-    sorted keys, one file per benchmark name so successive PRs diff
-    cleanly (``python -m repro.obs diff old.json new.json``).  An
-    existing file's ``trajectory`` list (the append-only per-PR history
-    written by :func:`record_trajectory`) is carried over, so a fresh
-    export refreshes the snapshot without erasing the history."""
-    doc = {
-        "bench": name,
-        "schema": BENCH_SCHEMA_VERSION,
-        "created_unix": time.time(),
-        "meta": dict(meta or {}),
-        "metrics": REGISTRY.snapshot(),
-        "router": ROUTES.snapshot(),
-    }
-    path = pathlib.Path(root) if root else bench_root()
-    path.mkdir(parents=True, exist_ok=True)
-    out = path / f"BENCH_{name}.json"
-    if out.exists():
-        try:
-            prev = json.loads(out.read_text()).get("trajectory")
-            if prev:
-                doc["trajectory"] = prev
-        except (OSError, ValueError):
-            pass        # corrupt old file: overwrite, don't crash the bench
-    tmp = out.with_suffix(".json.tmp")
-    tmp.write_text(json.dumps(doc, indent=1, sort_keys=True) + "\n")
-    tmp.replace(out)
-    return out
-
-
-def record_trajectory(name: str, entry: dict, *,
-                      root: Optional[os.PathLike] = None) -> pathlib.Path:
-    """Append one per-PR row to ``BENCH_<name>.json``'s ``trajectory``.
-
-    The trajectory is the longitudinal record ROADMAP asks for: each
-    ``benchmarks/run.py --record`` run appends a small dict of headline
-    numbers (tokens/s, latency percentiles) stamped with the current
-    commit when available, so regressions are visible across PRs, not
-    just against the latest snapshot.  Creates a skeleton doc when the
-    BENCH file does not exist yet."""
-    path = pathlib.Path(root) if root else bench_root()
-    path.mkdir(parents=True, exist_ok=True)
-    out = path / f"BENCH_{name}.json"
-    try:
-        doc = json.loads(out.read_text())
-    except (OSError, ValueError):
-        doc = {"bench": name, "schema": BENCH_SCHEMA_VERSION,
-               "created_unix": time.time(), "meta": {}, "metrics": {},
-               "router": []}
-    row = {"recorded_unix": time.time()}
-    commit = _git_head()
-    if commit:
-        row["commit"] = commit
-    row.update(entry)
-    doc.setdefault("trajectory", []).append(row)
-    tmp = out.with_suffix(".json.tmp")
-    tmp.write_text(json.dumps(doc, indent=1, sort_keys=True) + "\n")
-    tmp.replace(out)
-    return out
-
-
-_GIT_HEAD_CACHE: Optional[Tuple[Optional[str]]] = None
-
-
-def _git_head() -> Optional[str]:
-    """Short commit hash of the repo containing this file, or None.
-    Memoized per process — HEAD cannot move under a running benchmark,
-    and ``record_trajectory`` may be called once per suite."""
-    global _GIT_HEAD_CACHE
-    if _GIT_HEAD_CACHE is None:
-        import subprocess
-        try:
-            head = subprocess.run(
-                ["git", "rev-parse", "--short", "HEAD"],
-                cwd=pathlib.Path(__file__).resolve().parent, timeout=5,
-                capture_output=True, text=True, check=True).stdout.strip()
-        except Exception:
-            head = None
-        _GIT_HEAD_CACHE = (head,)
-    return _GIT_HEAD_CACHE[0]
-
-
-def load_bench(path: os.PathLike) -> dict:
-    doc = json.loads(pathlib.Path(path).read_text())
-    schema = int(doc.get("schema", -1))
-    if schema != BENCH_SCHEMA_VERSION:
-        raise ValueError(f"{path}: BENCH schema {schema} != supported "
-                         f"{BENCH_SCHEMA_VERSION}")
-    return doc
-
-
-def _scalar_metrics(doc: dict) -> Dict[str, float]:
-    """Flatten a BENCH doc to comparable scalars (counter/gauge values,
-    histogram count/mean/p50/p95/p99)."""
-    out: Dict[str, float] = {}
-    for key, m in doc.get("metrics", {}).items():
-        t = m.get("type")
-        if t in ("counter", "gauge"):
-            out[key] = float(m["value"])
-        elif t == "histogram":
-            for f in ("count", "mean", "p50", "p95", "p99"):
-                out[f"{key}.{f}"] = float(m[f])
-    return out
-
-
-def diff_bench(a: dict, b: dict) -> List[Tuple[str, Optional[float],
-                                               Optional[float],
-                                               Optional[float]]]:
-    """Rows of (metric, old, new, pct_change); None marks one-sided keys."""
-    am, bm = _scalar_metrics(a), _scalar_metrics(b)
-    rows: List[Tuple[str, Optional[float], Optional[float],
-                     Optional[float]]] = []
-    for key in sorted(set(am) | set(bm)):
-        old, new = am.get(key), bm.get(key)
-        pct = None
-        if old is not None and new is not None and old != 0:
-            pct = (new - old) / abs(old) * 100.0
-        rows.append((key, old, new, pct))
-    return rows
 
 
 def report_str() -> str:

@@ -1,26 +1,17 @@
 """CLI for the observability layer.
 
-    python -m repro.obs                     # summarize BENCH_*.json files
-    python -m repro.obs ls                  # same ("list" also works)
-    python -m repro.obs show BENCH_x.json   # pretty-print one BENCH file
-    python -m repro.obs diff OLD NEW        # metric deltas between two
-    python -m repro.obs report              # live registry of this process
+    python -m repro.obs                     # live registry of this process
+    python -m repro.obs report              # same
     python -m repro.obs trace OUT.json      # live flight recorder -> Perfetto
     python -m repro.obs trace IN OUT.json   # re-export a --trace dump
-
-``diff`` is the per-PR perf-trajectory tool: run a benchmark on main,
-run it on your branch, diff the two BENCH files.  Exits 0 always — the
-numbers are for humans; regression gates belong in the benchmarks
-themselves.
 
 ``trace`` writes a Chrome-trace-event JSON (open in
 https://ui.perfetto.dev or ``chrome://tracing``): slots as tracks,
 requests as flow-connected queued→prefill→decode slices.  With one
 path it dumps THIS process's live ring (useful after an in-process
 serve); with two it re-derives the view from a file previously written
-by ``benchmarks/serve_stream.py --trace`` / ``launch.serve --trace``
-(raw events ride inside the file), printing the per-request derived
-metrics either way.
+by ``launch.serve --trace`` (raw events ride inside the file), printing
+the per-request derived metrics either way.
 """
 from __future__ import annotations
 
@@ -38,34 +29,6 @@ def _fmt(v) -> str:
     if isinstance(v, float) and v == int(v) and abs(v) < 1e15:
         return str(int(v))
     return f"{v:.4g}" if isinstance(v, float) else str(v)
-
-
-def _show(path: pathlib.Path) -> None:
-    doc = obs.load_bench(path)
-    print(f"== {path.name} (bench={doc['bench']}, "
-          f"schema={doc['schema']}) ==")
-    meta = doc.get("meta", {})
-    if meta:
-        print("  meta: " + ", ".join(f"{k}={v}" for k, v in
-                                     sorted(meta.items())))
-    for key, val in sorted(obs._scalar_metrics(doc).items()):
-        print(f"  {key:<52s} {_fmt(val)}")
-    rows = doc.get("router", [])
-    if rows:
-        print(f"  -- router shape histogram ({len(rows)} classes) --")
-        for r in rows[:15]:
-            print(f"  {r['op']:<13s} {r['dtype']}/{r['trans']} "
-                  f"class={r['size_class']:<10s} {r['source']:<10s} "
-                  f"x{r['count']}")
-
-
-def _diff(old: pathlib.Path, new: pathlib.Path) -> None:
-    a, b = obs.load_bench(old), obs.load_bench(new)
-    print(f"== diff {old.name} -> {new.name} ==")
-    print(f"{'metric':<52s} {'old':>12s} {'new':>12s} {'change':>9s}")
-    for key, va, vb, pct in obs.diff_bench(a, b):
-        change = f"{pct:+.1f}%" if pct is not None else "-"
-        print(f"{key:<52s} {_fmt(va):>12s} {_fmt(vb):>12s} {change:>9s}")
 
 
 _TRACE_COLS = ("queue_wait_us", "ttft_wait_us", "ttft_prefill_us",
@@ -107,38 +70,17 @@ def _trace(files) -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.obs",
                                  description=__doc__.splitlines()[0])
-    ap.add_argument("cmd", nargs="?", default="list",
-                    choices=["list", "ls", "show", "diff", "report",
-                             "trace"])
-    ap.add_argument("files", nargs="*",
-                    help="BENCH_*.json path(s); for trace: [IN] OUT")
+    ap.add_argument("cmd", nargs="?", default="report",
+                    choices=["report", "trace"])
+    ap.add_argument("files", nargs="*", help="for trace: [IN] OUT")
     args = ap.parse_args(argv)
 
-    if args.cmd == "report":
-        print(obs.report_str())
-        return 0
-    if args.cmd == "show":
-        if len(args.files) != 1:
-            ap.error("show takes exactly one BENCH file")
-        _show(pathlib.Path(args.files[0]))
-        return 0
-    if args.cmd == "diff":
-        if len(args.files) != 2:
-            ap.error("diff takes exactly two BENCH files: OLD NEW")
-        _diff(pathlib.Path(args.files[0]), pathlib.Path(args.files[1]))
-        return 0
     if args.cmd == "trace":
         if _trace(args.files) != 0:
             ap.error("trace takes OUT.json (live ring) or IN.json OUT.json "
                      "(re-export a dump)")
         return 0
-    found = sorted(obs.bench_root().glob("BENCH_*.json"))
-    if not found:
-        print(f"no BENCH_*.json under {obs.bench_root()} — run "
-              f"`python benchmarks/serve_stream.py` to produce one")
-        return 0
-    for p in found:
-        _show(p)
+    print(obs.report_str())
     return 0
 
 

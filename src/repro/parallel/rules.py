@@ -12,11 +12,10 @@ mesh with divisibility-aware fallbacks, implementing:
         layer scan)
   DP    batch -> ("pod", "data") — the pod axis is *pure* DP so the only
         cross-pod traffic is one gradient reduce per step (DCN-friendly)
-  SP    cache_seq -> "data" for the batch-1 long-context decode cells
 
 Indivisible cases (smollm's 15 heads, gemma3's 4 heads on a 16-way model
 axis) fall back to replication for that tensor — recorded by
-``Rules.report()`` so the dry-run log shows every fallback explicitly.
+``Rules.report()``, which lists every fallback explicitly.
 """
 from __future__ import annotations
 
@@ -136,31 +135,3 @@ def data_shardings(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     emb = NamedSharding(mesh, P(b, None, None))
     return {"tokens": tok, "labels": tok, "prefix_embeds": emb,
             "src_embeds": emb}
-
-
-def cache_shardings(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
-                    rules: Rules) -> Dict[str, P]:
-    """PartitionSpecs for KV/SSM cache tensors (leading L or n_apps dim).
-
-    batch >= data axis -> shard batch; batch == 1 (long-context) -> shard
-    the cache *sequence* dim over data (SP for decode)."""
-    b = batch_spec(mesh, shape.global_batch)
-    kvh = rules.table.get("kv_heads")
-    seq = None
-    if b is None:
-        seq = "data"                        # SP: context-parallel cache
-    elif kvh is None:
-        # kv heads replicated (indivisible): shard the cache sequence dim
-        # over model instead — decode softmax pays a small AR, the cache
-        # pays nothing (§Perf iteration 6: 35 GiB -> ~4 GiB on smollm)
-        seq = "model"
-    attn = P(None, b, kvh, seq, None)
-    return {
-        "attn_k": attn, "attn_v": attn,
-        "shared_k": attn, "shared_v": attn,
-        "conv": P(None, b, None, rules.table.get("inner")),
-        "ssm": P(None, b, rules.table.get("ssm_heads"), None, None),
-        "self_k": attn, "self_v": attn,
-        "cross_k": attn, "cross_v": attn,
-        "pos": P(),
-    }

@@ -1,42 +1,32 @@
-"""Serving engines: the slot-level paged engine (default) and the
-wave-based reference batcher.
+"""The serving engine: :class:`PagedEngine`, slot-level continuous
+batching over a paged KV cache.
 
 decode-time projections are (B x d) @ (d x N) GEMMs with tiny B — the
-paper's small-GEMM regime.  Both engines take ONE :class:`repro.api.Policy`
+paper's small-GEMM regime.  The engine takes ONE :class:`repro.api.Policy`
 at construction (installed for the whole serving session — not re-entered
 per projection); ``Policy(backend="tuned")`` routes those decode GEMMs
 and the MoE expert FFN by the measured DeviceProfile.
 
-:class:`PagedEngine` is the production loop for EVERY decoder-only
-family: a block/paged KV cache plus per-slot recurrent state
-(:mod:`repro.serve.paged`), slot-level admission/eviction/preemption
-(:mod:`repro.serve.sched`), chunked prefill interleaved with decode,
-sampling fused into the jit'd decode step, and asynchronous token
-draining — so the decode batch B stays slot-stable (the Router sees a
-stationary shape histogram) and no per-token host sync starves the
-tuned kernels.
-
-:class:`ContinuousBatcher` is the wave-based reference implementation:
-a wave shares one padded prefill and slots only refill between waves.
-It is NOT a production path any more — it survives as the parity
-oracle (``slots=1`` is exact unbatched generation, what the paged
-differential tests compare against) and for engine-vs-engine
-benchmarking in ``benchmarks/serve_stream.py``.
+:class:`PagedEngine` serves EVERY decoder-only family: a block/paged KV
+cache plus per-slot recurrent state (:mod:`repro.serve.paged`),
+slot-level admission/eviction/preemption (:mod:`repro.serve.sched`),
+chunked prefill interleaved with decode, sampling fused into the jit'd
+decode step, and asynchronous token draining — so the decode batch B
+stays slot-stable (the Router sees a stationary shape histogram) and no
+per-token host sync starves the tuned kernels.
 
 Every request is traced through :mod:`repro.obs`: admission wait, time
-to first token, end-to-end latency (all measured from ``submit``),
-slot occupancy and preemptions — the numbers the serving-scale ROADMAP
-items are judged by (``BENCH_serve.json`` via
-``benchmarks/serve_stream.py``).  The paged engine's host loop writes
-its own spans (``serve.*``, :class:`repro.obs.span`) into the flight
-recorder, on the clock the profiler's device trace uses.
+to first token, end-to-end latency (all measured from ``submit``), slot
+occupancy and preemptions.  The engine's host loop writes its own spans
+(``serve.*``, :class:`repro.obs.span`) into the flight recorder, on the
+clock the profiler's device trace uses; the chip benchmark (``bench/``)
+reads them.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import time
-from typing import Deque, Dict, List, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,22 +37,6 @@ from repro.api import Policy
 from repro.models.registry import Model
 from repro.serve import sched
 from repro.serve.paged import CacheMap, OutOfBlocks, SlotStateStore
-
-
-def make_serve_fns(model: Model, be: Optional[Policy] = None):
-    """Returns (prefill_fn, decode_fn), both jit'd; decode donates cache.
-    ``be=None`` snapshots the ambient installed policy once, here — the
-    model-entry install point."""
-    pol = be if be is not None else api.current_policy()
-
-    def prefill(params, batch):
-        return model.prefill(params, batch, pol)
-
-    def decode(params, tokens, cache):
-        return model.decode(params, {"tokens": tokens}, cache, pol)
-
-    return (jax.jit(prefill),
-            jax.jit(decode, donate_argnums=(2,)))
 
 
 def sample(logits, key, temperature: float = 0.0,
@@ -118,7 +92,7 @@ def _round_up(n: int, m: int) -> int:
 
 
 # ==========================================================================
-# The paged engine (default).
+# The paged engine.
 # ==========================================================================
 
 class PagedEngine:
@@ -200,7 +174,7 @@ class PagedEngine:
         self._decode_fn = jax.jit(decode, donate_argnums=(2,))
         self._prefill_fn = jax.jit(prefill, donate_argnums=(2,))
 
-    # -- API (mirrors ContinuousBatcher) -----------------------------------
+    # -- API ---------------------------------------------------------------
 
     def submit(self, req: Request) -> None:
         req.t_submit = time.perf_counter()
@@ -379,10 +353,10 @@ class PagedEngine:
             tok = int(np.asarray(tok))
             sp.info = (time.perf_counter() - t_sync) * 1e6
             seq.out.append(tok)
-            # like the wave reference, the request's FIRST token is
-            # exempt from EOS (a request always yields at least one
-            # token); a post-preemption boundary token is an ordinary
-            # decode token and does get the EOS check
+            # the request's FIRST token is exempt from EOS (a request
+            # always yields at least one token); a post-preemption
+            # boundary token is an ordinary decode token and does get
+            # the EOS check
             done = (tok == self.eos and len(seq.out) > 1) \
                 or len(seq.out) >= seq.req.max_new
             if not done:
@@ -424,120 +398,3 @@ class PagedEngine:
         obs.TRACE.emit("FINISH", rid=seq.rid, slot=seq.slot,
                        arg=len(seq.out))
         self.scheduler.finish(seq)
-
-
-# ==========================================================================
-# The wave-based reference engine.
-# ==========================================================================
-
-class ContinuousBatcher:
-    """Wave-based continuous batching over a fixed decode batch.
-
-    Simplification vs the paged engine: prompts in one admission wave
-    share a prefill call (padded to the longest), ``cache_len`` is
-    pre-committed for the whole wave, and slots only refill between
-    waves.  Retired from production serving (the launcher only builds
-    :class:`PagedEngine` now); kept as the parity ORACLE — ``slots=1``
-    is exact unbatched generation, the baseline the paged differential
-    suite compares every family against — and for the engine-vs-engine
-    benchmark in ``benchmarks/serve_stream.py``."""
-
-    def __init__(self, model: Model, params, be: Optional[Policy] = None,
-                 *, slots: int = 4, max_len: int = 256, eos: int = 2,
-                 temperature: float = 0.0, seed: int = 0):
-        # the policy is resolved ONCE at engine construction (model
-        # entry); every projection below reads this frozen object.
-        be = be if be is not None else api.current_policy()
-        self.model, self.params, self.be = model, params, be
-        self.slots, self.max_len, self.eos = slots, max_len, eos
-        self.temperature = temperature
-        self.key = jax.random.PRNGKey(seed)
-        self.queue: Deque[Request] = collections.deque()
-        self.done: Dict[int, List[int]] = {}
-
-        def _decode(p, t, c, k):
-            logits, c = model.decode(p, {"tokens": t}, c, be)
-            # sampling fused into the step: only (B,) token ids cross
-            # to the host, never the (B, V) logits
-            return sample(logits, k, temperature,
-                          model.cfg.vocab).astype(jnp.int32), c
-
-        self._decode = jax.jit(_decode)
-
-    def submit(self, req: Request) -> None:
-        req.t_submit = time.perf_counter()
-        obs.counter("serve.requests").inc()
-        self.queue.append(req)
-
-    def step(self) -> bool:
-        """Admit and run ONE wave from the queue; False when idle.  The
-        streaming benchmark drives this directly so new arrivals can be
-        submitted between waves (Poisson arrivals against a wave-based
-        scheduler — the admission-wait histogram prices that gap)."""
-        if not self.queue:
-            return False
-        wave = [self.queue.popleft() for _ in range(
-            min(self.slots, len(self.queue)))]
-        self._run_wave(wave)
-        return True
-
-    def run(self) -> Dict[int, List[int]]:
-        while self.step():
-            pass
-        return self.done
-
-    def _run_wave(self, wave: List[Request]) -> None:
-        B = len(wave)
-        t_admit = time.perf_counter()
-        adm = obs.histogram("serve.admission_wait_us")
-        for r in wave:
-            adm.record((t_admit - r.t_submit) * 1e6)
-        obs.histogram("serve.wave_occupancy").record(B / self.slots)
-        S = max(len(r.prompt) for r in wave)
-        toks = np.zeros((B, S), np.int32)
-        for i, r in enumerate(wave):
-            toks[i, S - len(r.prompt):] = r.prompt     # left-pad
-        max_new = max(r.max_new for r in wave)
-        with obs.span("serve.prefill"):
-            logits, cache = self.model.prefill(
-                self.params, {"tokens": jnp.asarray(toks)}, self.be,
-                cache_len=min(S + max_new, self.max_len))
-            logits = jax.block_until_ready(logits)
-        outs = [[] for _ in wave]
-        alive = np.ones(B, bool)
-        cur = np.asarray(sample(logits, self.key, self.temperature,
-                                self.model.cfg.vocab))
-        t_first = time.perf_counter()
-        ttft = obs.histogram("serve.ttft_us")
-        for i in range(B):
-            outs[i].append(int(cur[i]))
-            ttft.record((t_first - wave[i].t_submit) * 1e6)
-        steps = max(r.max_new for r in wave) - 1
-        decoded = 0
-        cur_dev = jnp.asarray(cur.astype(np.int32))
-        with obs.span("serve.decode"):
-            for _ in range(max(steps, 0)):
-                if not alive.any():
-                    break
-                self.key, k = jax.random.split(self.key)
-                cur_dev, cache = self._decode(
-                    self.params, cur_dev[:, None], cache, k)
-                cur = np.asarray(cur_dev)
-                for i in range(B):
-                    if alive[i]:
-                        tok = int(cur[i])
-                        outs[i].append(tok)
-                        decoded += 1
-                        if tok == self.eos or \
-                                len(outs[i]) >= wave[i].max_new:
-                            alive[i] = False
-        t_done = time.perf_counter()
-        if decoded and t_done > t_first:
-            obs.histogram("serve.decode_tok_s").record(
-                decoded / (t_done - t_first))
-        e2e = obs.histogram("serve.e2e_us")
-        toks_out = obs.counter("serve.tokens")
-        for r, o in zip(wave, outs):
-            self.done[r.rid] = o
-            e2e.record((t_done - r.t_submit) * 1e6)
-            toks_out.inc(len(o))

@@ -1,6 +1,6 @@
 """Train-step builder: mixed precision, microbatched gradient accumulation,
-family-aware loss, optimizer fusion — the function the dry-run lowers and
-the trainer runs.
+family-aware loss, optimizer fusion — the function the trainer jits and
+runs.
 """
 from __future__ import annotations
 

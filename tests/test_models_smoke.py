@@ -1,6 +1,6 @@
 """Per-architecture smoke tests (deliverable f): reduced same-family
 configs, one forward + one train step on CPU, asserting shapes + no NaNs;
-plus prefill/decode consistency and param/spec structure invariants."""
+plus serving-path consistency and param/spec structure invariants."""
 import dataclasses
 
 import jax
@@ -9,8 +9,6 @@ import pytest
 
 from repro import configs
 from repro.models import frontends, registry
-from repro.models import lm as LM
-from repro.models import encdec as ED
 from repro.models.common import XLA, assert_same_structure, count_params
 from repro.train import loop as TL
 
@@ -71,29 +69,33 @@ def test_param_spec_structures_match(arch):
     assert_same_structure(params, model.specs())
 
 
-@pytest.mark.parametrize("arch", configs.ARCH_IDS)
+# the families with a serving path: every one but encoder-decoder
+DECODER_ONLY = [a for a in configs.ARCH_IDS
+                if configs.get_config(a).family not in ("encdec", "audio")]
+
+
+@pytest.mark.parametrize("arch", DECODER_ONLY)
 def test_prefill_decode_consistency(arch):
+    """The serving path (one slot: a prompt in one paged prefill chunk,
+    then one paged decode step) against ``forward_train`` over the same
+    tokens.  The VLM runs without a modality prefix: the paged path
+    takes none."""
     cfg = dataclasses.replace(configs.get_smoke(arch), dtype="float32")
     model = registry.build(cfg)
     params = model.init(KEY)
-    B, S = 2, 17
-    tok = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab)
-    full = {"tokens": tok}
-    pref = {"tokens": tok[:, :S]}
-    se = pe = None
-    if cfg.frontend == "vision":
-        pe = frontends.fake_frontend(KEY, cfg, B, S, jnp.float32)
-        full["prefix_embeds"] = pref["prefix_embeds"] = pe
-    if cfg.frontend == "audio":
-        se = frontends.fake_frontend(KEY, cfg, B, S, jnp.float32)
-        full["src_embeds"] = pref["src_embeds"] = se
-    logits_full, _ = model.forward_train(params, full, XLA)
-    extra = cfg.frontend_tokens if cfg.frontend == "vision" else 0
-    lp, cache = model.prefill(params, pref, XLA, cache_len=S + extra + 1)
-    ld, _ = model.decode(params, {"tokens": tok[:, S:S + 1]}, cache, XLA)
+    S, BS, NMAX = 17, 8, 3
+    tok = jax.random.randint(KEY, (1, S + 1), 0, cfg.vocab)
+    logits_full, _ = model.forward_train(params, {"tokens": tok}, XLA)
+    ps = model.init_paged_state(1 + NMAX, BS, 1, jnp.float32)
+    table = jnp.arange(1, NMAX + 1, dtype=jnp.int32)[None]
+    lp, ps = model.paged_prefill(params, {"tokens": tok[:, :S]}, ps, table,
+                                 jnp.zeros((1,), jnp.int32), 0, S, S, XLA)
+    ld, _ = model.paged_decode(params, {"tokens": tok[:, S:]}, ps, table,
+                               jnp.full((1,), S, jnp.int32),
+                               jnp.ones((1,), bool), XLA)
     scale = float(jnp.abs(logits_full).max()) + 1e-6
-    assert float(jnp.abs(lp - logits_full[:, -2]).max()) / scale < 1e-4
-    assert float(jnp.abs(ld - logits_full[:, -1]).max()) / scale < 1e-4
+    assert float(jnp.abs(lp - logits_full[:, :S]).max()) / scale < 1e-4
+    assert float(jnp.abs(ld[:, 0] - logits_full[:, S]).max()) / scale < 1e-4
 
 
 def test_full_configs_match_assignment():

@@ -1,7 +1,5 @@
 """repro.obs: registry semantics, histogram accuracy, spans, the Router
-shape log / decision memo, BENCH export, and the kill switch."""
-import json
-
+shape log / decision memo, the kill switch and the CLI."""
 import numpy as np
 import pytest
 
@@ -327,34 +325,6 @@ def test_routes_windowed_caps_and_validates():
     assert obs.ROUTES.windowed(4, bucket_s=1.0, now=200.0) == [{}]
 
 
-# -- BENCH export -----------------------------------------------------------
-
-def test_export_load_diff_roundtrip(tmp_path):
-    obs.counter("t.reqs").inc(10)
-    obs.histogram("t.lat_us").record(100.0)
-    _route_batch(Policy(backend="auto"))
-    p1 = obs.export_bench("t1", {"note": "a"}, root=tmp_path)
-    assert p1.name == "BENCH_t1.json"
-    doc = obs.load_bench(p1)
-    assert doc["schema"] == obs.BENCH_SCHEMA_VERSION
-    assert doc["meta"] == {"note": "a"}
-    assert doc["metrics"]["t.reqs"]["value"] == 10
-    assert sum(r["count"] for r in doc["router"]) == 6
-    # second run with more traffic diffs cleanly
-    obs.counter("t.reqs").inc(10)
-    p2 = obs.export_bench("t2", root=tmp_path)
-    rows = {r[0]: r for r in obs.diff_bench(doc, obs.load_bench(p2))}
-    _, old, new, pct = rows["t.reqs"]
-    assert (old, new) == (10.0, 20.0) and pct == 100.0
-
-
-def test_load_bench_rejects_wrong_schema(tmp_path):
-    bad = tmp_path / "BENCH_bad.json"
-    bad.write_text(json.dumps({"bench": "bad", "schema": 999}))
-    with pytest.raises(ValueError):
-        obs.load_bench(bad)
-
-
 # -- kill switch ------------------------------------------------------------
 
 def test_env_parse_only_explicit_off_disables():
@@ -402,40 +372,10 @@ def test_cli_report_prints_live_registry(capsys):
     assert rc == 0 and "repro.obs report" in out and "t.cli" in out
 
 
-def test_cli_ls_and_show(tmp_path, monkeypatch, capsys):
-    monkeypatch.setenv("REPRO_BENCH_DIR", str(tmp_path))
-    obs.counter("t.reqs").inc(4)
-    obs.export_bench("one", {"note": "x"}, root=tmp_path)
-    for cmd in ("ls", "list"):
-        rc, out = _cli(capsys, cmd)
-        assert rc == 0 and "BENCH_one.json" in out and "t.reqs" in out
-    rc, out = _cli(capsys, "show", str(tmp_path / "BENCH_one.json"))
-    assert rc == 0 and "note=x" in out
-
-
-def test_cli_ls_empty_dir_hints(tmp_path, monkeypatch, capsys):
-    monkeypatch.setenv("REPRO_BENCH_DIR", str(tmp_path))
-    rc, out = _cli(capsys, "ls")
-    assert rc == 0 and "no BENCH_*.json" in out
-
-
-def test_cli_diff_percent_change_rows(tmp_path, capsys):
-    obs.counter("t.reqs").inc(10)
-    p1 = obs.export_bench("old", root=tmp_path)
-    obs.counter("t.reqs").inc(5)
-    obs.counter("t.fresh").inc(1)        # one-sided key prints "-"
-    p2 = obs.export_bench("new", root=tmp_path)
-    rc, out = _cli(capsys, "diff", str(p1), str(p2))
-    assert rc == 0
-    row = next(ln for ln in out.splitlines() if ln.startswith("t.reqs"))
-    assert "+50.0%" in row and "10" in row and "15" in row
-    fresh = next(ln for ln in out.splitlines() if ln.startswith("t.fresh"))
-    assert fresh.rstrip().endswith("-")
-
-
 def test_cli_arity_errors_exit_nonzero():
     from repro.obs.__main__ import main
-    for argv in (["show"], ["diff", "one.json"], ["show", "a", "b"]):
+    # trace with no file or with three; show, which the CLI has not
+    for argv in (["trace"], ["trace", "a", "b", "c"], ["show"]):
         with pytest.raises(SystemExit) as ei:
             main(argv)
         assert ei.value.code != 0

@@ -1,11 +1,11 @@
 """Seeded differential fuzz: random Poisson arrival traces through the
-paged engine vs the wave oracle at temperature 0.
+paged engine vs each request served alone, at temperature 0.
 
 Each case draws a workload trace — Poisson inter-arrival gaps measured
 in engine steps, mixed prompt lengths, mixed budgets — replays it into
 a :class:`PagedEngine` whose pool is sized to force occasional
-preemption, and demands token-identity with the single-request wave
-reference for EVERY registry family.  Seeded, so a failure is a repro,
+preemption, and demands token-identity with the single-request runs of
+:func:`serve_oracle.serve_alone` for EVERY registry family.  Seeded, so a failure is a repro,
 not a flake.  The full matrix is marked ``slow``; CI runs a small
 instance (one ssm case) via ``-k``.
 """
@@ -19,11 +19,12 @@ from repro import api, configs, obs
 from repro.core.kernelgen import KernelSig
 from repro.models import registry
 from repro.models.common import XLA
-from repro.serve import ContinuousBatcher, PagedEngine, Request
+from repro.serve import PagedEngine, Request
 from repro.tune import classes as tune_classes, profile as profile_mod
 from repro.tune.online import OnlineTuner
 from repro.tune.profile import DeviceProfile, ProfileEntry
 from repro.tune.timer import Measurement
+from serve_oracle import serve_alone
 
 pytestmark = pytest.mark.slow
 
@@ -50,7 +51,7 @@ def get_model():
 
 @pytest.mark.parametrize("seed", (0, 1), ids=("s0", "s1"))
 @pytest.mark.parametrize("arch", FUZZ_ARCHS)
-def test_fuzz_poisson_trace_matches_wave(get_model, arch, seed):
+def test_fuzz_poisson_trace_matches_serve_alone(get_model, arch, seed):
     cfg, model, params = get_model(arch)
     rng = np.random.RandomState(1000 * FUZZ_ARCHS.index(arch) + seed)
     n = 7
@@ -60,12 +61,7 @@ def test_fuzz_poisson_trace_matches_wave(get_model, arch, seed):
     maxnew = [int(rng.randint(2, 10)) for _ in range(n)]
     arrivals = np.cumsum(rng.poisson(3, size=n))
 
-    # oracle: strictly sequential single-request runs
-    ref = {}
-    b = ContinuousBatcher(model, params, XLA, slots=1, max_len=64, eos=-1)
-    for rid in range(n):
-        b.submit(Request(rid, prompts[rid], max_new=maxnew[rid]))
-    ref = b.run()
+    ref = serve_alone(model, params, prompts, maxnew)
 
     # pool of 7 usable blocks x 8 << 3 slots' worst case -> preemption
     # pressure; fits_ever still holds for every single request

@@ -1,11 +1,11 @@
 """repro.serve.paged + sched + PagedEngine: block allocator properties,
-scheduler state machine, and end-to-end parity with the wave reference.
+scheduler state machine, and end-to-end parity with each request served
+alone.
 
-The parity oracle is the wave engine at ``slots=1``: the wave engine
-left-pads mixed-length prompts within a wave (pad tokens shift
-positions), so its multi-slot outputs are batch-composition dependent —
-only the unbatched run is the exact per-request generation the paged
-engine must reproduce at temperature 0.
+The parity oracle is :func:`serve_oracle.serve_alone`: the same engine at
+``slots=1`` with a pool that never preempts, whose every request is in
+turn held to the float32 ``forward_train`` reference.  A multi-slot run
+must reproduce it token for token at temperature 0.
 """
 import random
 
@@ -16,10 +16,10 @@ import pytest
 from repro import configs, obs
 from repro.models import registry
 from repro.models.common import XLA
-from repro.serve import (BlockAllocator, CacheMap, ContinuousBatcher,
-                         OutOfBlocks, PagedEngine, Request, Seq,
-                         SlotScheduler)
+from repro.serve import (BlockAllocator, CacheMap, OutOfBlocks,
+                         PagedEngine, Request, Seq, SlotScheduler)
 from repro.serve import sched
+from serve_oracle import TOL, reference_gaps, reference_logits, serve_alone
 
 KEY = jax.random.PRNGKey(0)
 
@@ -136,12 +136,12 @@ def test_scheduler_rejects_never_fitting_request():
 
 
 # --------------------------------------------------------------------------
-# End-to-end parity with the wave reference, over EVERY registry family.
+# End-to-end parity with serving alone, over EVERY registry family.
 # --------------------------------------------------------------------------
 
 # one smoke arch per decoder-only family: dense, MoE, VLM, ssm, hybrid —
 # the parity suite runs each so a family can't silently lose its paged
-# path again (the pre-PR regression: ssm/hybrid fell back to the wave)
+# path
 PARITY_ARCHS = ("olmo-1b", "moonshot-v1-16b-a3b", "internvl2-2b",
                 "mamba2-780m", "zamba2-7b")
 
@@ -167,28 +167,17 @@ def smoke(get_model):
     return get_model("olmo-1b")
 
 
-def _wave_ref(model, params, prompts, maxnew, eos=-1):
-    """Unbatched wave-engine generations (the exact per-request oracle).
-    One batcher at slots=1 runs the queue strictly sequentially, so its
-    outputs are the per-request generations free of the wave engine's
-    left-pad batch-composition effects."""
-    b = ContinuousBatcher(model, params, XLA, slots=1, max_len=64, eos=eos)
-    for rid, (p, mn) in enumerate(zip(prompts, maxnew)):
-        b.submit(Request(rid, p, max_new=mn))
-    return b.run()
-
-
 @pytest.mark.parametrize("arch", PARITY_ARCHS)
 def test_paged_parity_mixed_lengths_mid_decode_admission(get_model, arch):
-    """Token-identical to the wave engine at temperature 0 across mixed
-    prompt lengths / budgets, with half the requests admitted mid-decode
-    of the others."""
+    """Token-identical to serving each request alone at temperature 0
+    across mixed prompt lengths / budgets, with half the requests
+    admitted mid-decode of the others."""
     cfg, model, params = get_model(arch)
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab, n).astype(np.int32)
                for n in (5, 9, 3, 17, 2)]
     maxnew = [6, 5, 6, 3, 8]
-    ref = _wave_ref(model, params, prompts, maxnew)
+    ref = serve_alone(model, params, prompts, maxnew)
 
     e = PagedEngine(model, params, XLA, slots=2, max_len=64, eos=-1,
                     block_size=8, chunk=8)
@@ -213,7 +202,7 @@ def test_paged_parity_under_preemption(get_model, arch):
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, cfg.vocab, 7).astype(np.int32)
                for _ in range(2)]
-    ref = _wave_ref(model, params, prompts, [10, 10])
+    ref = serve_alone(model, params, prompts, [10, 10])
 
     # capacity 3 blocks x 8 = 24 tokens; each request needs 2 blocks by
     # mid-decode, so demand hits 4 > 3 and the younger request cycles
@@ -240,15 +229,16 @@ def test_no_engine_fallback_for_registry_families():
     assert obs.counter("serve.engine_fallback").value == 0
 
 
-def test_paged_parity_eos_eviction(smoke):
-    """EOS truncation matches the wave engine and returns blocks."""
-    cfg, model, params = smoke
+@pytest.mark.parametrize("arch", PARITY_ARCHS)
+def test_paged_parity_eos_eviction(get_model, arch):
+    """EOS truncation matches serving alone and returns blocks."""
+    cfg, model, params = get_model(arch)
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, cfg.vocab, n).astype(np.int32)
                for n in (4, 6)]
-    free_run = _wave_ref(model, params, prompts, [8, 8])
+    free_run = serve_alone(model, params, prompts, [8, 8])
     eos = free_run[0][2]                        # a token that WILL appear
-    ref = _wave_ref(model, params, prompts, [8, 8], eos=eos)
+    ref = serve_alone(model, params, prompts, [8, 8], eos=eos)
     assert any(len(v) < 8 for v in ref.values())    # eviction exercised
 
     e = PagedEngine(model, params, XLA, slots=2, max_len=64, eos=eos,
@@ -257,6 +247,22 @@ def test_paged_parity_eos_eviction(smoke):
         e.submit(Request(rid, p, max_new=8))
     assert e.run() == ref
     assert e.cache.blocks_in_use == 0
+
+
+@pytest.mark.parametrize("arch", PARITY_ARCHS)
+def test_reference_check_rejects_a_wrong_token(get_model, arch):
+    """Negative control of the reference check: one served token swapped
+    for the id the reference ranks last fails it, by at least 4x TOL,
+    while the tokens before it still pass."""
+    cfg, model, params = get_model(arch)
+    rng = np.random.RandomState(5)
+    prompt = rng.randint(0, cfg.vocab, 9).astype(np.int32)
+    out = serve_alone(model, params, [prompt], [6])[0]
+    bad = list(out)
+    bad[-1] = int(reference_logits(model, params, prompt, out)[-1].argmin())
+    gaps = reference_gaps(model, params, prompt, bad)
+    assert gaps[:-1].max() <= TOL
+    assert gaps[-1] >= 4 * TOL, (arch, gaps[-1])
 
 
 def test_chunked_prefill_does_not_starve_decode(smoke):

@@ -8,7 +8,7 @@ state), so any bookkeeping slip — a row not zero-reset on reuse, an
 inactive row advanced by a masked decode step, a preempted request
 resuming on a stale carry — silently leaks one request's recurrence
 into another's tokens.  Every end-to-end check therefore compares
-against single-request reference runs (the wave oracle at ``slots=1``),
+against single-request runs (:func:`serve_oracle.serve_alone`, one slot),
 where no sharing exists by construction.
 """
 import jax
@@ -19,8 +19,9 @@ import pytest
 from repro import configs, obs
 from repro.models import registry
 from repro.models.common import XLA
-from repro.serve import (CacheMap, ContinuousBatcher, PagedEngine, Request,
-                         Seq, SlotScheduler, SlotStateStore)
+from repro.serve import (CacheMap, PagedEngine, Request, Seq, SlotScheduler,
+                         SlotStateStore)
+from serve_oracle import serve_alone
 
 KEY = jax.random.PRNGKey(0)
 
@@ -37,14 +38,6 @@ def get_model():
         return cache[arch]
 
     return get
-
-
-def _wave_ref(model, params, prompts, maxnew, eos=-1):
-    """Single-request reference runs (slots=1 processes sequentially)."""
-    b = ContinuousBatcher(model, params, XLA, slots=1, max_len=64, eos=eos)
-    for rid, (p, mn) in enumerate(zip(prompts, maxnew)):
-        b.submit(Request(rid, p, max_new=mn))
-    return b.run()
 
 
 # --------------------------------------------------------------------------
@@ -103,8 +96,8 @@ def test_scheduler_keeps_store_in_lockstep():
 def test_masked_decode_freezes_inactive_carries(get_model, arch):
     """A decode step with a slot masked inactive must leave that slot's
     conv/ssm rows bitwise unchanged and touch no pool block but the
-    null sink (block 0), while each active slot's rows equal the wave
-    oracle's carry after the same tokens — mamba2 exercises the
+    null sink (block 0), while each active slot's rows equal the carry
+    of a one-slot decode of the same tokens — mamba2 exercises the
     recurrent rows alone, zamba2 the rows and the shared-attention pool
     in one model."""
     cfg, model, params = get_model(arch)
@@ -119,13 +112,25 @@ def test_masked_decode_freezes_inactive_carries(get_model, arch):
     def same(a, b):
         return bool(jnp.all(a == b))
 
+    def alone(s, *steps):
+        """Slot s's tokens of ``steps``, decoded in a one-slot state."""
+        one = model.init_paged_state(2, 8, 1)
+        table = jnp.array([[1, 0, 0, 0]], jnp.int32)
+        for t, step in enumerate(steps):
+            _, one = model.paged_decode(
+                params, {"tokens": step["tokens"][s:s + 1]}, one, table,
+                jnp.array([t], jnp.int32), jnp.ones((1,), bool), XLA)
+        return one
+
     # one all-active step so the carries are non-zero (a frozen zero
-    # row proves nothing); the oracle primes its cache on the same token
+    # row proves nothing); each slot's rows are its one-slot run's
     _, ps1 = model.paged_decode(params, toks, ps, own, pos,
                                 jnp.ones((slots,), bool), XLA)
     assert bool(jnp.any(ps1.conv != 0)) and bool(jnp.any(ps1.ssm != 0))
-    _, wave = model.prefill(params, toks, XLA, cache_len=2)
-    assert same(ps1.conv, wave.conv) and same(ps1.ssm, wave.ssm)
+    for s in range(slots):
+        one = alone(s, toks)
+        assert same(ps1.conv[:, s], one.conv[:, 0])
+        assert same(ps1.ssm[:, s], one.ssm[:, 0])
 
     # all-inactive step: different tokens, nothing may move
     _, ps2 = model.paged_decode(params, nxt, ps1, null, pos + 1,
@@ -135,7 +140,7 @@ def test_masked_decode_freezes_inactive_carries(get_model, arch):
         assert same(ps2.shared_k[:, 1:], ps1.shared_k[:, 1:])
         assert same(ps2.shared_v[:, 1:], ps1.shared_v[:, 1:])
 
-    # mixed step: only the active slot's rows advance, as the oracle's do
+    # mixed step: only the active slot's rows advance, as alone they do
     act = jnp.array([False, True, False])
     _, ps3 = model.paged_decode(params, nxt, ps2, null.at[1].set(own[1]),
                                 pos + 1, act, XLA)
@@ -143,13 +148,13 @@ def test_masked_decode_freezes_inactive_carries(get_model, arch):
         assert same(ps3.conv[:, s], ps2.conv[:, s])
         assert same(ps3.ssm[:, s], ps2.ssm[:, s])
     assert bool(jnp.any(ps3.ssm[:, 1] != ps2.ssm[:, 1]))
-    _, wave = model.decode(params, nxt, wave, XLA)
-    assert same(ps3.conv[:, 1], wave.conv[:, 1])
-    assert same(ps3.ssm[:, 1], wave.ssm[:, 1])
+    one = alone(1, toks, nxt)
+    assert same(ps3.conv[:, 1], one.conv[:, 0])
+    assert same(ps3.ssm[:, 1], one.ssm[:, 0])
 
 
 # --------------------------------------------------------------------------
-# Property-style slot isolation (end-to-end vs single-request oracle).
+# Property-style slot isolation (end-to-end vs single-request runs).
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("arch,seed", [("mamba2-780m", 0),
@@ -167,7 +172,7 @@ def test_slot_isolation_random_interleaving(get_model, arch, seed):
                            int(rng.randint(2, 20))).astype(np.int32)
                for _ in range(n)]
     maxnew = [int(rng.randint(2, 9)) for _ in range(n)]
-    ref = _wave_ref(model, params, prompts, maxnew)
+    ref = serve_alone(model, params, prompts, maxnew)
 
     e = PagedEngine(model, params, XLA, slots=2, max_len=64, eos=-1,
                     block_size=8, chunk=8, num_blocks=6)
@@ -188,9 +193,9 @@ def test_slot_isolation_eos_evict_and_reuse(get_model):
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, cfg.vocab, p).astype(np.int32)
                for p in (4, 6, 9, 5)]
-    free = _wave_ref(model, params, prompts, [8, 8, 8, 8])
+    free = serve_alone(model, params, prompts, [8, 8, 8, 8])
     eos = free[0][2]                    # a token that WILL appear
-    ref = _wave_ref(model, params, prompts, [8, 8, 8, 8], eos=eos)
+    ref = serve_alone(model, params, prompts, [8, 8, 8, 8], eos=eos)
     assert any(len(v) < 8 for v in ref.values())    # eviction exercised
 
     e = PagedEngine(model, params, XLA, slots=2, max_len=64, eos=eos,
@@ -211,7 +216,7 @@ def test_exhaustion_resume_rebuilds_carry(get_model):
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, cfg.vocab, 7).astype(np.int32)
                for _ in range(2)]
-    ref = _wave_ref(model, params, prompts, [10, 10])
+    ref = serve_alone(model, params, prompts, [10, 10])
 
     e = PagedEngine(model, params, XLA, slots=2, max_len=24, eos=-1,
                     block_size=8, chunk=8, num_blocks=4)

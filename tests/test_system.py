@@ -115,13 +115,3 @@ def test_dispatch_thresholds_route_correctly():
         assert cfg.threshold("TN") == 32
     cfg = api.current_policy()
     assert cfg.threshold("NN") == 80 * api.TPU_SCALE
-
-
-def test_all_cells_enumerated():
-    cells = configs.all_cells()
-    assert len(cells) == 40
-    runnable = [c for c in cells if c[2]]
-    skipped = [c for c in cells if not c[2]]
-    assert len(runnable) == 34
-    assert len(skipped) == 6
-    assert all("full-attention" in c[3] or "500k" in c[3] for c in skipped)

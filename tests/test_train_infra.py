@@ -9,8 +9,6 @@ import pytest
 
 from repro import configs
 from repro.models import registry
-from repro.models.common import XLA
-from repro.serve.engine import ContinuousBatcher, Request
 from repro.train import checkpoint as ck
 from repro.train import data as data_mod
 from repro.train import fault
@@ -143,20 +141,6 @@ def test_training_recovers_after_fault(tmp_path):
         "--seq", "32", "--log-every", "100"])
     out2 = run(args2)
     assert abs(out["loss"] - out2["loss"]) < 1e-4
-
-
-def test_continuous_batcher_serves_all():
-    cfg = configs.get_smoke("olmo-1b")
-    model = registry.build(cfg)
-    params = model.init(KEY)
-    b = ContinuousBatcher(model, params, XLA, slots=2, max_len=64)
-    rng = np.random.RandomState(0)
-    for rid in range(5):
-        b.submit(Request(rid, rng.randint(0, cfg.vocab, 6).astype(np.int32),
-                         max_new=4))
-    done = b.run()
-    assert sorted(done) == [0, 1, 2, 3, 4]
-    assert all(1 <= len(v) <= 4 for v in done.values())
 
 
 def test_elastic_restore_resharding(tmp_path):
