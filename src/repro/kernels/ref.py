@@ -188,14 +188,46 @@ def ref_ssd_recurrent(x, dt, A, B, C, *, D_skip=None):
     return y.astype(x.dtype)
 
 
+def ssd_chunk(h, xc, dtc, A, Bc, Cc, *, precision=None):
+    """One chunk of the chunked SSD from the state ``h`` before it:
+    the intra-chunk 'attention-like' term plus the carried state's
+    contribution, and the state after the chunk.
+
+    h: (Bt,H,P,N) f32; xc: (Bt,c,H,P); dtc: (Bt,c,H); A: (H,) (negative);
+    Bc/Cc: (Bt,c,N).  A row with ``dtc = 0`` neither decays nor feeds the
+    state, and adds nothing to any row's output (``L`` is lower
+    triangular and the input weights carry ``dtc``), so masked rows pass
+    the state through.  ``precision`` goes to every einsum.  Returns
+    (h', y (Bt,c,H,P))."""
+    c = xc.shape[1]
+    tri = jnp.tril(jnp.ones((c, c), bool))
+    dA = dtc * A[None, None, :]                         # (Bt,c,H)
+    cum = jnp.cumsum(dA, axis=1)                        # inclusive
+    tot = cum[:, -1]                                    # (Bt,H)
+    decay = cum[:, :, None, :] - cum[:, None, :, :]     # (Bt,t,s,H)
+    L = jnp.where(tri[None, :, :, None], jnp.exp(decay), 0.0)
+    cb = jnp.einsum("btn,bsn->bts", Cc, Bc, precision=precision)
+    scores = cb[..., None] * L * dtc[:, None]           # (Bt,t,s,H)
+    y = jnp.einsum("btsh,bshp->bthp", scores, xc, precision=precision)
+    y = y + jnp.einsum("btn,bhpn->bthp", Cc, h, precision=precision) \
+        * jnp.exp(cum)[..., None]
+    w = (dtc * jnp.exp(tot[:, None] - cum))[..., None] * xc  # (Bt,c,H,P)
+    h = h * jnp.exp(tot)[..., None, None] \
+        + jnp.einsum("bchp,bcn->bhpn", w, Bc, precision=precision)
+    return h, y
+
+
 def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
-            return_state: bool = False):
+            return_state: bool = False, h0=None, precision=None):
     """Chunked SSD (the paper-of-record algorithm, arXiv:2405.21060 §6):
-    intra-chunk 'attention-like' term + inter-chunk state recurrence.
+    :func:`ssd_chunk` scanned over the chunks, the state carried between
+    them.
 
     Mathematically identical to ``ref_ssd_recurrent``; O(S·c) memory.  This
     is the XLA model path; the Pallas kernel mirrors its block structure
-    (each chunk is a cascade of small GEMMs — IAAT's habitat).
+    (each chunk is a cascade of small GEMMs — IAAT's habitat).  ``h0``
+    (Bt,H,P,N) is the state before the first token (zero by default);
+    ``precision`` goes to every einsum of the chunks.
     """
     Bt, S, H, P = x.shape
     N = B.shape[-1]
@@ -217,29 +249,16 @@ def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
     # production shapes.  The scan keeps the working set at one chunk
     # (exactly the Pallas kernel's schedule) and the checkpointed step
     # keeps the backward pass from stacking the per-chunk scores.
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-
     def step(h, inputs):
         xc, dtc, Bc, Cc = inputs      # (Bt,c,H,P) (Bt,c,H) (Bt,c,N) (Bt,c,N)
-        dA = dtc * A[None, None, :]                     # (Bt,c,H)
-        cum = jnp.cumsum(dA, axis=1)                    # inclusive
-        tot = cum[:, -1]                                # (Bt,H)
-        decay = cum[:, :, None, :] - cum[:, None, :, :]  # (Bt,t,s,H)
-        L = jnp.where(tri[None, :, :, None], jnp.exp(decay), 0.0)
-        cb = jnp.einsum("btn,bsn->bts", Cc, Bc)
-        scores = cb[..., None] * L * dtc[:, None]       # (Bt,t,s,H)
-        y = jnp.einsum("btsh,bshp->bthp", scores, xc)
-        y = y + jnp.einsum("btn,bhpn->bthp", Cc, h) * jnp.exp(cum)[..., None]
-        w = (dtc * jnp.exp(tot[:, None] - cum))[..., None] * xc  # (Bt,c,H,P)
-        h = h * jnp.exp(tot)[..., None, None] \
-            + jnp.einsum("bchp,bcn->bhpn", w, Bc)
-        return h, y
+        return ssd_chunk(h, xc, dtc, A, Bc, Cc, precision=precision)
 
-    from repro.parallel.ctx import constrain
-    h0 = constrain(jnp.zeros((Bt, H, P, N), jnp.float32),
-                   "batch", "ssm_heads", None, None)
+    if h0 is None:
+        from repro.parallel.ctx import constrain
+        h0 = constrain(jnp.zeros((Bt, H, P, N), jnp.float32),
+                       "batch", "ssm_heads", None, None)
     h_last, ys = lax.scan(
-        jax.checkpoint(step), h0,
+        jax.checkpoint(step), h0.astype(jnp.float32),
         (xf.transpose(1, 0, 2, 3, 4), dtf.transpose(1, 0, 2, 3),
          Bf.transpose(1, 0, 2, 3), Cf.transpose(1, 0, 2, 3)))
     y = ys.transpose(1, 0, 2, 3, 4).reshape(Bt, Sp, H, P)[:, :S]

@@ -340,10 +340,16 @@ def _paged_core(params, cfg: ModelConfig, be: Policy, x, ps: PagedState,
     a stacked scan output and copied over.  K/V route through
     ``block_tables`` into the pools; ``decode_from`` (B,) marks the
     original decode boundary so recompute-resume chunks replay those
-    rows with decode numerics (see layers.paged_attend).  Returns
+    rows with decode numerics (see layers.paged_attend and
+    ssm.serve_ssd).  Returns
     (logits, ps-with-new-pools, conv', ssm')."""
     idxs = jnp.arange(cfg.n_layers)
     if cfg.family in ("ssm", "hybrid"):
+        # chunk-local row of the decode boundary: the recurrence replays
+        # the rows from there on with the decode step (ssm.serve_ssd)
+        replay_from = None if decode_from is None \
+            else decode_from - qpos[:, 0]
+
         def body(carry, xs):
             x, conv, ssm_h, sk, sv = carry
             blk, i = xs
@@ -364,7 +370,8 @@ def _paged_core(params, cfg: ModelConfig, be: Policy, x, ps: PagedState,
             row = (lax.dynamic_index_in_dim(conv, i, 0, keepdims=False),
                    lax.dynamic_index_in_dim(ssm_h, i, 0, keepdims=False))
             y, (cv, hh) = S.paged_step(blk["mixer"], h, be, cfg, row,
-                                       seg_len=seg_len, active=active)
+                                       seg_len=seg_len, active=active,
+                                       replay_from=replay_from)
             conv = lax.dynamic_update_index_in_dim(conv, cv, i, 0)
             ssm_h = lax.dynamic_update_index_in_dim(ssm_h, hh, i, 0)
             return (x + y, conv, ssm_h, sk, sv), None
@@ -398,8 +405,8 @@ def paged_prefill(params: Dict, cfg: ModelConfig, be: Policy,
     (1, nmax).  ``n_prompt`` is the request's prompt length: rows at
     positions >= n_prompt only exist on recompute-resume (they replay
     tokens that decode generated before the preemption) and take the
-    decode-path attention numerics so the rebuilt K/V and recurrent
-    carries are bitwise what an unpreempted run would hold.
+    decode-path numerics, in attention and in the SSM recurrence, so a
+    preempted request replays its tokens bit-identically.
 
     When ``pos_start == 0`` — fresh admission OR recompute-resume after
     preemption — the slot's recurrent-carry rows are zero-reset inside

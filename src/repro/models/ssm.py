@@ -1,12 +1,16 @@
 """Mamba-2 block (SSD) — attention-free sequence mixing.
 
-Training runs the chunked SSD (Pallas kernel or jnp oracle).  Every
-serving path — wave prefill, wave decode, paged prefill chunks, paged
-slot decode — runs ONE chunked recurrence with an explicit carry
-(:func:`paged_step`), so the paged engine is token-identical to the
-wave oracle by construction: the recurrent state after any token t is
-the same bit pattern no matter how the tokens were chunked, which is
-what makes recompute-resume after preemption exact at temperature 0.
+Training runs the chunked SSD (Pallas kernel or jnp oracle).  Serving
+runs :func:`paged_step`, one layer over a token chunk with an explicit
+per-slot carry, and picks the recurrence by the chunk length: a prefill
+chunk (C > 1) runs the chunked SSD form (``ref.ssd_chunk``, every dot at
+HIGHEST precision, the state in float32) from the slot's state, and the
+decode step (C == 1) runs the serial one-token step
+(``ref.ref_ssd_decode_step``).  So the carry is bit-stable for a given
+chunking, not across chunkings.  Recompute-resume after preemption stays
+exact at temperature 0 because a replayed request is chunked as it was
+first served: its prompt rows take the chunk numerics, and the rows that
+decode generated before the preemption take the serial step.
 The short causal conv is implemented as ``d_conv`` shifted adds
 (compiles everywhere, no conv primitive needed).
 """
@@ -139,7 +143,7 @@ def mamba(p: Dict, x, be: Policy, cfg: ModelConfig):
 
 
 # --------------------------------------------------------------------------
-# Serving recurrence (paged prefill chunks and slot decode share it).
+# Serving recurrence (paged prefill chunks and slot decode).
 # --------------------------------------------------------------------------
 
 def init_paged_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
@@ -155,11 +159,67 @@ def init_paged_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
     return conv, h
 
 
+def serve_ssd(h, xs, dt, A, Bm, Cm, valid, replay_from=None, *,
+              chunk: int):
+    """The SSM recurrence of one layer over C rows from the state ``h`` —
+    the serving numerics.  h: (B, nh, P, N) f32; xs: (B, C, nh, P) and
+    Bm/Cm: (B, C, N) in the compute dtype; dt: (B, C, nh) f32; valid
+    (B, C): the rows that advance the state (the others get ``dt = 0``,
+    so ``exp(dt*A) = 1`` and their input term vanishes).
+
+    C == 1, the decode step: :func:`ref.ref_ssd_decode_step`.  C > 1, a
+    prefill chunk: the chunked SSD form from ``h``, in sub-chunks of
+    ``min(C, chunk)``, with every dot at HIGHEST precision — on the MXU
+    a default-precision dot would round its float32 operands to
+    bfloat16.  ``replay_from`` (B,) is the chunk-local row at which the
+    rows that decode generated before a preemption start: the chunked
+    form stops there, and only if some valid row lies at or past it do
+    those rows run the serial step from the state it leaves (under
+    ``lax.cond``, so a plain prompt chunk never enters the serial loop).
+    Returns (h', y (B, C, nh, P) f32)."""
+    B, C = xs.shape[:2]
+    if C > 1:
+        rows = jnp.arange(C)[None, :]
+        if replay_from is None:
+            replay_from = jnp.full((B,), C, jnp.int32)
+        chunked = valid & (rows < replay_from[:, None])
+        serial_rows = valid & ~chunked
+        y, h = ref.ref_ssd(
+            xs.astype(jnp.float32), jnp.where(chunked[..., None], dt, 0.0),
+            A, Bm[:, :, None], Cm[:, :, None], chunk=min(C, chunk),
+            return_state=True, h0=h, precision=lax.Precision.HIGHEST)
+    else:
+        serial_rows = valid
+    dt_m = jnp.where(serial_rows[..., None], dt, 0.0)
+
+    def step(hc, xs_t):
+        xt, dtt, Bt, Ct = xs_t
+        return ref.ref_ssd_decode_step(hc, xt, dtt, A, Bt, Ct)
+
+    def serial(h):
+        h, ys = lax.scan(step, h, (
+            xs.transpose(1, 0, 2, 3).astype(jnp.float32),
+            dt_m.transpose(1, 0, 2),
+            Bm.transpose(1, 0, 2).astype(jnp.float32),
+            Cm.transpose(1, 0, 2).astype(jnp.float32)))
+        return h, ys.transpose(1, 0, 2, 3)                # (B, C, nh, P)
+
+    if C == 1:
+        return serial(h)
+
+    def replay(h, y):
+        h, ys = serial(h)
+        return h, jnp.where(serial_rows[..., None, None], ys, y)
+
+    return lax.cond(jnp.any(serial_rows), replay, lambda h, y: (h, y),
+                    h, y)
+
+
 def paged_step(p: Dict, x, be: Policy, cfg: ModelConfig, state: Tuple,
-               *, seg_len=None, active=None):
+               *, seg_len=None, active=None, replay_from=None):
     """One mamba layer over a token chunk with an explicit carry — THE
-    serving-path numerics.  x: (B, C, d); state = (conv_state
-    (B, K-1, ch), h (B, nh, P, N)).
+    serving path.  x: (B, C, d); state = (conv_state (B, K-1, ch),
+    h (B, nh, P, N)).
 
     ``seg_len`` (B,) marks how many of the C positions are real tokens
     (a prefill chunk's tail past the prompt is padding); ``active`` (B,)
@@ -170,11 +230,15 @@ def paged_step(p: Dict, x, be: Policy, cfg: ModelConfig, state: Tuple,
     vanishes), and both are additionally re-selected through
     ``jnp.where`` so inactive rows are bitwise untouched.
 
-    Each valid token undergoes exactly the ops of the one-token decode
-    step, so chunking is invisible to the carry: prefill(prompt) then
-    decode(k tokens) leaves the same state bits as one prefill over
-    prompt+k — the property the recompute-resume parity tests pin down.
-    Returns (y (B, C, d), (conv_state', h'))."""
+    The recurrence is :func:`serve_ssd`: a prefill chunk (C > 1) runs
+    the chunked SSD form, the decode step (C == 1) the serial step.
+    ``replay_from`` (B,) is the chunk-local row from which a
+    recompute-resume chunk replays tokens that decode generated before
+    the preemption; those rows take the serial step, from the state the
+    prompt rows leave, as they did in the request's first run — which
+    keeps a preempted request's tokens bit-identical (the property the
+    recompute-resume parity tests pin down).  Returns (y (B, C, d),
+    (conv_state', h'))."""
     s = cfg.ssm
     B, C, _ = x.shape
     di, N, nh, P = cfg.d_inner, s.d_state, cfg.ssm_heads, s.head_dim
@@ -195,19 +259,8 @@ def paged_step(p: Dict, x, be: Policy, cfg: ModelConfig, state: Tuple,
                            + p["dt_bias"][None, None, :])     # (B, C, nh)
     valid = (jnp.arange(C)[None, :] < seg_len[:, None]) \
         & active[:, None]                                     # (B, C)
-    dt_m = jnp.where(valid[..., None], dt_c, 0.0)
-
-    def step(hc, xs_t):
-        xt, dtt, Bt, Ct = xs_t
-        hc, y_t = ref.ref_ssd_decode_step(hc, xt, dtt, A, Bt, Ct)
-        return hc, y_t
-
-    h_new, ys = lax.scan(step, h, (
-        xs_c.transpose(1, 0, 2, 3).astype(jnp.float32),
-        dt_m.transpose(1, 0, 2),
-        B_c.transpose(1, 0, 2).astype(jnp.float32),
-        C_c.transpose(1, 0, 2).astype(jnp.float32)))
-    y = ys.transpose(1, 0, 2, 3)                              # (B, C, nh, P)
+    h_new, y = serve_ssd(h, xs_c, dt_c, A, B_c, C_c, valid, replay_from,
+                         chunk=s.chunk)
     y = y + p["D"][None, None, :, None] * xs_c.astype(jnp.float32)
     y = y.reshape(B, C, di).astype(x.dtype)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype),

@@ -333,6 +333,10 @@ class PagedEngine:
                 last_idx)
             seq.pos = p0 + len(segment)
             obs.counter("serve.prefill_chunks").inc()
+            if seq.pos > len(seq.req.prompt):
+                # rows past the prompt replay what decode generated
+                # before a preemption, with the decode step's numerics
+                obs.counter("serve.prefill_replay_chunks").inc()
             obs.TRACE.emit(
                 "PREFILL_CHUNK", rid=seq.rid, slot=seq.slot,
                 arg=(p0, len(segment)),

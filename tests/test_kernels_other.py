@@ -1,12 +1,14 @@
 """Per-kernel allclose vs ref.py: flash attention, grouped GEMM, SSD."""
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 import pytest
 pytest.importorskip("hypothesis")  # property tests degrade to skip
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
+from repro.models import ssm
 
 
 # -- flash attention -------------------------------------------------------
@@ -153,3 +155,54 @@ def test_ssd_state_handoff():
     gt = ref.ref_ssd_recurrent(x, dt, A, B, C)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(gt[:, 32]),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("active", [True, False])
+@pytest.mark.parametrize("h0", ["zero", "random"])
+@pytest.mark.parametrize("seg", ["full", "partial"])
+@pytest.mark.parametrize("C", [1, 8, 32])
+def test_ssd_serve_chunked_vs_serial(C, seg, h0, active):
+    """The serving recurrence (ssm.serve_ssd) against the serial decode
+    step run over the same rows: a prefill chunk (C > 1) takes the
+    chunked form and agrees within float32 rounding; the decode step
+    (C == 1) is the serial step, bit for bit.  A chunk split in two
+    sub-chunks is two calls of ref.ssd_chunk with the state handed on."""
+    rng = np.random.RandomState(C * 7 + (seg == "full") * 3
+                                + (h0 == "zero") + 11 * active)
+    H, P, N = 3, 8, 16
+    x, dt, A, B, Cm = _ssd_inputs(rng, 1, C, H, P, N)
+    B, Cm = B[:, :, 0], Cm[:, :, 0]
+    h = jnp.zeros((1, H, P, N), jnp.float32) if h0 == "zero" else \
+        jnp.asarray(rng.randn(1, H, P, N) * 0.5, jnp.float32)
+    seg_len = C if seg == "full" else C // 2
+    valid = (jnp.arange(C)[None, :] < seg_len) & active
+
+    def step(hc, t):
+        dtt = jnp.where(valid[:, t, None], dt[:, t], 0.0)
+        return ref.ref_ssd_decode_step(hc, x[:, t], dtt, A, B[:, t],
+                                       Cm[:, t])
+    h_ser, y_ser = lax.scan(step, h, jnp.arange(C))
+    y_ser = y_ser.transpose(1, 0, 2, 3)
+    h_got, y_got = ssm.serve_ssd(h, x, dt, A, B, Cm, valid, chunk=16)
+    if C == 1:
+        np.testing.assert_array_equal(np.asarray(h_got), np.asarray(h_ser))
+        np.testing.assert_array_equal(np.asarray(y_got), np.asarray(y_ser))
+        return
+    np.testing.assert_allclose(np.asarray(h_got), np.asarray(h_ser),
+                               rtol=1e-5, atol=1e-5)
+    rows = np.asarray(valid[0])          # masked rows' outputs are unused
+    np.testing.assert_allclose(np.asarray(y_got)[:, rows],
+                               np.asarray(y_ser)[:, rows],
+                               rtol=1e-5, atol=1e-5)
+    # ref_ssd from h0 over two sub-chunks == two ssd_chunk calls
+    dtm = jnp.where(valid[..., None], dt, 0.0)
+    c = C // 2
+    y2, h2 = ref.ref_ssd(x, dtm, A, B[:, :, None], Cm[:, :, None], chunk=c,
+                         return_state=True, h0=h)
+    ha, ya = ref.ssd_chunk(h, x[:, :c], dtm[:, :c], A, B[:, :c], Cm[:, :c])
+    hb, yb = ref.ssd_chunk(ha, x[:, c:], dtm[:, c:], A, B[:, c:], Cm[:, c:])
+    np.testing.assert_allclose(np.asarray(h2), np.asarray(hb),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y2),
+                               np.concatenate([ya, yb], axis=1),
+                               rtol=1e-6, atol=1e-6)

@@ -173,6 +173,7 @@ def test_paged_parity_mixed_lengths_mid_decode_admission(get_model, arch):
     across mixed prompt lengths / budgets, with half the requests
     admitted mid-decode of the others."""
     cfg, model, params = get_model(arch)
+    replays = obs.counter("serve.prefill_replay_chunks").value
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, cfg.vocab, n).astype(np.int32)
                for n in (5, 9, 3, 17, 2)]
@@ -189,6 +190,8 @@ def test_paged_parity_mixed_lengths_mid_decode_admission(get_model, arch):
         e.submit(Request(rid, prompts[rid], max_new=maxnew[rid]))
     assert e.run() == ref
     assert e.cache.blocks_in_use == 0           # every eviction freed
+    # nothing preempted, so no chunk replays decode rows
+    assert obs.counter("serve.prefill_replay_chunks").value == replays
 
 
 @pytest.mark.parametrize("arch", PARITY_ARCHS)
@@ -213,6 +216,8 @@ def test_paged_parity_under_preemption(get_model, arch):
         e.submit(Request(rid, p, max_new=10))
     assert e.run() == ref
     assert obs.counter("serve.preemptions").value > 0
+    # the resumed request's chunks past its prompt take the serial step
+    assert obs.counter("serve.prefill_replay_chunks").value > 0
     assert e.cache.blocks_in_use == 0
 
 

@@ -20,7 +20,7 @@ from repro import configs, obs
 from repro.models import registry
 from repro.models.common import XLA
 from repro.serve import (CacheMap, PagedEngine, Request, Seq, SlotScheduler,
-                         SlotStateStore)
+                         SlotStateStore, paged_step_fns)
 from serve_oracle import serve_alone
 
 KEY = jax.random.PRNGKey(0)
@@ -226,3 +226,46 @@ def test_exhaustion_resume_rebuilds_carry(get_model):
     assert obs.counter("serve.preemptions").value > 0
     assert e.state.binds > 2            # at least one resume re-bound
     assert e.state.bound == 0 and e.cache.blocks_in_use == 0
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "zamba2-7b"])
+def test_replay_chunks_rebuild_carry_bitwise(get_model, arch):
+    """The SSM state a recompute-resume rebuilds is the unpreempted
+    run's, bit for bit: a prompt prefilled in one chunk then decoded
+    token by token leaves the same SSM rows as prefilling prompt and
+    generated tokens together, where the chunk rows past the prompt
+    take the serial decode step and the prompt rows the chunked form.
+    The conv carry is held bitwise after the chunk that holds the
+    prompt's end; after a chunk of replayed rows alone it can differ in
+    the last bit of a bfloat16 input (a GEMM over 8 rows against one),
+    which the token parity tests absorb."""
+    cfg, model, params = get_model(arch)
+    decode, prefill = (jax.jit(f) for f in paged_step_fns(model, XLA))
+    rng = np.random.RandomState(7)
+    C, n_prompt = 8, 7
+    toks = rng.randint(0, cfg.vocab, 2 * C).astype(np.int32)
+    table = jnp.array([[1, 2, 3]], jnp.int32)
+    key = jax.random.PRNGKey(0)
+    i32 = np.int32
+
+    def chunk(ps, p0, seg):
+        row = np.zeros((1, C), np.int32)
+        row[0, :seg] = toks[p0:p0 + seg]
+        _, ps = prefill(params, jnp.asarray(row), ps, table,
+                        jnp.array([p0], jnp.int32), i32(0), i32(seg),
+                        i32(n_prompt), i32(seg - 1))
+        return ps
+
+    # served without preemption: the prompt's chunk, then decode steps
+    run = chunk(model.init_paged_state(4, 8, 1), 0, n_prompt)
+    states = {}
+    for t in range(n_prompt, 2 * C):
+        _, run, key = decode(params, jnp.asarray(toks[t:t + 1]), run, table,
+                             jnp.array([t], jnp.int32),
+                             jnp.ones((1,), bool), key)
+        states[t + 1] = run
+    # resumed: prompt and generated tokens re-prefilled from position 0
+    resumed = chunk(model.init_paged_state(4, 8, 1), 0, C)
+    for p0, ps in ((C, resumed), (2 * C, chunk(resumed, C, C))):
+        assert bool(jnp.all(ps.ssm == states[p0].ssm)), (arch, p0)
+    assert bool(jnp.all(resumed.conv == states[C].conv)), arch
