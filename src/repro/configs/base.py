@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -39,6 +39,9 @@ class SSMConfig:
     chunk: int = 128
     dt_min: float = 0.001
     dt_max: float = 0.1
+    # B and C come in n_groups groups; head h reads group
+    # h // (heads / n_groups), and the gated RMSNorm is taken per group
+    n_groups: int = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,9 +78,15 @@ class ModelConfig:
     attn: AttentionPattern = AttentionPattern()
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid (zamba2-style): one shared attention block reused every
-    # ``shared_attn_every`` layers
-    shared_attn_every: int = 0
+    # hybrid (Zamba2): before the mamba mixer of each layer listed in
+    # ``hybrid_layers`` (ids at or past n_layers are not held), one of
+    # ``n_shared_blocks`` weight-shared [attn + mlp] blocks runs on
+    # concat(x, embedding); application j uses block j % n_shared_blocks
+    # and its own LoRA adapter (rank ``adapter_rank``) and output linear
+    hybrid_layers: Tuple[int, ...] = ()
+    n_shared_blocks: int = 0
+    adapter_rank: int = 0
+    mlp_act: str = "silu"                   # silu | gelu (exact)
     # enc-dec
     n_encoder_layers: int = 0
     # modality frontend stub: inputs arrive as precomputed embeddings
@@ -99,6 +108,22 @@ class ModelConfig:
     # exactly 0 forward AND receive exactly 0 gradient (their q/k/v and
     # wo rows stay 0), so the math is unchanged while attention shards.
     head_pad_multiple: int = 0
+
+    def __post_init__(self):
+        # a configuration file gives the schedule as a JSON list
+        object.__setattr__(self, "hybrid_layers", tuple(self.hybrid_layers))
+
+    @property
+    def hybrid_apps(self) -> Tuple[int, ...]:
+        """The layers, below ``n_layers``, that apply a shared block."""
+        return tuple(i for i in self.hybrid_layers if i < self.n_layers)
+
+    @property
+    def attn_applications(self) -> int:
+        """Paged attention applications in one serving step."""
+        if self.family == "hybrid":
+            return len(self.hybrid_apps)
+        return self.n_layers if self.family in ("dense", "moe", "vlm") else 0
 
     @property
     def head_dim_(self) -> int:
@@ -145,10 +170,9 @@ class ModelConfig:
         n = 0
         emb = self.vocab_padded * d
         n += emb * (1 if self.tie_embeddings else 2)
-        is_hybrid = self.shared_attn_every > 0
 
-        def attn_params():
-            return d * hd * (self.n_heads + 2 * self.n_kv_heads) + \
+        def attn_params(d_in=d):
+            return d_in * hd * (self.n_heads + 2 * self.n_kv_heads) + \
                 self.n_heads * hd * d
 
         def mlp_params(dff):
@@ -167,14 +191,18 @@ class ModelConfig:
             n += per * L
         elif self.family in ("ssm", "hybrid"):
             di, s = self.d_inner, self.ssm
-            nh = self.ssm_heads
-            per = d * (2 * di + 2 * s.d_state + nh)   # in_proj(z,x,B,C,dt)
-            per += s.d_conv * (di + 2 * s.d_state)    # conv
+            nh, gn = self.ssm_heads, s.n_groups * s.d_state
+            per = d * (2 * di + 2 * gn + nh)          # in_proj(z,x,B,C,dt)
+            per += s.d_conv * (di + 2 * gn)           # conv
             per += nh * 2                             # A_log, D
             per += di * d + 2 * d                     # out_proj + norms
             n += per * L
-            if is_hybrid:
-                n += attn_params() + mlp_params(self.d_ff) + 2 * d
+            if self.family == "hybrid":
+                n += self.n_shared_blocks * (
+                    attn_params(2 * d) + mlp_params(self.d_ff) + 3 * d)
+                r = self.adapter_rank
+                n += len(self.hybrid_apps) * (
+                    d * r + 2 * r * self.d_ff + d * d)  # adapter, linear
         return n
 
     def active_param_count(self) -> int:

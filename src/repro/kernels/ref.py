@@ -161,23 +161,23 @@ def ref_ssd_recurrent(x, dt, A, B, C, *, D_skip=None):
     """Ground-truth sequential recurrence (one step per token).
 
     x: (Bt, S, H, P); dt: (Bt, S, H); A: (H,) (negative);
-    B, C: (Bt, S, G, N) with G == 1 broadcast over heads.
+    B, C: (Bt, S, G, N), head h reading group h // (H / G).
     h_t = exp(dt*A) h_{t-1} + dt * B_t x_t ;  y_t = C_t . h_t
     """
     Bt, S, H, P = x.shape
     N = B.shape[-1]
     xf = x.astype(jnp.float32)
     dtf = dt.astype(jnp.float32)
-    Bf = B.astype(jnp.float32)[:, :, 0]   # (Bt, S, N)
-    Cf = C.astype(jnp.float32)[:, :, 0]
+    Bf = jnp.repeat(B.astype(jnp.float32), H // B.shape[2], axis=2)
+    Cf = jnp.repeat(C.astype(jnp.float32), H // C.shape[2], axis=2)
 
     def step(h, t):
         # h: (Bt, H, P, N)
         da = jnp.exp(dtf[:, t] * A[None, :])            # (Bt, H)
         inp = (dtf[:, t, :, None, None] * xf[:, t, :, :, None]
-               * Bf[:, t, None, None, :])               # (Bt,H,P,N)
+               * Bf[:, t, :, None, :])                  # (Bt,H,P,N)
         h = h * da[..., None, None] + inp
-        y = jnp.einsum("bhpn,bn->bhp", h, Cf[:, t])
+        y = jnp.einsum("bhpn,bhn->bhp", h, Cf[:, t])
         return h, y
 
     h0 = jnp.zeros((Bt, H, P, N), jnp.float32)
@@ -194,7 +194,8 @@ def ssd_chunk(h, xc, dtc, A, Bc, Cc, *, precision=None):
     contribution, and the state after the chunk.
 
     h: (Bt,H,P,N) f32; xc: (Bt,c,H,P); dtc: (Bt,c,H); A: (H,) (negative);
-    Bc/Cc: (Bt,c,N).  A row with ``dtc = 0`` neither decays nor feeds the
+    Bc/Cc: (Bt,c,N), shared by every head, or (Bt,c,H,N), one row per
+    head.  A row with ``dtc = 0`` neither decays nor feeds the
     state, and adds nothing to any row's output (``L`` is lower
     triangular and the input weights carry ``dtc``), so masked rows pass
     the state through.  ``precision`` goes to every einsum.  Returns
@@ -206,15 +207,24 @@ def ssd_chunk(h, xc, dtc, A, Bc, Cc, *, precision=None):
     tot = cum[:, -1]                                    # (Bt,H)
     decay = cum[:, :, None, :] - cum[:, None, :, :]     # (Bt,t,s,H)
     L = jnp.where(tri[None, :, :, None], jnp.exp(decay), 0.0)
-    cb = jnp.einsum("btn,bsn->bts", Cc, Bc, precision=precision)
-    scores = cb[..., None] * L * dtc[:, None]           # (Bt,t,s,H)
+    g = "h" if Bc.ndim == 4 else ""                     # per-head B/C
+    cb = jnp.einsum(f"bt{g}n,bs{g}n->bts{g}", Cc, Bc, precision=precision)
+    scores = (cb if g else cb[..., None]) * L * dtc[:, None]  # (Bt,t,s,H)
     y = jnp.einsum("btsh,bshp->bthp", scores, xc, precision=precision)
-    y = y + jnp.einsum("btn,bhpn->bthp", Cc, h, precision=precision) \
+    y = y + jnp.einsum(f"bt{g}n,bhpn->bthp", Cc, h, precision=precision) \
         * jnp.exp(cum)[..., None]
     w = (dtc * jnp.exp(tot[:, None] - cum))[..., None] * xc  # (Bt,c,H,P)
     h = h * jnp.exp(tot)[..., None, None] \
-        + jnp.einsum("bchp,bcn->bhpn", w, Bc, precision=precision)
+        + jnp.einsum(f"bchp,bc{g}n->bhpn", w, Bc, precision=precision)
     return h, y
+
+
+def _heads(m, H: int):
+    """(..., G, N) groups as the heads read them: (..., N) for one group,
+    shared by every head; else (..., H, N), head h reading group
+    h // (H / G)."""
+    G = m.shape[-2]
+    return m[..., 0, :] if G == 1 else jnp.repeat(m, H // G, axis=-2)
 
 
 def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
@@ -225,7 +235,8 @@ def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
 
     Mathematically identical to ``ref_ssd_recurrent``; O(S·c) memory.  This
     is the XLA model path; the Pallas kernel mirrors its block structure
-    (each chunk is a cascade of small GEMMs — IAAT's habitat).  ``h0``
+    (each chunk is a cascade of small GEMMs — IAAT's habitat).  B, C:
+    (Bt,S,G,N) in G groups, head h reading group h // (H / G).  ``h0``
     (Bt,H,P,N) is the state before the first token (zero by default);
     ``precision`` goes to every einsum of the chunks.
     """
@@ -241,8 +252,8 @@ def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
     Sp = nc * chunk
     xf = x.astype(jnp.float32).reshape(Bt, nc, chunk, H, P)
     dtf = dt.astype(jnp.float32).reshape(Bt, nc, chunk, H)
-    Bf = B.astype(jnp.float32).reshape(Bt, nc, chunk, -1, N)[:, :, :, 0]
-    Cf = C.astype(jnp.float32).reshape(Bt, nc, chunk, -1, N)[:, :, :, 0]
+    Bf = _heads(B.astype(jnp.float32).reshape(Bt, nc, chunk, -1, N), H)
+    Cf = _heads(C.astype(jnp.float32).reshape(Bt, nc, chunk, -1, N), H)
 
     # one chunk per scan step: the vectorised form materialises a
     # (Bt, nc, c, c, H) decay tensor — O(S·c·H) memory, terabytes at
@@ -250,7 +261,7 @@ def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
     # (exactly the Pallas kernel's schedule) and the checkpointed step
     # keeps the backward pass from stacking the per-chunk scores.
     def step(h, inputs):
-        xc, dtc, Bc, Cc = inputs      # (Bt,c,H,P) (Bt,c,H) (Bt,c,N) (Bt,c,N)
+        xc, dtc, Bc, Cc = inputs      # (Bt,c,H,P) (Bt,c,H) (Bt,c,[H,]N) x2
         return ssd_chunk(h, xc, dtc, A, Bc, Cc, precision=precision)
 
     if h0 is None:
@@ -260,7 +271,7 @@ def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
     h_last, ys = lax.scan(
         jax.checkpoint(step), h0.astype(jnp.float32),
         (xf.transpose(1, 0, 2, 3, 4), dtf.transpose(1, 0, 2, 3),
-         Bf.transpose(1, 0, 2, 3), Cf.transpose(1, 0, 2, 3)))
+         Bf.swapaxes(0, 1), Cf.swapaxes(0, 1)))
     y = ys.transpose(1, 0, 2, 3, 4).reshape(Bt, Sp, H, P)[:, :S]
     if D_skip is not None:
         y = y + D_skip[None, None, :, None] * x.astype(jnp.float32)[:, :S]
@@ -273,11 +284,14 @@ def ref_ssd(x, dt, A, B, C, *, D_skip=None, chunk: int = 64,
 def ref_ssd_decode_step(h, x_t, dt_t, A, B_t, C_t):
     """One-token SSM recurrence for serving (state in, state out).
 
-    h: (Bt,H,P,N); x_t: (Bt,H,P); dt_t: (Bt,H); B_t/C_t: (Bt,N)."""
+    h: (Bt,H,P,N); x_t: (Bt,H,P); dt_t: (Bt,H); B_t/C_t: (Bt,N), shared
+    by every head, or (Bt,H,N), one row per head."""
+    per_head = B_t.ndim == 3
     da = jnp.exp(dt_t * A[None, :])
-    h = h * da[..., None, None] + (dt_t[..., None, None]
-                                   * x_t[..., None] * B_t[:, None, None, :])
-    y = jnp.einsum("bhpn,bn->bhp", h, C_t)
+    h = h * da[..., None, None] + (
+        dt_t[..., None, None] * x_t[..., None]
+        * (B_t[:, :, None, :] if per_head else B_t[:, None, None, :]))
+    y = jnp.einsum("bhpn,bhn->bhp" if per_head else "bhpn,bn->bhp", h, C_t)
     return h, y
 
 

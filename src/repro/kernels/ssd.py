@@ -73,11 +73,13 @@ def _body(chunk: int, S: int, nc: int,
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, *, chunk: int = 128,
              interpret: Optional[bool] = None) -> jax.Array:
-    """x: (Bt, S, H, P); dt: (Bt, S, H); A: (H,); B, C: (Bt, S, 1, N).
+    """x: (Bt, S, H, P); dt: (Bt, S, H); A: (H,); B, C: (Bt, S, G, N), head
+    h reading group h // (H / G).
 
     Returns y: (Bt, S, H, P).  D-skip is applied by the caller."""
     Bt, S, H, P = x.shape
     N = B.shape[-1]
+    hpg = H // B.shape[2]                          # heads per B/C group
     nc = -(S // -chunk)
     Sp = nc * chunk
     if Sp != S:
@@ -86,7 +88,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         B = jnp.pad(B, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
     dA = dt.astype(jnp.float32) * A.astype(jnp.float32)[None, None, :]
-    # broadcast B/C across heads via index maps (G=1 in all assigned archs)
+    # each head reads its group's B/C through the index maps
     body = functools.partial(_body, chunk, S, nc)
     out = pl.pallas_call(
         body,
@@ -95,8 +97,10 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
             pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
             pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, chunk, 1, N),
+                         lambda b, h, c: (b, c, h // hpg, 0)),
+            pl.BlockSpec((1, chunk, 1, N),
+                         lambda b, h, c: (b, c, h // hpg, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((Bt, Sp, H, P), x.dtype),

@@ -36,10 +36,17 @@ def mm(x: jax.Array, w: jax.Array,
     return api.matmul(x, w.astype(x.dtype), policy=be)
 
 
-def rmsnorm(x: jax.Array, w: Optional[jax.Array], eps: float) -> jax.Array:
+def rmsnorm(x: jax.Array, w: Optional[jax.Array], eps: float,
+            groups: int = 1) -> jax.Array:
+    """RMSNorm over the last axis, or over each of its ``groups`` equal
+    parts."""
     xf = x.astype(jnp.float32)
+    if groups > 1:
+        xf = xf.reshape(x.shape[:-1] + (groups, -1))
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf * lax.rsqrt(var + eps)
+    if groups > 1:
+        y = y.reshape(x.shape)
     if w is not None:
         y = y * w.astype(jnp.float32)
     return y.astype(x.dtype)

@@ -33,17 +33,21 @@ def _zero_pad_cols(w, cols: int):
         if cols > w.shape[1] else w
 
 
-def init_attention(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
+def init_attention(key, cfg: ModelConfig, dtype=jnp.float32,
+                   d_in: Optional[int] = None) -> Dict:
+    """q/k/v read ``d_in`` features (``d_model`` unless given: Zamba2's
+    shared block reads concat(x, embedding)); o writes ``d_model``."""
     d, H, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    d_in = d_in or d
     Hp, Hkvp = cfg.n_heads_padded, cfg.n_kv_heads_padded
     ks = jax.random.split(key, 4)
-    s = 1.0 / math.sqrt(d)
+    s = 1.0 / math.sqrt(d_in)
     so = 1.0 / math.sqrt(H * hd) / math.sqrt(2.0 * cfg.n_layers)
     # dead (padding) heads are ZERO so they contribute nothing and their
     # gradients are identically zero (see ModelConfig.head_pad_multiple)
-    wq = _zero_pad_cols(ninit(ks[0], (d, H * hd), s, dtype), Hp * hd)
-    wk = _zero_pad_cols(ninit(ks[1], (d, Hkv * hd), s, dtype), Hkvp * hd)
-    wv = _zero_pad_cols(ninit(ks[2], (d, Hkv * hd), s, dtype), Hkvp * hd)
+    wq = _zero_pad_cols(ninit(ks[0], (d_in, H * hd), s, dtype), Hp * hd)
+    wk = _zero_pad_cols(ninit(ks[1], (d_in, Hkv * hd), s, dtype), Hkvp * hd)
+    wv = _zero_pad_cols(ninit(ks[2], (d_in, Hkv * hd), s, dtype), Hkvp * hd)
     wo = _zero_pad_cols(ninit(ks[3], (H * hd, d), so, dtype).T,
                         Hp * hd).T
     return {"wq": wq, "wk": wk, "wv": wv, "wo": wo}
@@ -82,11 +86,15 @@ def _full_attn(q, k, v, be: Policy, *, causal, window, q_offset, scale):
 
 def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
                  scale: float, window: Optional[int] = None,
-                 decode_from=None):
+                 decode_from=None, token_major: bool = False):
     """Attention over a paged KV pool, read through a block table.
 
     q: (B, H, C, hd); k_pool/v_pool: (P, Hkv, BS, hd) — one layer's
-    block pool; block_table: (B, nmax) int32 pool ids in *logical*
+    block pool — or, ``token_major``, (P, BS, Hkv * hd), each token's
+    heads side by side in one row (a TPU keeps that layout where it lies
+    for heads of any width, where it relays a (P, Hkv, BS, 224) pool out
+    whole around the step's layer loop); block_table: (B, nmax) int32
+    pool ids in *logical*
     order (padded with the null block 0); q_pos: (B, C) absolute query
     positions.  Because the table lists blocks logically, flattened key
     index j of the gathered (B, Hkv, nmax*BS, hd) buffer holds sequence
@@ -106,14 +114,23 @@ def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
     softmax rounding, the recurrent carries inherit it, and the
     continuation after preemption drifts from the unpreempted one."""
     B, H, C, hd = q.shape
-    Hkv, BS = k_pool.shape[1], k_pool.shape[2]
     nmax = block_table.shape[1]
+    if token_major:
+        BS, Hkv = k_pool.shape[1], k_pool.shape[2] // hd
+        # (B, nmax, BS, Hkv * hd) -> (B, Hkv, S, hd)
+        kg = k_pool[block_table].reshape(B, nmax * BS, Hkv, hd) \
+            .transpose(0, 2, 1, 3)
+        vg = v_pool[block_table].reshape(B, nmax * BS, Hkv, hd) \
+            .transpose(0, 2, 1, 3)
+    else:
+        Hkv, BS = k_pool.shape[1], k_pool.shape[2]
+        # gather the request's blocks: (B, nmax, Hkv, BS, hd) -> (B, Hkv,
+        # S, hd)
+        kg = k_pool[block_table].transpose(0, 2, 1, 3, 4) \
+            .reshape(B, Hkv, nmax * BS, hd)
+        vg = v_pool[block_table].transpose(0, 2, 1, 3, 4) \
+            .reshape(B, Hkv, nmax * BS, hd)
     rep = H // Hkv
-    # gather the request's blocks: (B, nmax, Hkv, BS, hd) -> (B, Hkv, S, hd)
-    kg = k_pool[block_table].transpose(0, 2, 1, 3, 4) \
-        .reshape(B, Hkv, nmax * BS, hd)
-    vg = v_pool[block_table].transpose(0, 2, 1, 3, 4) \
-        .reshape(B, Hkv, nmax * BS, hd)
     key_pos = jnp.arange(nmax * BS)
     ok = key_pos[None, None, :] <= q_pos[:, :, None]          # (B, C, S)
     if window is not None:
@@ -161,8 +178,11 @@ def paged_attend(q, k_pool, v_pool, block_table, q_pos, *,
 def attention(p: Dict, x, be: Policy, cfg: ModelConfig, *,
               causal: bool = True, window: Optional[int] = None,
               positions=None, cross_kv: Optional[Tuple] = None,
-              paged_kv: Optional[Tuple] = None):
-    """Unified attention layer.
+              paged_kv: Optional[Tuple] = None,
+              scale: Optional[float] = None, token_major: bool = False):
+    """Unified attention layer; ``scale`` multiplies the scores
+    (``head_dim ** -0.5`` unless given); ``token_major``: the paged pools
+    are (P, BS, Hkv * hd) (see :func:`paged_attend`).
 
     Modes:
       sequence: positions (S,) or (B,S); the whole sequence at once
@@ -174,7 +194,7 @@ def attention(p: Dict, x, be: Policy, cfg: ModelConfig, *,
       cross:    cross_kv (k, v) precomputed from encoder states.
     Returns y, and in paged mode (y, (k_pool, v_pool))."""
     H, Hkv, hd = cfg.n_heads_padded, cfg.n_kv_heads_padded, cfg.head_dim_
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     B, S, _ = x.shape
     q = _split_heads(mm(x, p["wq"], be), H, hd)
     if cross_kv is not None:
@@ -195,19 +215,27 @@ def attention(p: Dict, x, be: Policy, cfg: ModelConfig, *,
         else:
             k_pool, v_pool, bt, qpos = paged_kv
             decode_from = None
-        BS = k_pool.shape[2]
+        BS = k_pool.shape[1 if token_major else 2]
         q = rope(q, qpos, cfg.rope_theta)
         k = rope(k, qpos, cfg.rope_theta)
         blk = jnp.take_along_axis(bt, (qpos // BS).astype(jnp.int32),
                                   axis=1)                     # (B, C)
         off = jnp.mod(qpos, BS).astype(jnp.int32)
-        # advanced indices at dims 0 and 2 -> update shape (B, C, Hkv, hd)
-        k_pool = k_pool.at[blk, :, off, :].set(
-            k.transpose(0, 2, 1, 3).astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off, :].set(
-            v.transpose(0, 2, 1, 3).astype(v_pool.dtype))
+        # advanced indices at dims 0 and 2 -> update shape (B, C, Hkv,
+        # hd); token-major: dims 0 and 1, update (B, C, Hkv * hd)
+        if token_major:
+            k_pool = k_pool.at[blk, off].set(
+                _merge_heads(k).astype(k_pool.dtype))
+            v_pool = v_pool.at[blk, off].set(
+                _merge_heads(v).astype(v_pool.dtype))
+        else:
+            k_pool = k_pool.at[blk, :, off, :].set(
+                k.transpose(0, 2, 1, 3).astype(k_pool.dtype))
+            v_pool = v_pool.at[blk, :, off, :].set(
+                v.transpose(0, 2, 1, 3).astype(v_pool.dtype))
         y = paged_attend(q, k_pool, v_pool, bt, qpos, window=window,
-                         scale=scale, decode_from=decode_from)
+                         scale=scale, decode_from=decode_from,
+                         token_major=token_major)
         return mm(_merge_heads(y), p["wo"], be), (k_pool, v_pool)
     if positions is None:
         positions = jnp.arange(S)
@@ -219,8 +247,20 @@ def attention(p: Dict, x, be: Policy, cfg: ModelConfig, *,
 
 
 # --------------------------------------------------------------------------
-# Gated MLP (SwiGLU).
+# Gated MLP (SwiGLU; Zamba2's gated GELU, with a LoRA adapter).
 # --------------------------------------------------------------------------
+
+def _gelu(x):
+    """Exact (erf) GELU, as Hugging Face's ``"gelu"``, evaluated in
+    float32 and rounded once.  Left in bfloat16, the result depended on
+    what XLA fused the erf with: on the CPU a decode step and a prefill
+    chunk gave one token's activation a different last bit."""
+    return jax.nn.gelu(x.astype(jnp.float32), approximate=False) \
+        .astype(x.dtype)
+
+
+#: The gate's activation by ``ModelConfig.mlp_act``.
+ACTS = {"silu": jax.nn.silu, "gelu": _gelu}
 
 def init_mlp(key, cfg: ModelConfig, d_ff: Optional[int] = None,
              dtype=jnp.float32) -> Dict:
@@ -239,9 +279,32 @@ def mlp_specs(cfg: ModelConfig) -> Dict:
             "wd": ("mlp", "embed")}
 
 
-def mlp(p: Dict, x, be: Policy):
-    h = jax.nn.silu(mm(x, p["wg"], be)) * mm(x, p["wu"], be)
-    h = constrain(h, "batch", None, "mlp")
+def init_adapter(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
+    """LoRA on the gate/up projection: [g, u] += (x A) [B_g | B_u]."""
+    d, r, ff = cfg.d_model, cfg.adapter_rank, cfg.d_ff
+    ks = jax.random.split(key, 3)
+    return {"lora_a": ninit(ks[0], (d, r), 1.0 / math.sqrt(d), dtype),
+            "lora_bg": ninit(ks[1], (r, ff), 1.0 / math.sqrt(r), dtype),
+            "lora_bu": ninit(ks[2], (r, ff), 1.0 / math.sqrt(r), dtype)}
+
+
+def adapter_specs(cfg: ModelConfig) -> Dict:
+    return {"lora_a": ("embed", None), "lora_bg": (None, "mlp"),
+            "lora_bu": (None, "mlp")}
+
+
+def mlp(p: Dict, x, be: Policy, act=jax.nn.silu,
+        adapter: Optional[Dict] = None):
+    """down(act(g) * u), [g, u] = x [W_g | W_u] (+ the adapter's term)."""
+    a = None if adapter is None else mm(x, adapter["lora_a"], be)
+    g = mm(x, p["wg"], be)
+    if a is not None:
+        g = g + mm(a, adapter["lora_bg"], be)
+    g = act(g)
+    u = mm(x, p["wu"], be)
+    if a is not None:
+        u = u + mm(a, adapter["lora_bu"], be)
+    h = constrain(g * u, "batch", None, "mlp")
     return mm(h, p["wd"], be)
 
 

@@ -12,16 +12,24 @@ Family wiring:
                 compiled once).
   moe           [attn + moe] blocks, aux loss accumulated in the carry.
   ssm           [mamba] blocks (attention-free).
-  hybrid        [mamba] blocks + ONE shared [attn + mlp] block (zamba2
-                style) applied every ``shared_attn_every`` layers; its
-                params are closed over (true weight sharing), its KV pool
-                is indexed per application.
+  hybrid        Zamba2: [mamba] blocks, and before the mixer of each
+                layer in ``hybrid_layers`` one of ``n_shared_blocks``
+                weight-shared [attn + mlp] blocks (application j uses
+                block j % n_shared_blocks, chosen by ``lax.switch``, its
+                weights closed over).  The block reads
+                u = rmsnorm(concat(x, e)), e the embedding output; it has
+                no residual of its own: t = linear_j(mlp_j(rmsnorm(attn(u))))
+                with application j's LoRA adapter on the MLP's gate/up,
+                and the layer is x + mamba(rmsnorm(x + t)).  Adapters and
+                linears are stacked per application, and so is the KV
+                pool.
 VLM (internvl2) enters ``forward_train`` through ``prefix_embeds`` (the
 stubbed ViT frontend); audio enc-dec lives in encdec.py.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -84,15 +92,33 @@ def init_lm(key, cfg: ModelConfig) -> Dict:
     }
     if not cfg.tie_embeddings:
         params["unembed"] = ninit(ks[2], (d, Vp), 1.0 / math.sqrt(d), dtype)
-    if cfg.shared_attn_every:
-        kk = jax.random.split(ks[3], 2)
-        params["shared"] = {
-            "ln1": _norm_w(cfg, dtype),
-            "attn": L.init_attention(kk[0], cfg, dtype),
-            "ln2": _norm_w(cfg, dtype),
-            "mlp": L.init_mlp(kk[1], cfg, dtype=dtype),
-        }
+    if cfg.family == "hybrid":
+        params.update(_init_shared(ks[3], cfg, dtype))
     return params
+
+
+def _init_shared(key, cfg: ModelConfig, dtype) -> Dict:
+    """Zamba2's shared blocks (a list: each is closed over whole by its
+    branch, never sliced out of a stack) and the per-application
+    adapters and linears, stacked over the applications."""
+    d = cfg.d_model
+    kb, ka = jax.random.split(key)
+
+    def block(k):
+        k1, k2 = jax.random.split(k)
+        return {"ln1": jnp.ones((2 * d,), dtype),
+                "attn": L.init_attention(k1, cfg, dtype, d_in=2 * d),
+                "ln2": _norm_w(cfg, dtype),
+                "mlp": L.init_mlp(k2, cfg, dtype=dtype)}
+
+    def app(k):
+        k1, k2 = jax.random.split(k)
+        return dict(L.init_adapter(k1, cfg, dtype),
+                    linear=ninit(k2, (d, d), 1.0 / math.sqrt(d), dtype))
+
+    return {"shared": [block(k) for k in
+                       jax.random.split(kb, cfg.n_shared_blocks)],
+            "apps": stack_init(app, ka, len(cfg.hybrid_apps))}
 
 
 def lm_specs(cfg: ModelConfig) -> Dict:
@@ -108,9 +134,12 @@ def lm_specs(cfg: ModelConfig) -> Dict:
     }
     if not cfg.tie_embeddings:
         specs["unembed"] = (None, "vocab")
-    if cfg.shared_attn_every:
-        specs["shared"] = {"ln1": n, "attn": L.attention_specs(cfg),
-                           "ln2": n, "mlp": L.mlp_specs(cfg)}
+    if cfg.family == "hybrid":
+        specs["shared"] = [{"ln1": ("embed",), "attn": L.attention_specs(cfg),
+                            "ln2": n, "mlp": L.mlp_specs(cfg)}
+                           for _ in range(cfg.n_shared_blocks)]
+        specs["apps"] = stack_specs(dict(L.adapter_specs(cfg),
+                                         linear=("embed", None)))
     return specs
 
 
@@ -188,22 +217,64 @@ def _apply_attn_block(p, x, be, cfg, i, *, positions=None, paged_kv=None):
     return x + y, aux, kv_out
 
 
-def _apply_mamba_block(p, x, be, cfg):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+def _shared_block(params, cfg: ModelConfig, be, b: int, j, x, e, *,
+                  positions=None, paged_kv=None):
+    """Application ``j`` (traced) of shared block ``b`` (static) on the
+    residual stream x and the embedding output e: returns t, what the
+    layer adds to its mamba mixer's input, and in paged mode the new
+    (k_pool, v_pool).  The scores are scaled by (head_dim / 2) ** -0.5,
+    as Zamba2's modelling code scales them."""
+    p = params["shared"][b]
+    u = rmsnorm(jnp.concatenate([x, e], axis=-1), p["ln1"], cfg.norm_eps)
+    out = L.attention(p["attn"], u, be, cfg, positions=positions,
+                      paged_kv=paged_kv, scale=(cfg.head_dim_ / 2) ** -0.5,
+                      token_major=True)
+    a, kv = out if paged_kv is not None else (out, None)
+    ap = jax.tree.map(
+        lambda w: lax.dynamic_index_in_dim(w, j, 0, keepdims=False),
+        params["apps"])
+    y = L.mlp(p["mlp"], rmsnorm(a, p["ln2"], cfg.norm_eps), be,
+              L.ACTS[cfg.mlp_act], adapter=ap)
+    return mm(y, ap["linear"], be), kv
+
+
+def _mixer_input(params, cfg: ModelConfig, be, i, x, e, pools=None, *,
+                 positions=None, paged=None):
+    """Hybrid: the input of layer i's mamba mixer, x + t at a hybrid
+    layer and x elsewhere (``lax.switch`` over "none" and one branch per
+    shared block), and the KV pools.  ``pools`` (k, v), each (napps, P,
+    BS, Hkv * hd), are read and written as one pool of napps * P blocks,
+    application j's block table shifted by j * P.  ``paged`` is
+    (block_tables, qpos, decode_from) in paged mode."""
+    kind, app = [0] * cfg.n_layers, [0] * cfg.n_layers
+    for j, li in enumerate(cfg.hybrid_apps):
+        kind[li], app[li] = 1 + j % cfg.n_shared_blocks, j
+    kind = jnp.asarray(kind, jnp.int32)[i]
+    j = jnp.asarray(app, jnp.int32)[i]
+
+    def apply(b, x, pools):
+        kv = None
+        if paged is not None:
+            bt, qpos, decode_from = paged
+            npool = pools[0].shape[1]
+            flat = [pl.reshape((-1,) + pl.shape[2:]) for pl in pools]
+            kv = (flat[0], flat[1], bt + j * npool, qpos, decode_from)
+        t, kv = _shared_block(params, cfg, be, b, j, x, e,
+                              positions=positions, paged_kv=kv)
+        if kv is not None:
+            pools = tuple(k.reshape(pl.shape) for k, pl in zip(kv, pools))
+        return x + t, pools
+
+    branches = [lambda x, pools: (x, pools)] + [
+        functools.partial(apply, b) for b in range(cfg.n_shared_blocks)]
+    return lax.switch(kind, branches, x, pools)
+
+
+def _apply_mamba_block(p, x, be, cfg, xin=None):
+    """x + mamba(rmsnorm(xin)), ``xin`` the mixer's input (x unless a
+    shared block added to it)."""
+    h = rmsnorm(x if xin is None else xin, p["ln1"], cfg.norm_eps)
     return x + S.mamba(p["mixer"], h, be, cfg)
-
-
-def _maybe_shared(params, x, be, cfg, i, *, positions=None):
-    """Hybrid: apply the shared attn block when i % every == 0."""
-    if not cfg.shared_attn_every:
-        return x
-
-    def apply(x):
-        return _apply_attn_block(params["shared"], x, be, cfg, i,
-                                 positions=positions)[0]
-
-    return lax.cond(i % cfg.shared_attn_every == 0,
-                    apply, lambda x: x, x)
 
 
 # --------------------------------------------------------------------------
@@ -244,11 +315,18 @@ def forward_train(params: Dict, cfg: ModelConfig, be: Policy,
     idxs = jnp.arange(cfg.n_layers)
 
     if cfg.family in ("ssm", "hybrid"):
+        e = x
+
         def body(carry, xs):
             x = carry
             blk, i = xs
-            x = _maybe_shared(params, x, be, cfg, i, positions=positions)
-            x = _apply_mamba_block(blk, x, be, cfg)
+            xin = None
+            if cfg.hybrid_apps:
+                with jax.named_scope("shared_attn"):
+                    xin, _ = _mixer_input(params, cfg, be, i, x, e,
+                                          positions=positions)
+            with jax.named_scope("mamba"):
+                x = _apply_mamba_block(blk, x, be, cfg, xin)
             return x, None
         x, _ = lax.scan(_remat(body, cfg), x, (params["blocks"], idxs))
         aux = jnp.zeros((), jnp.float32)
@@ -280,16 +358,17 @@ class PagedState:
     Attention K/V live in block pools indexed through block tables
     (token-proportional, block-granular, see repro.serve.paged);
     recurrent carries live in per-SLOT rows — fixed-size, allocated for
-    the slot's lifetime, never per token.  Hybrid models add dedicated
-    pools for the weight-shared attention block, one pool row per
-    application.  Which request owns which slot row is host-side state
+    the slot's lifetime, never per token.  Hybrid models hold both: the
+    mamba rows, and a block pool per application of a weight-shared
+    attention block, read through the same block tables.  Which request
+    owns which slot row is host-side state
     (:class:`repro.serve.paged.SlotStateStore`)."""
     attn_k: Optional[jax.Array] = None    # (L, P, Hkv, BS, hd)
     attn_v: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None      # (L, slots, K-1, ch)
     ssm: Optional[jax.Array] = None       # (L, slots, nh, Phd, N) f32
-    shared_k: Optional[jax.Array] = None  # (napps, P, Hkv, BS, hd)
-    shared_v: Optional[jax.Array] = None
+    shared_k: Optional[jax.Array] = None  # (napps, P, BS, Hkv * hd)
+    shared_v: Optional[jax.Array] = None   # one pool per application
 
     def tree_flatten(self):
         return ((self.attn_k, self.attn_v, self.conv, self.ssm,
@@ -298,11 +377,6 @@ class PagedState:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
-
-
-def _n_shared_apps(cfg: ModelConfig) -> int:
-    return -(cfg.n_layers // -cfg.shared_attn_every) \
-        if cfg.shared_attn_every else 0
 
 
 def init_paged_state(cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -322,10 +396,11 @@ def init_paged_state(cfg: ModelConfig, num_blocks: int, block_size: int,
         conv1, h1 = S.init_paged_state(cfg, slots, dtype)
         kw["conv"] = jnp.zeros((cfg.n_layers,) + conv1.shape, conv1.dtype)
         kw["ssm"] = jnp.zeros((cfg.n_layers,) + h1.shape, h1.dtype)
-    if cfg.shared_attn_every:
-        na = _n_shared_apps(cfg)
-        kw["shared_k"] = jnp.zeros((na,) + pool, dtype)
-        kw["shared_v"] = jnp.zeros((na,) + pool, dtype)
+    if cfg.family == "hybrid" and cfg.hybrid_apps:
+        # token-major (see layers.paged_attend)
+        tpool = (len(cfg.hybrid_apps), num_blocks, block_size, Hkv * hd)
+        kw["shared_k"] = jnp.zeros(tpool, dtype)
+        kw["shared_v"] = jnp.zeros(tpool, dtype)
     return PagedState(**kw)
 
 
@@ -349,36 +424,34 @@ def _paged_core(params, cfg: ModelConfig, be: Policy, x, ps: PagedState,
         # the rows from there on with the decode step (ssm.serve_ssd)
         replay_from = None if decode_from is None \
             else decode_from - qpos[:, 0]
+        e = x
 
         def body(carry, xs):
-            x, conv, ssm_h, sk, sv = carry
+            x, conv, ssm_h, pools = carry
             blk, i = xs
-            if cfg.shared_attn_every:
-                app = i // cfg.shared_attn_every
-
-                def apply(x, sk, sv):
-                    y, _, kv = _apply_attn_block(
-                        params["shared"], x, be, cfg, i,
-                        paged_kv=(sk[app], sv[app], block_tables, qpos,
-                                  decode_from))
-                    return y, sk.at[app].set(kv[0]), sv.at[app].set(kv[1])
-
-                x, sk, sv = lax.cond(i % cfg.shared_attn_every == 0,
-                                     apply, lambda x, sk, sv: (x, sk, sv),
-                                     x, sk, sv)
-            h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
-            row = (lax.dynamic_index_in_dim(conv, i, 0, keepdims=False),
-                   lax.dynamic_index_in_dim(ssm_h, i, 0, keepdims=False))
-            y, (cv, hh) = S.paged_step(blk["mixer"], h, be, cfg, row,
-                                       seg_len=seg_len, active=active,
-                                       replay_from=replay_from)
-            conv = lax.dynamic_update_index_in_dim(conv, cv, i, 0)
-            ssm_h = lax.dynamic_update_index_in_dim(ssm_h, hh, i, 0)
-            return (x + y, conv, ssm_h, sk, sv), None
-        (x, conv_new, ssm_new, sk, sv), _ = lax.scan(
-            body, (x, conv, ssm_h, ps.shared_k, ps.shared_v),
-            (params["blocks"], idxs))
-        ps = dataclasses.replace(ps, shared_k=sk, shared_v=sv)
+            xin = x
+            if cfg.hybrid_apps:
+                with jax.named_scope("shared_attn"):
+                    xin, pools = _mixer_input(
+                        params, cfg, be, i, x, e, pools,
+                        paged=(block_tables, qpos, decode_from))
+            with jax.named_scope("mamba"):
+                h = rmsnorm(xin, blk["ln1"], cfg.norm_eps)
+                row = (lax.dynamic_index_in_dim(conv, i, 0, keepdims=False),
+                       lax.dynamic_index_in_dim(ssm_h, i, 0,
+                                                keepdims=False))
+                y, (cv, hh) = S.paged_step(blk["mixer"], h, be, cfg, row,
+                                           seg_len=seg_len, active=active,
+                                           replay_from=replay_from)
+                conv = lax.dynamic_update_index_in_dim(conv, cv, i, 0)
+                ssm_h = lax.dynamic_update_index_in_dim(ssm_h, hh, i, 0)
+            return (x + y, conv, ssm_h, pools), None
+        pools = None if ps.shared_k is None else (ps.shared_k, ps.shared_v)
+        (x, conv_new, ssm_new, pools), _ = lax.scan(
+            body, (x, conv, ssm_h, pools), (params["blocks"], idxs))
+        if pools is not None:
+            ps = dataclasses.replace(ps, shared_k=pools[0],
+                                     shared_v=pools[1])
     else:
         def body(carry, xs):
             x = carry

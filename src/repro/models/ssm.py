@@ -32,16 +32,16 @@ from repro.parallel.ctx import constrain
 
 def init_mamba(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
     s = cfg.ssm
-    d, di, N = cfg.d_model, cfg.d_inner, s.d_state
+    d, di, GN = cfg.d_model, cfg.d_inner, s.n_groups * s.d_state
     nh = cfg.ssm_heads
-    ch = di + 2 * N                       # conv channels: x, B, C streams
+    ch = di + 2 * GN                      # conv channels: x, B, C streams
     ks = jax.random.split(key, 6)
     sc = 1.0 / math.sqrt(d)
     dt = jnp.exp(jax.random.uniform(ks[4], (nh,), jnp.float32)
                  * (math.log(s.dt_max) - math.log(s.dt_min))
                  + math.log(s.dt_min))
     return {
-        "in_proj": ninit(ks[0], (d, 2 * di + 2 * N + nh), sc, dtype),
+        "in_proj": ninit(ks[0], (d, 2 * di + 2 * GN + nh), sc, dtype),
         "conv_w": ninit(ks[1], (s.d_conv, ch), 0.2, dtype),
         "conv_b": jnp.zeros((ch,), dtype),
         "A_log": jnp.log(jnp.abs(
@@ -102,11 +102,38 @@ def _conv_chunk(conv_state, x, w, b):
 
 def _project(p, x, cfg: ModelConfig, be: Policy):
     s = cfg.ssm
-    di, N, nh = cfg.d_inner, s.d_state, cfg.ssm_heads
+    di, N = cfg.d_inner, s.n_groups * s.d_state
     proj = mm(x, p["in_proj"], be)
     z, xs, Bm, Cm, dt = jnp.split(
         proj, [di, 2 * di, 2 * di + N, 2 * di + 2 * N], axis=-1)
     return z, xs, Bm, Cm, dt
+
+
+def _per_head(m, cfg: ModelConfig):
+    """B or C, (..., n_groups * N), as the heads read it: (..., N), shared
+    by every head, for one group; else (..., nh, N), head h reading group
+    h // (nh / n_groups)."""
+    G = cfg.ssm.n_groups
+    if G == 1:
+        return m
+    m = m.reshape(m.shape[:-1] + (G, cfg.ssm.d_state))
+    return jnp.repeat(m, cfg.ssm_heads // G, axis=-2)
+
+
+def _gated_norm(y, z, w, cfg: ModelConfig):
+    """rmsnorm(y * silu(z)) * w, the norm taken over each group's
+    ``d_inner / n_groups`` channels.  In groups the gated product stays
+    in float32, as Zamba2's modelling code keeps it: rounded to bfloat16
+    before the grouping reshape, whether the rounding survived depended
+    on what XLA fused it with, so on the CPU a decode step and a
+    prefill chunk differed in its last bit."""
+    G = cfg.ssm.n_groups
+    if G == 1:
+        return rmsnorm(
+            y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), w,
+            cfg.norm_eps)
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rmsnorm(g, w, cfg.norm_eps, groups=G).astype(y.dtype)
 
 
 def mamba(p: Dict, x, be: Policy, cfg: ModelConfig):
@@ -114,7 +141,8 @@ def mamba(p: Dict, x, be: Policy, cfg: ModelConfig):
     y (B, S, d).  Serving runs :func:`paged_step`."""
     s = cfg.ssm
     B, S, d = x.shape
-    di, N, nh, P = cfg.d_inner, s.d_state, cfg.ssm_heads, s.head_dim
+    di, nh, P = cfg.d_inner, cfg.ssm_heads, s.head_dim
+    G, N = s.n_groups, s.n_groups * s.d_state
     z, xs, Bm, Cm, dt = _project(p, x, cfg, be)
     conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
     A = -jnp.exp(p["A_log"])
@@ -123,8 +151,8 @@ def mamba(p: Dict, x, be: Policy, cfg: ModelConfig):
     conv_out = constrain(conv_out, "batch", None, "inner")
     xs_c = constrain(conv_out[..., :di].reshape(B, S, nh, P),
                      "batch", None, "ssm_heads", None)
-    B_c = conv_out[..., di:di + N].reshape(B, S, 1, N)
-    C_c = conv_out[..., di + N:].reshape(B, S, 1, N)
+    B_c = conv_out[..., di:di + N].reshape(B, S, G, s.d_state)
+    C_c = conv_out[..., di + N:].reshape(B, S, G, s.d_state)
     dt_c = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     dt_c = constrain(dt_c, "batch", None, "ssm_heads")
     if be.pallas:
@@ -137,9 +165,7 @@ def mamba(p: Dict, x, be: Policy, cfg: ModelConfig):
         y = ref.ref_ssd(xs_c, dt_c, A, B_c, C_c, D_skip=p["D"],
                         chunk=s.chunk).astype(jnp.float32)
     y = y.reshape(B, S, di).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype),
-                p["norm_w"], cfg.norm_eps)
-    return mm(y, p["out_proj"], be)
+    return mm(_gated_norm(y, z, p["norm_w"], cfg), p["out_proj"], be)
 
 
 # --------------------------------------------------------------------------
@@ -152,7 +178,7 @@ def init_paged_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
     (batch, nh, P, N) in f32).  Fixed-size per row — slot-lifetime, not
     token-proportional."""
     s = cfg.ssm
-    ch = cfg.d_inner + 2 * s.d_state
+    ch = cfg.d_inner + 2 * s.n_groups * s.d_state
     conv = jnp.zeros((batch, s.d_conv - 1, ch), dtype)
     h = jnp.zeros((batch, cfg.ssm_heads, s.head_dim, s.d_state),
                   jnp.float32)
@@ -163,7 +189,8 @@ def serve_ssd(h, xs, dt, A, Bm, Cm, valid, replay_from=None, *,
               chunk: int):
     """The SSM recurrence of one layer over C rows from the state ``h`` —
     the serving numerics.  h: (B, nh, P, N) f32; xs: (B, C, nh, P) and
-    Bm/Cm: (B, C, N) in the compute dtype; dt: (B, C, nh) f32; valid
+    Bm/Cm in the compute dtype: (B, C, N) shared by every head, or
+    (B, C, nh, N) per head (:func:`_per_head`); dt: (B, C, nh) f32; valid
     (B, C): the rows that advance the state (the others get ``dt = 0``,
     so ``exp(dt*A) = 1`` and their input term vanishes).
 
@@ -184,9 +211,11 @@ def serve_ssd(h, xs, dt, A, Bm, Cm, valid, replay_from=None, *,
             replay_from = jnp.full((B,), C, jnp.int32)
         chunked = valid & (rows < replay_from[:, None])
         serial_rows = valid & ~chunked
+        shared = Bm.ndim == 3
         y, h = ref.ref_ssd(
             xs.astype(jnp.float32), jnp.where(chunked[..., None], dt, 0.0),
-            A, Bm[:, :, None], Cm[:, :, None], chunk=min(C, chunk),
+            A, Bm[:, :, None] if shared else Bm,
+            Cm[:, :, None] if shared else Cm, chunk=min(C, chunk),
             return_state=True, h0=h, precision=lax.Precision.HIGHEST)
     else:
         serial_rows = valid
@@ -200,8 +229,8 @@ def serve_ssd(h, xs, dt, A, Bm, Cm, valid, replay_from=None, *,
         h, ys = lax.scan(step, h, (
             xs.transpose(1, 0, 2, 3).astype(jnp.float32),
             dt_m.transpose(1, 0, 2),
-            Bm.transpose(1, 0, 2).astype(jnp.float32),
-            Cm.transpose(1, 0, 2).astype(jnp.float32)))
+            Bm.swapaxes(0, 1).astype(jnp.float32),
+            Cm.swapaxes(0, 1).astype(jnp.float32)))
         return h, ys.transpose(1, 0, 2, 3)                # (B, C, nh, P)
 
     if C == 1:
@@ -241,7 +270,8 @@ def paged_step(p: Dict, x, be: Policy, cfg: ModelConfig, state: Tuple,
     (conv_state', h'))."""
     s = cfg.ssm
     B, C, _ = x.shape
-    di, N, nh, P = cfg.d_inner, s.d_state, cfg.ssm_heads, s.head_dim
+    di, nh, P = cfg.d_inner, cfg.ssm_heads, s.head_dim
+    N = s.n_groups * s.d_state
     conv_state, h = state
     if seg_len is None:
         seg_len = jnp.full((B,), C, jnp.int32)
@@ -253,8 +283,8 @@ def paged_step(p: Dict, x, be: Policy, cfg: ModelConfig, state: Tuple,
     conv_out = jax.nn.silu(_conv_chunk(conv_state, conv_in,
                                        p["conv_w"], p["conv_b"]))
     xs_c = conv_out[..., :di].reshape(B, C, nh, P)
-    B_c = conv_out[..., di:di + N]                            # (B, C, N)
-    C_c = conv_out[..., di + N:]
+    B_c = _per_head(conv_out[..., di:di + N], cfg)    # (B, C, [nh,] N)
+    C_c = _per_head(conv_out[..., di + N:], cfg)
     dt_c = jax.nn.softplus(dt.astype(jnp.float32)
                            + p["dt_bias"][None, None, :])     # (B, C, nh)
     valid = (jnp.arange(C)[None, :] < seg_len[:, None]) \
@@ -263,9 +293,7 @@ def paged_step(p: Dict, x, be: Policy, cfg: ModelConfig, state: Tuple,
                          chunk=s.chunk)
     y = y + p["D"][None, None, :, None] * xs_c.astype(jnp.float32)
     y = y.reshape(B, C, di).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype),
-                p["norm_w"], cfg.norm_eps)
-    out = mm(y, p["out_proj"], be)
+    out = mm(_gated_norm(y, z, p["norm_w"], cfg), p["out_proj"], be)
     # conv carry: rows [seg_len, seg_len + K-1) of [carry ; chunk] are
     # the last K-1 inputs at or before the segment end
     Kc = s.d_conv - 1

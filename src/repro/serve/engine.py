@@ -118,7 +118,12 @@ class PagedEngine:
     context sum), ``serve.prefill_chunk`` (holding ``serve.prefill_sample``
     on a final chunk, info: microseconds of its host sync) and
     ``serve.drain`` (holding one ``serve.drain_wait`` per token array
-    pulled to the host); ``serve.preempt`` marks a preemption."""
+    pulled to the host); ``serve.preempt`` marks a preemption.  Each
+    decode step also counts, over its attention applications (layers,
+    or shared-block applications), the KV blocks its slots' contexts
+    fill (``serve.attn_blocks_live``) against the blocks the paged
+    attention gathers, every table entry of every slot
+    (``serve.attn_blocks_gathered``)."""
 
     def __init__(self, model: Model, params, be: Optional[Policy] = None,
                  *, slots: int = 4, max_len: int = 256, eos: int = 2,
@@ -162,6 +167,7 @@ class PagedEngine:
         self.state = SlotStateStore(slots)
         self.scheduler = sched.SlotScheduler(self.cache, slots, self.state)
         self.done: Dict[int, List[int]] = {}
+        self._attn_apps = model.cfg.attn_applications
         dtype = model.cfg.compute_dtype
         self._ps = model.init_paged_state(num_blocks, block_size, slots,
                                           dtype)
@@ -297,13 +303,20 @@ class PagedEngine:
             bt = np.zeros((self.slots, self.cache.nmax), np.int32)
             pos = np.zeros((self.slots,), np.int32)
             act = np.zeros((self.slots,), bool)
-            ctx = 0
+            ctx = live = 0
+            bs = self.cache.block_size
             for q in dec:
                 bt[q.slot] = self.cache.row(q.rid)
                 pos[q.slot] = q.pos
                 act[q.slot] = True
                 ctx += q.pos + 1
+                live += -(-(q.pos + 1) // bs)
             sp.info = (len(dec), ctx)
+            if self._attn_apps:
+                obs.counter("serve.attn_blocks_live").inc(
+                    live * self._attn_apps)
+                obs.counter("serve.attn_blocks_gathered").inc(
+                    self.slots * self.cache.nmax * self._attn_apps)
             self._cur, self._ps, self.key = self._decode_fn(
                 self.params, self._cur, self._ps,
                 jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(act),

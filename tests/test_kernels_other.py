@@ -157,6 +157,38 @@ def test_ssd_state_handoff():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("path", ["chunked", "kernel", "serve"])
+def test_ssd_groups_match_recurrence(path):
+    """B and C in two groups (Zamba2's ``n_groups``), head h reading group
+    h // (H / G): the recurrence over each group's heads is the one-group
+    recurrence of that group, and the chunked form, the Pallas kernel
+    and the serving recurrence (B and C given per head) agree with it."""
+    rng = np.random.RandomState(12)
+    H, G, S = 4, 2, 40
+    x, dt, A, _, _ = _ssd_inputs(rng, 2, S, H, 8, 16)
+    B = jnp.asarray(rng.randn(2, S, G, 16) * 0.3, jnp.float32)
+    C = jnp.asarray(rng.randn(2, S, G, 16) * 0.3, jnp.float32)
+    gt = ref.ref_ssd_recurrent(x, dt, A, B, C)
+    hpg = H // G
+    for g in range(G):
+        hs = slice(g * hpg, (g + 1) * hpg)
+        one = ref.ref_ssd_recurrent(x[:, :, hs], dt[:, :, hs], A[hs],
+                                    B[:, :, g:g + 1], C[:, :, g:g + 1])
+        np.testing.assert_array_equal(np.asarray(gt[:, :, hs]),
+                                      np.asarray(one))
+    if path == "chunked":
+        got = ref.ref_ssd(x, dt, A, B, C, chunk=16)
+    elif path == "kernel":
+        got = ops.ssd_scan(x, dt, A, B, C, chunk=16, interpret=True)
+    else:
+        h0 = jnp.zeros((2, H, 8, 16), jnp.float32)
+        _, got = ssm.serve_ssd(h0, x, dt, A, jnp.repeat(B, hpg, axis=2),
+                               jnp.repeat(C, hpg, axis=2),
+                               jnp.ones((2, S), bool), chunk=16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(gt),
+                               rtol=1e-4, atol=1e-5)
+
+
 @pytest.mark.parametrize("active", [True, False])
 @pytest.mark.parametrize("h0", ["zero", "random"])
 @pytest.mark.parametrize("seg", ["full", "partial"])

@@ -108,7 +108,11 @@ def test_full_configs_match_assignment():
     c = configs.get_config("mamba2-780m")
     assert c.ssm.d_state == 128 and c.n_layers == 48 and c.d_model == 1536
     c = configs.get_config("zamba2-7b")
-    assert c.n_layers == 81 and c.ssm.d_state == 64 and c.shared_attn_every
+    assert c.n_layers == 81 and c.ssm.d_state == 64 and c.ssm.n_groups == 2
+    assert c.hybrid_layers == (6, 11, 17, 23, 29, 35, 41, 47, 53, 59, 65,
+                               71, 77)
+    assert (c.n_shared_blocks, c.adapter_rank, c.head_dim) == (2, 128, 224)
+    assert c.mlp_act == "gelu" and c.tie_embeddings
     c = configs.get_config("glm4-9b")
     assert (c.n_layers, c.d_model, c.n_kv_heads, c.d_ff) == (40, 4096, 2, 13696)
     c = configs.get_config("gemma3-1b")

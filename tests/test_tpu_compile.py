@@ -252,3 +252,39 @@ def test_glm4_9b_cut_paged_prefill_step_compiles(one_chip):
     cfg = dataclasses.replace(configs.get_config("glm4-9b"), n_layers=4)
     compiled = _compile_paged_step(cfg, one_chip, slots=4, step="prefill")
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_zamba2_7b_cut_paged_decode_keeps_pools_in_place(one_chip):
+    """Published widths, 12 of 81 layers (hybrid layers 6 and 11), 8
+    slots at 4096 positions: the two applications' KV pools (token-major,
+    0.94 GB each for K and V) and the slot state are updated where they
+    lie, with no copy and no relayout of a pool, and the adapters'
+    (8,3584)@(3584,128) and (8,128)@(128,14336) GEMMs go to IAAT kernels
+    (three per shared block)."""
+    cfg = dataclasses.replace(configs.get_config("zamba2-7b"), n_layers=12)
+    slots, block_size, nmax = 8, 16, 4096 // 16
+    model = build(cfg)
+    decode, _ = paged_step_fns(model, api.named_policy("auto",
+                                                       interpret=False))
+    template = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = _sds(jax.eval_shape(model.serving_params, template), one_chip)
+    ps = jax.eval_shape(lambda: model.init_paged_state(
+        1 + slots * nmax, block_size, slots, cfg.compute_dtype))
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(decode, donate_argnums=(2,)).lower(
+        params, arr((slots,)), _sds(ps, one_chip), arr((slots, nmax)),
+        arr((slots,)), arr((slots,), bool), arr((2,), jnp.uint32)).compile()
+    _check(compiled, n_kernels=3 * cfg.n_shared_blocks)
+    _kernels_named(compiled)
+    text = compiled.as_text()
+    aliases = _state_aliases(text)
+    assert ps.shared_k.shape == (2, 1 + slots * nmax, block_size,
+                                 cfg.n_kv_heads * cfg.head_dim)
+    for stack in (ps.shared_k, ps.shared_v, ps.ssm, ps.conv):
+        typ = _hlo_type(stack)
+        assert not re.search(rf"= {re.escape(typ)}(\{{[^}}]*\}})? copy\(",
+                             text), typ
+        assert (typ, typ) in aliases, typ
